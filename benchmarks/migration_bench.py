@@ -200,6 +200,7 @@ def measured_child(steps: int) -> None:
 def measure(steps: int) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"  # host devices; never the chip
     env["PYTHONPATH"] = f"{ROOT}/src"
     out = subprocess.run(
         [sys.executable, __file__, "--measure-child", str(steps)],

@@ -30,10 +30,8 @@ def main():
         plan = MeshPlan(mesh=mesh, ep=8, tp=1, dp_axes=("data",))
         x = jax.random.normal(jax.random.PRNGKey(0), (64, 16, 32))
 
-        from repro import compat
-
         def run(fn):
-            return jax.jit(compat.shard_map(
+            return jax.jit(jax.shard_map(
                 fn, mesh=mesh, in_specs=P("ep", None, None),
                 out_specs=P("ep", None, None), check_vma=False,
             ))(x)

@@ -102,11 +102,9 @@ def a2a_bandwidth_curve(msg_sizes: Tuple[int, ...] = (2**14, 2**17, 2**20)) -> L
     def f(x):
         return jax.lax.all_to_all(x, "x", 0, 0, tiled=True)
 
-    from repro import compat
-
     g = jax.jit(
-        compat.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
-                         check_vma=False)
+        jax.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+                      check_vma=False)
     )
     for m in msg_sizes:
         rows_per = max(m // 4 // n, 1)
@@ -139,7 +137,6 @@ def a2a_overlap_layer(
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.core import halo
     from repro.sharding import host_mesh
 
@@ -175,7 +172,7 @@ def a2a_overlap_layer(
         outs = halo.overlapped_a2a(a2a, get_chunk, compute, slices)
         return jnp.concatenate(outs, axis=1)
 
-    f = jax.jit(compat.shard_map(
+    f = jax.jit(jax.shard_map(
         layer, mesh=mesh, in_specs=(P("ep"), P(), P()), out_specs=P("ep"),
         check_vma=False,
     ))

@@ -75,29 +75,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat, obs
+from repro import obs
 from repro.configs.base import ArchConfig
 from repro.core import schedules as sched_lib
 from repro.core.schedules import OP_B, OP_BI, OP_BW, OP_F
 from repro.models import transformer
 from repro.sharding import MeshPlan
-
-
-def _composition(plan: MeshPlan):
-    """(manual_axes, local_interior) for the outer pipeline shard_map.
-
-    Production composition: manual over the pipeline axis only, GSPMD-auto
-    interior (full expert-data-parallel machinery per stage).  When the
-    installed JAX cannot express partial manualness (see
-    ``compat.partial_auto_shard_map``), fall back to a fully-manual region
-    where every device inside a stage redundantly computes the whole
-    microbatch with collective-free block math (``local`` interior) — the
-    schedule execution, ppermute hand-offs and memory profile stay real;
-    only intra-stage parallelism is sacrificed, on a JAX that cannot run it
-    anyway."""
-    if compat.partial_auto_shard_map():
-        return {plan.pp_axis}, False
-    return set(plan.mesh.axis_names), True
 
 
 def _stage_block_params(
@@ -245,7 +228,6 @@ def pipelined_stack_forward(
 
     has_moe = arch.num_moe_layers > 0
     mesh = plan.mesh
-    manual_axes, local = _composition(plan)
 
     def stage_program(stage_params, emb_params, xm_local):
         # in_spec P(pp_axis) leaves a leading length-1 stage dim; the next
@@ -267,19 +249,15 @@ def pipelined_stack_forward(
                 impl=impl,
                 token_sharded=True,
                 unroll=True,
-                local=local,
             )
 
         # Steer GSPMD to the canonical activation layout inside the stage —
         # without this the partitioner invents mixed shardings for the
         # carried microbatch and hits an XLA involuntary-remat bug at
-        # 512-device scale.  (No-op in the fully-manual compat composition:
-        # there is no auto interior to steer.)
+        # 512-device scale.
         act_spec = P(tuple(plan.dp_axes), tuple(plan.sp_axes), None)
 
         def constrain(h):
-            if local:
-                return h
             return lax.with_sharding_constraint(h, act_spec)
 
         def tick(carry, xs):
@@ -291,9 +269,8 @@ def pipelined_stack_forward(
             h_out, aux_d, loads_d = stage_fn(inp)
             h_out = constrain(h_out)
             valid = valid_t[stage, t].astype(jnp.float32)
-            # (1,)-shaped accumulators: old-JAX shard_map AD mis-specs
-            # SCALAR residuals crossing the region boundary (it names dim 0
-            # of every residual), so keep these rank-1.
+            # (1,)-shaped accumulators: out_specs stack them over the
+            # pipeline axis.
             aux = aux + aux_d["moe_aux_loss"][None] * valid
             z = z + aux_d["moe_z_loss"][None] * valid
             if loads is not None and loads_d is not None:
@@ -352,13 +329,13 @@ def pipelined_stack_forward(
             return out, aux, z, jnp.zeros((), jnp.float32)
         return out, aux, z, loads[None]
 
-    out, aux, z, loads = compat.shard_map(
+    out, aux, z, loads = jax.shard_map(
         wrapped,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
         check_vma=False,
-        axis_names=manual_axes,
+        axis_names={plan.pp_axis},
     )(staged, embed_params if embed_params is not None else jnp.zeros(()), xm)
 
     # out: (PP, M, b_mu, s, d) — only the last stage's block is the real
@@ -410,7 +387,6 @@ def _pipelined_stack_forward_v(
 
     has_moe = arch.num_moe_layers > 0
     mesh = plan.mesh
-    manual_axes, local = _composition(plan)
     act_dtype = (
         _act_dtype(block_params, x.dtype) if embed_fn is not None else x.dtype
     )
@@ -430,8 +406,6 @@ def _pipelined_stack_forward_v(
         act_spec = P(tuple(plan.dp_axes), tuple(plan.sp_axes), None)
 
         def constrain(h):
-            if local:
-                return h
             return lax.with_sharding_constraint(h, act_spec)
 
         def tick(carry, t):
@@ -461,7 +435,7 @@ def _pipelined_stack_forward_v(
             h_out, aux_d, loads_d = transformer.stack_forward(
                 chunk, inp, arch, plan,
                 positions=pos_mu, impl=impl, token_sharded=True,
-                unroll=True, local=local,
+                unroll=True,
             )
             h_out = constrain(h_out)
             vmask = valid_t[stage, t].astype(jnp.float32)
@@ -515,13 +489,13 @@ def _pipelined_stack_forward_v(
             return out, aux, z, jnp.zeros((), jnp.float32)
         return out, aux, z, loads[None]
 
-    out, aux, z, loads = compat.shard_map(
+    out, aux, z, loads = jax.shard_map(
         wrapped,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
         check_vma=False,
-        axis_names=manual_axes,
+        axis_names={plan.pp_axis},
     )(staged, embed_params if embed_params is not None else jnp.zeros(()), xm)
 
     y = out[-1].reshape(b, s, d)
@@ -668,7 +642,6 @@ def pipelined_step(
 
     has_moe = arch.num_moe_layers > 0
     mesh = plan.mesh
-    manual_axes, local = _composition(plan)
     # Buffer/wire dtype: parameter dtype when embedding in-pipeline, the
     # input embeds' own dtype otherwise (input-driven promotion keeps stage
     # outputs in x.dtype there) — mirrors pipelined_stack_forward.
@@ -700,8 +673,6 @@ def pipelined_step(
         act_spec = P(tuple(plan.dp_axes), tuple(plan.sp_axes), None)
 
         def constrain(h):
-            if local:
-                return h
             return lax.with_sharding_constraint(h, act_spec)
 
         sp_floats, sp_merge, sp_rebuild = _partition_floats(stage_params)
@@ -728,7 +699,6 @@ def pipelined_step(
             h_out, aux_d, loads_d = transformer.stack_forward(
                 chunk, inp, arch, plan,
                 positions=pos_mu, impl=impl, token_sharded=True, unroll=True,
-                local=local,
             )
             return (
                 constrain(h_out),
@@ -1010,13 +980,13 @@ def pipelined_step(
                 z[None], loads, occ[None], wocc[None], cocc[None])
 
     (g_blocks, gemb, ghead, ce, aux, z, loads, occ, wocc,
-     cocc) = compat.shard_map(
+     cocc) = jax.shard_map(
         wrapped,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
         check_vma=False,
-        axis_names=manual_axes,
+        axis_names={plan.pp_axis},
     )(staged, emb_in, head_params, xm, lm_)
 
     # Chunk-major (PP, V, rpc, ...) grads -> the caller's (reps, ...) layout.

@@ -10,11 +10,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention import flash_attention as fa
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def flash_attention(
@@ -29,7 +26,7 @@ def flash_attention(
     bq: int = 256,
     bk: int = 256,
 ) -> jax.Array:
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     out = fa.flash_attention(
         q.transpose(0, 2, 1, 3),
         k.transpose(0, 2, 1, 3),

@@ -1,1 +1,2 @@
-from repro.kernels.moe_gemm import ops, ref  # noqa: F401
+"""Grouped expert GEMM kernels: ``ops`` (public wrappers) and ``ref``
+(pure-jnp oracle for tests)."""

@@ -27,9 +27,9 @@ overlapping expert with the out-of-range rows masked (blend-store), which
 is what bounds the padding waste at < bm rows per expert instead of
 ``C - c_e`` rows per expert.
 
-Interpret-mode caveat (JAX 0.4.37): ``pl.program_id`` inside a ``pl.when``
-body fails to lower on the CPU interpreter, so every program-id-derived
-value is hoisted out of the ``pl.when`` bodies below.
+Every program-id-derived value is hoisted out of the ``pl.when`` bodies
+below, which keeps the bodies free of grid queries in both the Mosaic and
+the interpret-mode lowering.
 """
 
 from __future__ import annotations

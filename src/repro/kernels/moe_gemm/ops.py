@@ -24,17 +24,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.moe_gemm import moe_gemm, ref
-
-
-def _interpret_default() -> bool:
-    # CPU containers run the kernel body in interpret mode; on TPU the
-    # compiled kernel is used.
-    return jax.default_backend() != "tpu"
+from repro.kernels import interpret_mode
+from repro.kernels.moe_gemm import moe_gemm
 
 
 def grouped_matmul(x, w, *, interpret=None, **blocks):
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     out = moe_gemm.grouped_matmul_f32(x, w, interpret=interpret, **blocks)
     return out.astype(x.dtype)
 
@@ -49,7 +44,7 @@ def grouped_ffn(tokens, w_up, w_gate, w_down, activation: str = "swiglu",
     fp32 accumulation the kernel exists to provide (the down-projection
     contracts over d_ffn, so the truncation error compounds with width).
     """
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     mm = partial(moe_gemm.grouped_matmul_f32, interpret=interpret, **blocks)
     if activation == "swiglu":
         h = jax.nn.silu(mm(tokens, w_gate)) * mm(tokens, w_up)
@@ -86,7 +81,7 @@ def ragged_matmul(x, w, offsets, *, interpret=None, bm=None, **blocks):
     x: (T, K); w: (E, K, N); offsets: (E+1,) int32 with offsets[E] <= T.
     Rows beyond offsets[E] (padding) produce zeros.  Returns x.dtype.
     """
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     bm = _row_block(x.shape[0]) if bm is None else bm
     xp, T = _pad_rows(x, bm)
     out = moe_gemm.ragged_matmul_f32(
@@ -179,7 +174,7 @@ def ragged_ffn(tokens, w_up, w_gate, w_down, offsets,
     """
     if activation == "swiglu" and w_gate is None:
         raise ValueError("swiglu ragged_ffn requires w_gate")
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     bm = _row_block(tokens.shape[0]) if bm is None else bm
     xp, T = _pad_rows(tokens, bm)
     ffn = _make_ragged_ffn(activation, interpret, bm, bn, bk)

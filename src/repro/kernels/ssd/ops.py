@@ -4,19 +4,14 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
-
+from repro.kernels import interpret_mode
 from repro.kernels.ssd import ssd
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def ssd_intra_chunk(xc, dAc, Bc, Cc, *, interpret: Optional[bool] = None):
     """xc: (b, nc, cl, h, p); dAc: (b, nc, cl, h); Bc/Cc: (b, nc, cl, h, n).
     Returns the intra-chunk output (b, nc, cl, h, p)."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     b, nc, cl, h, p = xc.shape
     fold = lambda t: t.reshape((b * nc,) + t.shape[2:])
     y = ssd.ssd_intra_chunk(

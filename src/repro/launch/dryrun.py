@@ -1,5 +1,8 @@
 import os
+# Host-lowered by design: 512 virtual CPU devices, never an accelerator —
+# this process and every child it starts stay off the chip.
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape x
 mesh) combination against the production meshes, with no device allocation
@@ -17,8 +20,8 @@ Usage:
 Results land in results/dryrun/<cell>.json (one file per cell) and are
 consumed by repro.launch.roofline and EXPERIMENTS.md.
 
-NOTE: the XLA_FLAGS line above must precede any jax import — jax locks the
-device count on first initialization.
+NOTE: the environment lines above must precede any jax import — jax locks
+the platform and device count on first initialization.
 """
 
 import argparse
@@ -393,9 +396,7 @@ def run_cell(
 
         ma = compiled.memory_analysis()
         print(ma)
-        from repro.compat import compiled_cost_analysis
-
-        ca = compiled_cost_analysis(compiled)
+        ca = compiled.cost_analysis() or {}
         # cost_analysis visits while-loop bodies once; analyze_hlo multiplies
         # by trip counts (see hlo_analysis docstring) — it is the authoritative
         # number for the roofline.
@@ -493,7 +494,8 @@ def _run_all(jobs: int, force: bool):
             print(f"[dryrun] launch {cell}")
             p = subprocess.Popen(
                 cmd, stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
-                env={**os.environ, "PYTHONPATH": "src"},
+                env={**os.environ, "PYTHONPATH": "src",
+                     "JAX_PLATFORMS": "cpu"},
             )
             running.append((cell, p, time.time()))
         done = [r for r in running if r[1].poll() is not None]

@@ -13,8 +13,9 @@ report (EP x TP x batch x dispatch under the latency SLO), binds the
 planner's dispatch mode and batch width into the local engine, serves a
 batch of synthetic mixed-length requests with continuous batching, and
 runs a decode parity probe against the uncached forward (ragged decode
-must match to 1e-5 — the dropless path recomputes nothing and drops
-nothing, so the paged incremental forward is exact).
+must match to 1e-5 of the largest logit — the dropless path recomputes
+nothing and drops nothing, so the paged incremental forward is exact up
+to fp32 summation order).
 """
 
 from __future__ import annotations
@@ -27,6 +28,10 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-moe-3b-a800m")
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="keep this many layers (whole periods of the "
+                         "block pattern), e.g. to fit one chip; default: "
+                         "the config's depth")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--chips", type=int, default=16,
@@ -58,6 +63,7 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
+    from repro import compile_cache
     from repro.configs import get_arch
     from repro.core import planner
     from repro.core.platform import TPU_V5E
@@ -65,6 +71,10 @@ def main():
     from repro.serving import Engine, Request, ServeConfig
     from repro.sharding import single_device_plan
 
+    compile_cache.enable()
+    dev = jax.devices()[0]
+    print(f"[device] platform={dev.platform} kind={dev.device_kind} "
+          f"count={len(jax.devices())}")
     arch = get_arch(args.arch)
 
     # Production serving-strategy report (what this arch needs at scale).
@@ -83,6 +93,8 @@ def main():
 
     if args.reduced:
         arch = arch.reduced()
+    if args.layers:
+        arch = arch.replace(num_layers=args.layers)
 
     # Bind the planner's choices into the local run: dispatch mode into
     # MoECfg (the MoE layer executes whatever the config says), batch
@@ -152,57 +164,73 @@ def main():
         # Replay request 0's sequence through the paged prefill + decode
         # steps with exact shapes and compare every decode step's logits to
         # the full no-cache forward.  Ragged decode recomputes nothing and
-        # drops nothing, so it must agree to 1e-5 (asserted); capacity
-        # decode re-derives its slot budget from T=1 (vs the forward's
-        # full-T), so under routing skew its drops may differ — reported
-        # for the bound mode, asserted for ragged.
+        # drops nothing, so it must agree up to fp32 summation order
+        # (asserted); capacity decode re-derives its slot budget from T=1
+        # (vs the forward's full-T), so under routing skew its drops may
+        # differ — reported for the bound mode, asserted for ragged.  Both
+        # sides run at "highest" matmul precision: a TPU's default runs
+        # fp32 matmuls in bf16 passes, which the two sides would round
+        # differently.  Returns (max |dlogits|, max |logit|, steps).
         def parity_probe(lm_p, seq, plen):
             from repro.serving.kv_cache import BlockPool
 
-            layout = cfg.layout()
-            pool = BlockPool(layout)
-            slot = pool.admit(plen)
-            cache = lm_p.init_paged_cache(layout, dtype=jnp.float32)
-            logits, cache = jax.jit(lm_p.prefill_paged)(
-                params, {"tokens": jnp.asarray(seq[None, :plen])}, cache,
-                jnp.asarray(pool.block_table[slot][None]),
-                jnp.asarray([plen], jnp.int32),
-            )
-            ref, _, _ = jax.jit(lm_p.forward)(
-                params, {"tokens": jnp.asarray(seq[None])}
-            )
-            errs = [float(jnp.abs(logits[0] - ref[0, plen - 1]).max())]
-            decode = jax.jit(lm_p.decode_step_paged)
-            for i, tok in enumerate(seq[plen:]):
-                pool.extend(slot, 1)
-                logits, cache = decode(
-                    params, cache,
+            with jax.default_matmul_precision("highest"):
+                layout = cfg.layout()
+                pool = BlockPool(layout)
+                slot = pool.admit(plen)
+                cache = lm_p.init_paged_cache(layout, dtype=jnp.float32)
+                logits, cache = jax.jit(lm_p.prefill_paged)(
+                    params, {"tokens": jnp.asarray(seq[None, :plen])}, cache,
                     jnp.asarray(pool.block_table[slot][None]),
-                    jnp.asarray([plen + i], jnp.int32),
-                    {"tokens": jnp.asarray([[int(tok)]])},
+                    jnp.asarray([plen], jnp.int32),
                 )
-                errs.append(float(jnp.abs(logits[0] - ref[0, plen + i]).max()))
-            return max(errs), len(errs)
+                ref, _, _ = jax.jit(lm_p.forward)(
+                    params, {"tokens": jnp.asarray(seq[None])}
+                )
+                errs = [float(jnp.abs(logits[0] - ref[0, plen - 1]).max())]
+                # Largest real logit (the vocab padding holds -1e30).
+                scale = float(jnp.abs(ref[0, :, : arch.vocab_size]).max())
+                decode = jax.jit(lm_p.decode_step_paged)
+                for i, tok in enumerate(seq[plen:]):
+                    pool.extend(slot, 1)
+                    logits, cache = decode(
+                        params, cache,
+                        jnp.asarray(pool.block_table[slot][None]),
+                        jnp.asarray([plen + i], jnp.int32),
+                        {"tokens": jnp.asarray([[int(tok)]])},
+                    )
+                    errs.append(
+                        float(jnp.abs(logits[0] - ref[0, plen + i]).max())
+                    )
+                return max(errs), scale, len(errs)
 
         req = reqs[0]
         seq = np.concatenate([req.tokens, out[req.rid][:-1]]).astype(np.int32)
         plen = int(req.tokens.size)
-        err, n = parity_probe(lm, seq, plen)
+        err, scale, n = parity_probe(lm, seq, plen)
         print(f"[parity] paged decode vs uncached forward: "
-              f"max |dlogits| = {err:.2e} over {n} steps "
+              f"max |dlogits| = {err:.2e} (max |logit| {scale:.2e}) over "
+              f"{n} steps "
               f"({arch.moe.dispatch if arch.moe else 'dense'} dispatch)")
         if arch.moe is not None and arch.moe.dispatch != "ragged":
             rag_arch = arch.replace(
                 moe=dataclasses.replace(arch.moe, dispatch="ragged")
             )
-            err, n = parity_probe(
+            err, scale, n = parity_probe(
                 LanguageModel(rag_arch, plan), seq, plen
             )
             print(f"[parity] ragged decode: max |dlogits| = {err:.2e} "
-                  f"over {n} steps")
+                  f"(max |logit| {scale:.2e}) over {n} steps")
         if arch.moe is not None:
-            assert err <= 1e-5, f"ragged decode parity violated: {err}"
-            print("[parity] ragged OK (<= 1e-5)")
+            # 1e-5 of the largest logit is ~80 fp32 ulps: room for a
+            # different summation order, while a bf16 pass (~4e-3
+            # relative) fails it.
+            tol = 1e-5 * max(1.0, scale)
+            if err > tol:
+                raise SystemExit(
+                    f"ragged decode parity violated: {err:.3e} > {tol:.3e}"
+                )
+            print(f"[parity] ragged OK (<= {tol:.2e})")
 
 
 def _telemetry_reports(args, arch, engine, max_seqs):

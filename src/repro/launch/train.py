@@ -12,6 +12,8 @@ Examples (CPU container — reduced configs; on TPU drop --reduced):
 The driver: consults the planner for the configuration report, builds the
 mesh+plan, initializes or restores state, and runs the fault-tolerant
 Trainer (checkpointing, straggler monitor, expert migration).
+:func:`setup` is that whole path up to the first step, so other entry
+points (``chip_smoke.py``) train through exactly the same binding.
 """
 
 from __future__ import annotations
@@ -19,10 +21,14 @@ from __future__ import annotations
 import argparse
 
 
-def main():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="keep this many layers (whole periods of the "
+                         "block pattern), e.g. to fit one chip; default: "
+                         "the config's depth")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -61,8 +67,34 @@ def main():
                          "per-stage pipeline lanes when PP>1) lands next "
                          "to it as <path>.trace.json and a model-vs-"
                          "measured drift report prints at end of run")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def main(argv=None):
+    from repro import compile_cache, obs
+
+    args = parse_args(argv)
+    compile_cache.enable()
+    run = setup(args)
+    with run["plan"].mesh:
+        out = run["trainer"].fit(run["state"], run["data"])
+    print(f"[done] step={out['last_step']} "
+          f"loss={float(out['metrics']['loss']):.4f} "
+          f"migrations={len(out['migrations'])} "
+          f"stragglers={len(out['stragglers'])}")
+
+    if run["ring"] is not None:
+        _telemetry_reports(args, run["arch"], run["plan"], run["ring"])
+        obs.get_telemetry().close()
+
+
+def setup(args):
+    """Everything before the first step: planner binding, mesh and plan,
+    model, initial state, data stream and Trainer.
+
+    Returns a dict with ``arch``, ``plan``, ``state``, ``data``,
+    ``trainer`` and ``ring`` (the telemetry ring buffer, or None).
+    """
     import jax
     import numpy as np
 
@@ -89,6 +121,11 @@ def main():
     arch = get_arch(args.arch)
     if args.reduced:
         arch = arch.reduced()
+    if args.layers:
+        arch = arch.replace(num_layers=args.layers)
+    dev = jax.devices()[0]
+    print(f"[device] platform={dev.platform} kind={dev.device_kind} "
+          f"count={len(jax.devices())}")
 
     # Planner report (what this run would need at production scale).
     best = planner.best_strategy(
@@ -192,39 +229,38 @@ def main():
              if plan.pp > 1 and plan.vstages > 1 else ""))
 
     lm = LanguageModel(arch, plan, impl=args.impl)
-    opt = OptimizerConfig(lr=args.lr, total_steps=args.steps)
+    # Warm up for at most a tenth of the run: a run shorter than the
+    # default 100-step warmup would otherwise never reach its learning rate.
+    opt = OptimizerConfig(lr=args.lr, total_steps=args.steps,
+                          warmup_steps=min(100, args.steps // 10))
     with plan.mesh:
         state = training.init_state(lm, jax.random.PRNGKey(args.seed), opt)
+        # Each device takes its share (its experts, its ZeRO slice) now,
+        # not whatever the first step's compiler would pick.
+        state = jax.device_put(state, training.state_shardings(lm))
         n_params = sum(
             int(np.prod(p.shape)) for p in jax.tree.leaves(state["params"])
         )
         print(f"[model] {args.arch}{' (reduced)' if args.reduced else ''}: "
-              f"{n_params/1e6:.1f}M params")
+              f"{arch.num_layers} layers, {n_params/1e6:.1f}M params")
 
-        if args.corpus:
-            data = MemmapCorpus(args.corpus, args.batch, args.seq)
-        else:
-            data = SyntheticTokens(arch.vocab_size, args.batch, args.seq)
-        data = Prefetcher(iter(data))
+    if args.corpus:
+        data = MemmapCorpus(args.corpus, args.batch, args.seq)
+    else:
+        data = SyntheticTokens(arch.vocab_size, args.batch, args.seq)
+    data = Prefetcher(iter(data))
 
-        trainer = Trainer(
-            lm, opt,
-            TrainerConfig(
-                total_steps=args.steps,
-                checkpoint_dir=args.ckpt_dir,
-                checkpoint_every=args.ckpt_every,
-                migrate_every=args.migrate_every,
-            ),
-        )
-        out = trainer.fit(state, data)
-        print(f"[done] step={out['last_step']} "
-              f"loss={float(out['metrics']['loss']):.4f} "
-              f"migrations={len(out['migrations'])} "
-              f"stragglers={len(out['stragglers'])}")
-
-    if ring is not None:
-        _telemetry_reports(args, arch, plan, ring)
-        obs.get_telemetry().close()
+    trainer = Trainer(
+        lm, opt,
+        TrainerConfig(
+            total_steps=args.steps,
+            checkpoint_dir=args.ckpt_dir,
+            checkpoint_every=args.ckpt_every,
+            migrate_every=args.migrate_every,
+        ),
+    )
+    return {"arch": arch, "plan": plan, "state": state, "data": data,
+            "trainer": trainer, "ring": ring}
 
 
 def _telemetry_reports(args, arch, plan, ring):

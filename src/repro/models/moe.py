@@ -57,7 +57,6 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig, MoECfg
 from repro.core import halo
 from repro.sharding import MeshPlan
@@ -176,24 +175,18 @@ def _sort_dispatch(flat_e: jax.Array, E: int):
     return order, inv, offsets
 
 
-def _ragged_rows_ffn(xs, w_up, w_gate, w_down, offsets, activation: str,
-                     impl: str):
-    """Grouped FFN over expert-sorted rows.  impl="pallas" runs the ragged
-    Pallas kernels (custom VJP, fp32 accumulation both directions);
-    impl="xla" runs the differentiable dense-select oracle (reference
-    semantics, O(T·d·f) weight-gather temp)."""
+def _ragged_rows_ffn(xs, w_up, w_gate, w_down, offsets, activation: str):
+    """Grouped FFN over expert-sorted rows: always the ragged Pallas kernels
+    (custom VJP, fp32 accumulation both directions), whatever kernel the
+    attention uses.  The jnp oracle in ``kernels/moe_gemm/ref.py`` gathers a
+    full expert weight per row (O(T·d·f) temp) and is for tests only."""
     from repro.kernels.moe_gemm import ops as moe_ops
-    from repro.kernels.moe_gemm import ref as moe_ref
 
-    if impl == "pallas":
-        return moe_ops.ragged_ffn(
-            xs, w_up, w_gate, w_down, offsets, activation
-        )
-    return moe_ref.ragged_ffn(xs, w_up, w_gate, w_down, offsets, activation)
+    return moe_ops.ragged_ffn(xs, w_up, w_gate, w_down, offsets, activation)
 
 
 def _moe_ragged_local(xt, top_phys, top_w, w_up, w_gate, w_down,
-                      activation: str, impl: str, E: int, k: int):
+                      activation: str, E: int, k: int):
     """Dropless single-rank MoE compute: sort → ragged FFN → inverse
     permutation → weighted combine.  Processes every (token, k) pair —
     no capacity, no drops, no zero-padding beyond the kernel's row tile."""
@@ -202,15 +195,14 @@ def _moe_ragged_local(xt, top_phys, top_w, w_up, w_gate, w_down,
     flat_w = top_w.reshape(-1)
     order, inv, offsets = _sort_dispatch(flat_e, E)
     xs = jnp.take(xt, order // k, axis=0)  # (T*k, d) expert-sorted
-    ys = _ragged_rows_ffn(xs, w_up, w_gate, w_down, offsets, activation,
-                          impl)
+    ys = _ragged_rows_ffn(xs, w_up, w_gate, w_down, offsets, activation)
     vals = jnp.take(ys, inv, axis=0)  # back to flat (token, k) order
     keep = jnp.ones_like(flat_e, dtype=bool)
     return _combine_expert_outputs(vals, flat_w, keep, T, k, d)
 
 
 def _moe_ragged_sharded(xt, top_phys, top_w, wu_f, wg_f, wd_f,
-                        activation: str, impl: str, moe: MoECfg,
+                        activation: str, moe: MoECfg,
                         ep_size: int, capacity: int, a2a, chunks: int = 1,
                         skip=None):
     """Dropless-style EP dispatch: sorted rows as the all-to-all payload,
@@ -230,10 +222,10 @@ def _moe_ragged_sharded(xt, top_phys, top_w, wu_f, wg_f, wd_f,
     arrive sorted by expert, those counts reconstruct the receiver-side
     expert ids exactly (``jnp.repeat`` with a static total), so the
     per-row id sideband the payload used to carry is no longer shipped.
-    On a JAX with ``lax.ragged_all_to_all`` the same counts would also
-    right-size the row payload itself; on this pinned JAX (0.4.37, no
-    ragged collective) the payload stays at the static capacity wire size
-    and the win is the id sideband + receiver-side segment metadata.  The
+    Fed to ``lax.ragged_all_to_all``, the same counts would also right-size
+    the row payload itself; this path still ships the payload with the
+    fixed-size ``all_to_all`` at the static capacity wire size, so the win
+    is the id sideband + receiver-side segment metadata.  The
     second (tiny) collective is priced by
     ``resource_model.dispatch_costs`` as ``counts_bytes_per_layer``.
 
@@ -319,8 +311,7 @@ def _moe_ragged_sharded(xt, top_phys, top_w, wu_f, wg_f, wd_f,
              jnp.cumsum(counts_c[:E_l]).astype(jnp.int32)]
         )
         xr = jnp.take(rx, order_c, axis=0)
-        ys = _ragged_rows_ffn(xr, wu_f, wg_f, wd_f, offsets_c, activation,
-                              impl)
+        ys = _ragged_rows_ffn(xr, wu_f, wg_f, wd_f, offsets_c, activation)
         back = jnp.zeros((ep_size * size, d), ys.dtype).at[order_c].set(ys)
         return back.reshape(ep_size, size, d)
 
@@ -336,7 +327,7 @@ def _moe_ragged_sharded(xt, top_phys, top_w, wu_f, wg_f, wd_f,
 
 
 def _moe_ragged_decode(xt, top_phys, top_w, wu_f, wg_f, wd_f,
-                       activation: str, impl: str, moe: MoECfg,
+                       activation: str, moe: MoECfg,
                        ep_size: int, skip=None):
     """Ragged weight-parallel decode (token_sharded=False): tokens are
     replicated over the "ep" axis; each rank locally sorts the replicated
@@ -367,7 +358,7 @@ def _moe_ragged_decode(xt, top_phys, top_w, wu_f, wg_f, wd_f,
          jnp.cumsum(counts[:E_l]).astype(jnp.int32)]
     )
     xs = jnp.take(xt, order // k, axis=0)  # (T*k, d) local-expert-sorted
-    ys = _ragged_rows_ffn(xs, wu_f, wg_f, wd_f, offsets, activation, impl)
+    ys = _ragged_rows_ffn(xs, wu_f, wg_f, wd_f, offsets, activation)
     # Rows past offsets[E_l] (other ranks' experts) come back zero, so the
     # inverse scatter leaves non-local rows zero and the psum sums each
     # row's single owning rank.
@@ -439,7 +430,7 @@ def _replica_weights(replicas, assignment, wu_f, wg_f, wd_f, E: int,
 
 
 def _replica_ffn(xt, rchan, top_k: int, wu_r, wg_r, wd_r, R: int,
-                 activation: str, impl: str, wire_bf16: bool):
+                 activation: str, wire_bf16: bool):
     """Ragged FFN over the (token, k) rows routed to replica channels.
 
     Rows carrying the sentinel R sort to the never-computed tail and come
@@ -456,7 +447,7 @@ def _replica_ffn(xt, rchan, top_k: int, wu_r, wg_r, wd_r, R: int,
     xs = jnp.take(xt, order // top_k, axis=0)
     if wire_bf16:
         xs = xs.astype(jnp.bfloat16).astype(xt.dtype)
-    ys = _ragged_rows_ffn(xs, wu_r, wg_r, wd_r, offsets, activation, impl)
+    ys = _ragged_rows_ffn(xs, wu_r, wg_r, wd_r, offsets, activation)
     if wire_bf16:
         ys = ys.astype(jnp.bfloat16).astype(xt.dtype)
     return jnp.zeros((rchan.shape[0], xt.shape[1]), ys.dtype).at[order].set(ys)
@@ -532,12 +523,8 @@ def moe_ffn_local(
     impl: str = "xla",
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Collective-free single-rank MoE: the exact routing/capacity/expert
-    math of :func:`moe_ffn`'s body with EP = 1 and no mesh.
-
-    Used by the pipeline executor's *compat interior* (old JAX cannot nest a
-    manual shard_map inside another manual region — see ``repro.compat``),
-    where every device inside a stage redundantly computes the full
-    microbatch, and by any caller that wants the reference semantics.
+    math of :func:`moe_ffn`'s body with EP = 1 and no mesh — the reference
+    the sharded paths are tested against.
     """
     moe = arch.moe
     assert moe is not None
@@ -552,7 +539,7 @@ def moe_ffn_local(
     if moe.dispatch == "ragged":
         y = _moe_ragged_local(
             xt, top_phys, top_w, params["w_up"], wg, params["w_down"],
-            arch.ffn_activation, impl, E, moe.top_k,
+            arch.ffn_activation, E, moe.top_k,
         )
     else:
         capacity = _capacity(T, moe)
@@ -677,7 +664,7 @@ def moe_ffn(
             if token_sharded:
                 vals_rep = _replica_ffn(
                     xt, rchan, moe.top_k, wu_r, wg_r, wd_r, R,
-                    arch.ffn_activation, impl, wire_bf16=True,
+                    arch.ffn_activation, wire_bf16=True,
                 )
             else:
                 # Decode: tokens are replicated over "ep" — round-robin row
@@ -689,7 +676,7 @@ def moe_ffn(
                 rchan_own = jnp.where(own, rchan, R)
                 vals_rep = _replica_ffn(
                     xt, rchan_own, moe.top_k, wu_r, wg_r, wd_r, R,
-                    arch.ffn_activation, impl, wire_bf16=False,
+                    arch.ffn_activation, wire_bf16=False,
                 )
                 vals_rep = lax.psum(vals_rep, "ep")
             # Disjoint supports (rep_row vs keep) make the two combines an
@@ -708,18 +695,18 @@ def moe_ffn(
             if not token_sharded:
                 y = _moe_ragged_decode(
                     xt, top_phys, top_w, wu_f, wg_f, wd_f,
-                    arch.ffn_activation, impl, moe, ep_size, skip=rep_row,
+                    arch.ffn_activation, moe, ep_size, skip=rep_row,
                 )
             elif ep_size > 1:
                 y = _moe_ragged_sharded(
                     xt, top_phys, top_w, wu_f, wg_f, wd_f,
-                    arch.ffn_activation, impl, moe, ep_size, capacity, a2a,
+                    arch.ffn_activation, moe, ep_size, capacity, a2a,
                     chunks, skip=rep_row,
                 )
             else:
                 y = _moe_ragged_local(
                     xt, top_phys, top_w, wu_f, wg_f, wd_f,
-                    arch.ffn_activation, impl, E, moe.top_k,
+                    arch.ffn_activation, E, moe.top_k,
                 )
             if y_rep is not None:
                 y = y + y_rep
@@ -797,14 +784,10 @@ def moe_ffn(
     # used — passing the concrete mesh would conflict with the outer manual
     # axis types.
     manual = set(a for a in mesh.axis_names if a != plan.pp_axis)
-    try:
-        ctx = jax.sharding.get_abstract_mesh()
-        have_ctx = ctx is not None and len(ctx.axis_names) > 0
-    except Exception:  # pragma: no cover
-        have_ctx = False
+    have_ctx = len(jax.sharding.get_abstract_mesh().axis_names) > 0
     mesh_kw = {} if have_ctx else {"mesh": mesh}
 
-    y, metrics = compat.shard_map(
+    y, metrics = jax.shard_map(
         wrapped,
         in_specs=in_specs,
         out_specs=out_specs,

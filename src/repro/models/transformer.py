@@ -36,15 +36,8 @@ def apply_block(
     cache_index=None,
     return_cache: bool = False,
     token_sharded: bool = True,
-    local: bool = False,
 ):
-    """One (mixer, ffn) block with pre-norms and residuals.
-
-    ``local=True`` runs the block as plain single-rank math — no sharding
-    constraints, no collectives (MoE via :func:`moe_ffn_local`).  The
-    pipeline executor's compat interior uses this when the installed JAX
-    cannot nest a manual shard_map inside the pipeline's manual region.
-    """
+    """One (mixer, ffn) block with pre-norms and residuals."""
     mixer, ffn = block
     metrics: Dict[str, jax.Array] = {}
     new_cache = None
@@ -62,7 +55,7 @@ def apply_block(
             cache=cache,
             cache_index=cache_index,
             return_kv=return_cache and cache is None,
-            plan=None if local else plan,
+            plan=plan,
         )
     elif mixer == "mamba":
         out, new_cache = ssm_lib.mamba_block(
@@ -82,19 +75,14 @@ def apply_block(
         if ffn == "dense":
             out = L.dense_ffn(params["ffn"], h, arch.ffn_activation)
         elif ffn == "moe":
-            if local:
-                out, metrics = moe_lib.moe_ffn_local(
-                    params["ffn"], h, arch, impl=impl
-                )
-            else:
-                out, metrics = moe_lib.moe_ffn(
-                    params["ffn"],
-                    h,
-                    arch,
-                    plan,
-                    token_sharded=token_sharded,
-                    impl=impl,
-                )
+            out, metrics = moe_lib.moe_ffn(
+                params["ffn"],
+                h,
+                arch,
+                plan,
+                token_sharded=token_sharded,
+                impl=impl,
+            )
         else:
             raise ValueError(ffn)
         x = x + out
@@ -121,7 +109,6 @@ def stack_forward(
     impl: str = "xla",
     token_sharded: bool = True,
     unroll: bool = False,
-    local: bool = False,
 ):
     """Run the full layer stack via scan-over-reps.
 
@@ -143,7 +130,6 @@ def stack_forward(
                 positions=positions,
                 impl=impl,
                 token_sharded=token_sharded,
-                local=local,
             )
             if metrics:
                 aux = aux + metrics["moe_aux_loss"]

@@ -100,6 +100,8 @@ class Trainer:
         self.injector = (
             injector if injector is not None else FaultInjector(log_fn=log_fn)
         )
+        # The new state leaves on the plan's shardings, so the next step's
+        # inputs match the first step's and nothing recompiles.
         self.train_step = jax.jit(
             training.make_train_step(
                 lm, opt_cfg,
@@ -107,6 +109,7 @@ class Trainer:
                 if cfg.gnorm_skip_cap > 0 else None,
             ),
             donate_argnums=(0,),
+            out_shardings=(training.state_shardings(lm), None),
         )
         self.ckpt = (
             CheckpointManager(
@@ -127,6 +130,8 @@ class Trainer:
         # gate's TrainSetup; None until the first batch arrives.
         self._batch_shape: Optional[tuple] = None
         self.step_times: List[float] = []
+        # (step, loss) at every log step — the values the log line prints.
+        self.losses: List[tuple] = []
         self.stragglers: List[int] = []
         self.migrations: List[Dict[str, Any]] = []
         self.anomalies: List[Dict[str, Any]] = []
@@ -378,20 +383,13 @@ class Trainer:
             )
 
     def _abstract_and_shardings(self, state):
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
         abstract = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state
         )
         # The PLAN's state shardings: restored leaves must land on-device
         # with the mesh layout the step expects — not replicated, and not
         # committed to whatever single device a fresh eager init sat on.
-        shardings = jax.tree.map(
-            lambda s: NamedSharding(self.lm.plan.mesh, s),
-            training.state_specs(self.lm),
-            is_leaf=lambda x: isinstance(x, P),
-        )
-        return abstract, shardings
+        return abstract, training.state_shardings(self.lm)
 
     def _next_batch(self, data, data_it, indexed: bool, step: int):
         """Fetch the step's batch, retrying transient data-source errors
@@ -558,6 +556,7 @@ class Trainer:
             state = self._maybe_migrate(state, step + 1)
             if step % self.cfg.log_every == 0:
                 loss = float(self._fetch(metrics["loss"]))
+                self.losses.append((step, loss))
                 obs.gauge("train.loss", loss, step=step)
                 self.log(
                     f"[train] step={step} loss={loss:.4f} "

@@ -35,7 +35,7 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig, DEFAULT_SCHEDULE, SCHEDULES
 
@@ -266,7 +266,10 @@ def single_device_plan(arch: ArchConfig) -> MeshPlan:
 
 
 def host_mesh(shape: Sequence[int], names: Sequence[str]) -> Mesh:
-    """Build a mesh from however many host devices exist (tests)."""
-    n = int(np.prod(shape))
-    devs = np.asarray(jax.devices()[:n]).reshape(tuple(shape))
-    return Mesh(devs, tuple(names))
+    """A mesh over the first ``prod(shape)`` local devices, laid out by
+    ``jax.make_mesh`` (physical-topology order on a TPU), with GSPMD-auto
+    axes like every other mesh here."""
+    return jax.make_mesh(
+        tuple(shape), tuple(names),
+        axis_types=(AxisType.Auto,) * len(shape),
+    )

@@ -48,6 +48,15 @@ def state_specs(lm: LanguageModel) -> Dict[str, Any]:
     }
 
 
+def state_shardings(lm: LanguageModel) -> Dict[str, Any]:
+    """The plan's ``NamedSharding`` for every train-state leaf."""
+    return jax.tree.map(
+        lambda s: NamedSharding(lm.plan.mesh, s),
+        state_specs(lm),
+        is_leaf=lambda x: isinstance(x, P),
+    )
+
+
 def abstract_state(lm: LanguageModel) -> Dict[str, Any]:
     params = model_lib.abstract_params(lm.arch, DTYPES[lm.plan.master_dtype])
     odt = DTYPES[lm.plan.optimizer_dtype]

@@ -8,7 +8,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs import get_arch
 from repro.core import halo
 from repro.core import migration as mig
@@ -34,7 +33,7 @@ def check_halo():
     xg = jax.random.normal(jax.random.PRNGKey(0), (64, R, d))
 
     def run(fn):
-        return compat.shard_map(
+        return jax.shard_map(
             fn, mesh=mesh, in_specs=P("ep", None, None),
             out_specs=P("ep", None, None), check_vma=False,
         )(xg)

@@ -130,7 +130,6 @@ def _child_main():
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.sharding import MeshPlan, host_mesh
 
     assert len(jax.devices()) == 8, jax.devices()
@@ -142,14 +141,14 @@ def _child_main():
         xg = jax.random.normal(jax.random.PRNGKey(ep), (ep * ep, R, d))
 
         def run(fn):
-            return jax.jit(compat.shard_map(
+            return jax.jit(jax.shard_map(
                 fn, mesh=mesh, in_specs=P("ep", None, None),
                 out_specs=P("ep", None, None), check_vma=False,
             ))(xg)
 
         def grad_of(fn):
             def loss(x):
-                y = compat.shard_map(
+                y = jax.shard_map(
                     fn, mesh=mesh, in_specs=P("ep", None, None),
                     out_specs=P("ep", None, None), check_vma=False,
                 )(x)

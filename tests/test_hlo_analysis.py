@@ -38,15 +38,13 @@ def test_nested_scan_multiplies():
 
 
 def test_loop_free_matches_xla_cost_analysis():
-    from repro.compat import compiled_cost_analysis
-
     f = jax.jit(lambda a, b: jnp.tanh(a @ b))
     c = f.lower(
         jax.ShapeDtypeStruct((256, 128), jnp.float32),
         jax.ShapeDtypeStruct((128, 512), jnp.float32),
     ).compile()
     cost = H.analyze_hlo(c.as_text(), 1)
-    xla = compiled_cost_analysis(c)["flops"]
+    xla = c.cost_analysis()["flops"]
     assert cost.flops == pytest.approx(xla, rel=0.05)
 
 
@@ -57,7 +55,6 @@ def test_collectives_in_scan(tmp_path):
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro import compat
         from repro.sharding import host_mesh
         from repro.launch import hlo_analysis as H
         mesh = host_mesh((8,), ('x',))
@@ -65,8 +62,8 @@ def test_collectives_in_scan(tmp_path):
             def body(c, x):
                 return c + jax.lax.psum(x, 'x'), None
             return jax.lax.scan(body, jnp.zeros(1024), xs)[0]
-        g = compat.shard_map(f, mesh=mesh, in_specs=P(None, None),
-                             out_specs=P(), check_vma=False)
+        g = jax.shard_map(f, mesh=mesh, in_specs=P(None, None),
+                          out_specs=P(), check_vma=False)
         c = jax.jit(g).lower(
             jax.ShapeDtypeStruct((10, 1024), jnp.float32)).compile()
         s = H.analyze_hlo(c.as_text(), 8)
