@@ -3,8 +3,8 @@
 Two halves:
 
 * **Overhead** — wall-clocks the trainer hot-loop instrumentation pattern
-  (one ``train.step`` span + the skipped-flag fetch counter + one step
-  histogram, exactly what ``runtime.trainer`` emits per step) around a
+  (one ``train.step`` span holding the skipped-flag fetch's counter and
+  ``train.fetch`` span, exactly what ``runtime.trainer`` emits per step) around a
   warmed jitted train step, in alternating rounds with telemetry fully on
   (ring buffer + JSONL sink) and fully off (the ``_NULL_SPAN`` path).
   ``overhead_frac = enabled/disabled - 1`` is the acceptance number
@@ -83,7 +83,7 @@ def _train_env():
 
 def _instrumented_round(step_fn, state, batch, n):
     """Run ``n`` steps with the exact per-step telemetry the Trainer hot
-    loop emits: span + skipped-flag fetch counter + step-time histogram.
+    loop emits: step span + skipped-flag fetch counter + fetch span.
     Whether anything is recorded depends on the installed global
     Telemetry — the timed code is identical in both modes."""
     import jax
@@ -92,13 +92,12 @@ def _instrumented_round(step_fn, state, batch, n):
 
     t0 = time.perf_counter()
     for i in range(n):
-        s0 = time.perf_counter()
         with obs.span("train.step", step=i) as sp:
             state, metrics = step_fn(state, batch)
             obs.counter("train.host_fetches")
-            skipped = bool(jax.device_get(metrics.get("skipped", 0)))
+            with obs.span("train.fetch", what="skipped"):
+                skipped = bool(jax.device_get(metrics.get("skipped", 0)))
             sp.set(skipped=skipped)
-        obs.histogram("train.step_s", time.perf_counter() - s0, step=i)
     return (time.perf_counter() - t0) / n, state
 
 

@@ -227,9 +227,11 @@ def attention(
     return out.reshape(b, s_q, hq, d)
 
 
+@jax.named_scope("attention")
 def attention_proj(params, x, cfg, positions, *, impl="xla", window=None,
                    cache=None, cache_index=None, return_kv=False, plan=None):
-    """Full attention sub-layer: QKV proj -> rope -> attention -> out proj.
+    """Full attention sub-layer: QKV proj -> rope -> attention -> out proj,
+    under the ``attention`` scope (the compiled step's op metadata).
 
     cache: optional dict {"k": (b, S, hkv, d), "v": ...} — decode path.
     return_kv=True additionally returns the freshly computed K/V (prefill).

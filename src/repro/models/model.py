@@ -265,6 +265,7 @@ class LanguageModel:
 
     # -- embedding / head ---------------------------------------------------
 
+    @jax.named_scope("embed")
     def _embed(self, params, batch) -> jax.Array:
         a = self.arch
         if a.frontend is not None and "embeds" in batch:
@@ -374,6 +375,7 @@ class LanguageModel:
         scale = math.sqrt(a.d_model) if a.scale_embeddings else None
         embed_grad = self.plan.embed_grad
 
+        @jax.named_scope("embed")
         def embed_fn(table, toks):
             if not embed_grad:
                 # Dry-run-only XLA-bug workaround; see MeshPlan.embed_grad.
@@ -395,6 +397,7 @@ class LanguageModel:
         a = self.arch
         tied = a.tie_embeddings
 
+        @jax.named_scope("loss_head")
         def head_fn(head_params, embed_params, y, labels):
             h = rms_norm(y, head_params["final_norm"], a.norm_eps)
             w = embed_params.T if tied else head_params["lm_head"]
@@ -471,9 +474,24 @@ class LanguageModel:
 
     def loss(self, params, batch):
         """Causal LM loss (sequence-chunked CE). Returns (loss, metrics)."""
-        a = self.arch
         x, aux, loads = self._stack_out(params, batch)
-        labels = batch["labels"]
+        b, s, _ = x.shape
+        ce = self._ce_sum(params, x, batch["labels"]) / (b * s)
+        total = ce + aux["moe_aux_loss"] + aux["moe_z_loss"]
+        metrics = {
+            "loss": total,
+            "ce": ce,
+            "moe_aux_loss": aux["moe_aux_loss"],
+            "moe_z_loss": aux["moe_z_loss"],
+            "expert_load": loads,
+        }
+        return total, metrics
+
+    @jax.named_scope("loss_head")
+    def _ce_sum(self, params, x, labels):
+        """Summed CE of the stack's output: final norm, head, logsumexp,
+        chunked over the sequence with each chunk rematerialized."""
+        a = self.arch
         b, s, d = x.shape
         nc = self._loss_chunks(b, s)
 
@@ -485,33 +503,22 @@ class LanguageModel:
             return jnp.sum(lse - ll)
 
         if nc <= 1:
-            total_ce = ce_of(x, labels)
-        else:
-            sc = s // nc
-            xc = x.reshape(b, nc, sc, d).transpose(1, 0, 2, 3)
-            lc = labels.reshape(b, nc, sc).transpose(1, 0, 2)
-            spec = safe_spec(self.plan, (nc, b, sc, d), (None, "batch", "seq", None))
-            xc = lax.with_sharding_constraint(
-                xc, NamedSharding(self.plan.mesh, spec)
-            )
+            return ce_of(x, labels)
+        sc = s // nc
+        xc = x.reshape(b, nc, sc, d).transpose(1, 0, 2, 3)
+        lc = labels.reshape(b, nc, sc).transpose(1, 0, 2)
+        spec = safe_spec(self.plan, (nc, b, sc, d), (None, "batch", "seq", None))
+        xc = lax.with_sharding_constraint(
+            xc, NamedSharding(self.plan.mesh, spec)
+        )
 
-            @jax.checkpoint
-            def chunk(carry, xs):
-                x_part, l_part = xs
-                return carry + ce_of(x_part, l_part), None
+        @jax.checkpoint
+        def chunk(carry, xs):
+            x_part, l_part = xs
+            return carry + ce_of(x_part, l_part), None
 
-            total_ce, _ = lax.scan(chunk, jnp.float32(0.0), (xc, lc))
-
-        ce = total_ce / (b * s)
-        total = ce + aux["moe_aux_loss"] + aux["moe_z_loss"]
-        metrics = {
-            "loss": total,
-            "ce": ce,
-            "moe_aux_loss": aux["moe_aux_loss"],
-            "moe_z_loss": aux["moe_z_loss"],
-            "expert_load": loads,
-        }
-        return total, metrics
+        total_ce, _ = lax.scan(chunk, jnp.float32(0.0), (xc, lc))
+        return total_ce
 
     # -- serving ------------------------------------------------------------
 

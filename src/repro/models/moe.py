@@ -68,6 +68,7 @@ def _all_axes(plan: MeshPlan) -> Tuple[str, ...]:
     return tuple(a for a in plan.mesh.axis_names if a != plan.pp_axis)
 
 
+@jax.named_scope("moe.router")
 def _route(x_tokens: jax.Array, w_router: jax.Array, moe: MoECfg):
     """Top-k routing. x_tokens: (T, d) -> (weights (T,k), ids (T,k), probs)."""
     logits = jnp.einsum(
@@ -79,6 +80,7 @@ def _route(x_tokens: jax.Array, w_router: jax.Array, moe: MoECfg):
     return top_w, top_i, probs, logits
 
 
+@jax.named_scope("moe.router")
 def _aux_losses(probs, logits, top_i, moe: MoECfg, axes):
     """Switch-style load-balancing aux loss + router z-loss, meaned over the
     global token population via psum over every mesh axis."""
@@ -104,6 +106,7 @@ def _capacity(T: int, moe: MoECfg) -> int:
     )
 
 
+@jax.named_scope("moe.dispatch")
 def _scatter_to_buffers(xt, flat_e, pos, keep, E: int, capacity: int):
     """Token rows -> (E, C, d) capacity buffers (overflow masked to zero)."""
     src = jnp.repeat(xt, len(flat_e) // xt.shape[0], axis=0)  # (T*k, d)
@@ -111,12 +114,14 @@ def _scatter_to_buffers(xt, flat_e, pos, keep, E: int, capacity: int):
     return buf.at[flat_e, pos].add(src * keep[:, None].astype(xt.dtype))
 
 
+@jax.named_scope("moe.combine")
 def _combine_expert_outputs(vals, flat_w, keep, T: int, k: int, d: int):
     """Weighted top-k combine of gathered expert outputs back to tokens."""
     vals = vals * (flat_w * keep.astype(jnp.float32))[:, None].astype(vals.dtype)
     return vals.reshape(T, k, d).sum(axis=1)
 
 
+@jax.named_scope("moe.dispatch")
 def _dispatch_indices(top_i, top_w, E: int, capacity: int):
     """Slot assignment: position of each (token,k) pair within its expert's
     capacity buffer.  Returns (flat_e, pos, keep, flat_w)."""
@@ -130,6 +135,7 @@ def _dispatch_indices(top_i, top_w, E: int, capacity: int):
     return flat_e, pos, keep, flat_w
 
 
+@jax.named_scope("moe.experts")
 def _expert_ffn(tokens, w_up, w_gate, w_down, activation: str):
     """Grouped expert GEMM. tokens: (E_l, C_r, d).
 
@@ -151,6 +157,7 @@ def _expert_ffn(tokens, w_up, w_gate, w_down, activation: str):
     return out.astype(tokens.dtype)
 
 
+@jax.named_scope("moe.experts")
 def _expert_ffn_pallas(tokens, w_up, w_gate, w_down, activation: str):
     from repro.kernels.moe_gemm import ops as moe_ops
 
@@ -160,6 +167,7 @@ def _expert_ffn_pallas(tokens, w_up, w_gate, w_down, activation: str):
 # -- ragged (sort-based, dropless) dispatch ---------------------------------
 
 
+@jax.named_scope("moe.dispatch")
 def _sort_dispatch(flat_e: jax.Array, E: int):
     """Sort-based dispatch: replaces the O(T·k·E) one-hot-cumsum slot
     assignment with an O(T·k·log) argsort into contiguous per-expert row
@@ -175,6 +183,7 @@ def _sort_dispatch(flat_e: jax.Array, E: int):
     return order, inv, offsets
 
 
+@jax.named_scope("moe.experts")
 def _ragged_rows_ffn(xs, w_up, w_gate, w_down, offsets, activation: str):
     """Grouped FFN over expert-sorted rows: always the ragged Pallas kernels
     (custom VJP, fp32 accumulation both directions), whatever kernel the
@@ -194,13 +203,16 @@ def _moe_ragged_local(xt, top_phys, top_w, w_up, w_gate, w_down,
     flat_e = top_phys.reshape(-1)
     flat_w = top_w.reshape(-1)
     order, inv, offsets = _sort_dispatch(flat_e, E)
-    xs = jnp.take(xt, order // k, axis=0)  # (T*k, d) expert-sorted
+    with jax.named_scope("moe.dispatch"):
+        xs = jnp.take(xt, order // k, axis=0)  # (T*k, d) expert-sorted
     ys = _ragged_rows_ffn(xs, w_up, w_gate, w_down, offsets, activation)
-    vals = jnp.take(ys, inv, axis=0)  # back to flat (token, k) order
+    with jax.named_scope("moe.combine"):
+        vals = jnp.take(ys, inv, axis=0)  # back to flat (token, k) order
     keep = jnp.ones_like(flat_e, dtype=bool)
     return _combine_expert_outputs(vals, flat_w, keep, T, k, d)
 
 
+@jax.named_scope("moe.dispatch")
 def _moe_ragged_sharded(xt, top_phys, top_w, wu_f, wg_f, wd_f,
                         activation: str, moe: MoECfg,
                         ep_size: int, capacity: int, a2a, chunks: int = 1,
@@ -326,6 +338,7 @@ def _moe_ragged_sharded(xt, top_phys, top_w, wu_f, wg_f, wd_f,
     return _combine_expert_outputs(vals, flat_w, keep_s[inv], T, k, d)
 
 
+@jax.named_scope("moe.dispatch")
 def _moe_ragged_decode(xt, top_phys, top_w, wu_f, wg_f, wd_f,
                        activation: str, moe: MoECfg,
                        ep_size: int, skip=None):
@@ -382,6 +395,7 @@ def _moe_ragged_decode(xt, top_phys, top_w, wu_f, wg_f, wd_f,
 # interior) remain exact.
 
 
+@jax.named_scope("moe.dispatch")
 def _replica_rows(top_i, replicas, E: int):
     """Per flat (token, k) row: routed-to-a-replica mask and the replica
     channel id (sentinel R for non-replica rows).  ``replicas``: (R,)
@@ -401,6 +415,7 @@ def _replica_rows(top_i, replicas, E: int):
     return rep_row, rchan.astype(jnp.int32)
 
 
+@jax.named_scope("moe.experts")
 def _replica_weights(replicas, assignment, wu_f, wg_f, wd_f, E: int,
                      E_l: int, ep_size: int):
     """Materialize the R replica channels' expert weights on every EP rank.
@@ -429,6 +444,7 @@ def _replica_weights(replicas, assignment, wu_f, wg_f, wd_f, E: int,
     return wu_r, wg_r, wd_r
 
 
+@jax.named_scope("moe.experts")
 def _replica_ffn(xt, rchan, top_k: int, wu_r, wg_r, wd_r, R: int,
                  activation: str, wire_bf16: bool):
     """Ragged FFN over the (token, k) rows routed to replica channels.
@@ -453,6 +469,7 @@ def _replica_ffn(xt, rchan, top_k: int, wu_r, wg_r, wd_r, R: int,
     return jnp.zeros((rchan.shape[0], xt.shape[1]), ys.dtype).at[order].set(ys)
 
 
+@jax.named_scope("moe.exchange")
 def _transport_bf16(a2a_fn, x):
     """Run a dispatch/combine collective with a bf16 payload in BOTH
     directions: the forward cast makes the wire payload bf16, and because
@@ -476,6 +493,7 @@ def _select_a2a(plan: MeshPlan):
     return halo.flat_all_to_all
 
 
+@jax.named_scope("moe.dispatch")
 def _moe_capacity_sharded(buf, wu_f, wg_f, wd_f, activation: str, ffn_fn,
                           ep_size: int, E_l: int, capacity: int, d: int,
                           a2a, chunks: int):
@@ -534,7 +552,8 @@ def moe_ffn_local(
     xt = x.reshape(T, d)
     top_w, top_i, probs, logits = _route(xt, params["w_router"], moe)
     aux, z, counts = _aux_losses(probs, logits, top_i, moe, ())
-    top_phys = params["assignment"][top_i]
+    with jax.named_scope("moe.router"):
+        top_phys = params["assignment"][top_i]
     wg = params.get("w_gate")
     if moe.dispatch == "ragged":
         y = _moe_ragged_local(
@@ -630,19 +649,21 @@ def moe_ffn(
         # Metrics/aux use LOGICAL expert ids; dispatch uses PHYSICAL slots
         # via the migration routing table.
         aux, z, counts = _aux_losses(probs, logits, top_i, moe, metric_axes)
-        top_phys = assignment[top_i]
+        with jax.named_scope("moe.router"):
+            top_phys = assignment[top_i]
 
         capacity = _capacity(T, moe)
 
         # Gather ZeRO-3-sharded expert weights (transpose = reduce-scatter).
         gather_axes = ("data", "tp") if "data" in axes else ("tp",)
-        wu_f = lax.all_gather(wu, gather_axes, axis=2, tiled=True)
-        wg_f = (
-            lax.all_gather(wg, gather_axes, axis=2, tiled=True)
-            if wg is not None
-            else None
-        )
-        wd_f = lax.all_gather(wd, gather_axes, axis=1, tiled=True)
+        with jax.named_scope("moe.experts"):
+            wu_f = lax.all_gather(wu, gather_axes, axis=2, tiled=True)
+            wg_f = (
+                lax.all_gather(wg, gather_axes, axis=2, tiled=True)
+                if wg is not None
+                else None
+            )
+            wd_f = lax.all_gather(wd, gather_axes, axis=1, tiled=True)
 
         # Flat/halo/chunked selection lives in _select_a2a + the plan's
         # a2a_chunks — shared by the capacity and ragged transports.

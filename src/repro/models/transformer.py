@@ -23,6 +23,7 @@ from repro.models import ssm as ssm_lib
 from repro.sharding import MeshPlan
 
 
+@jax.named_scope("block")
 def apply_block(
     block: Tuple[str, str],
     params: Dict[str, Any],
@@ -140,8 +141,9 @@ def stack_forward(
 
     body = _remat(body, plan.remat)
     zero = jnp.float32(0.0)
-    (x, aux, z), loads = lax.scan(
-        body, (x, zero, zero), block_params,
-        unroll=True if unroll else 1,
-    )
+    with jax.named_scope("block"):  # the scan's carries and stacked loads
+        (x, aux, z), loads = lax.scan(
+            body, (x, zero, zero), block_params,
+            unroll=True if unroll else 1,
+        )
     return x, {"moe_aux_loss": aux, "moe_z_loss": z}, (loads if has_moe else None)

@@ -2,7 +2,8 @@
 trace_event export, and model-vs-measured drift tracking.
 
 Instrument with the module-level helpers (no-ops until a launch script
-calls ``obs.configure(...)``):
+calls ``obs.configure(...)``; a live span also marks a ``jax.profiler``
+trace under its name):
 
     from repro import obs
 
@@ -21,7 +22,6 @@ from repro.obs.core import (
     counter,
     gauge,
     get_telemetry,
-    histogram,
     instant,
     set_telemetry,
     span,
@@ -47,7 +47,6 @@ __all__ = [
     "counter",
     "gauge",
     "get_telemetry",
-    "histogram",
     "instant",
     "schedule_lane_events",
     "set_telemetry",

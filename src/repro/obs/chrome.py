@@ -70,7 +70,7 @@ def chrome_trace(
                     "args": dict(attrs),
                 }
             )
-        elif kind in ("counter", "gauge", "hist"):
+        elif kind in ("counter", "gauge"):
             value = ev.get("total", ev.get("value", 0.0))
             out.append(
                 {**base, "ph": "C", "name": ev["name"],

@@ -1,4 +1,4 @@
-"""Structured telemetry core: spans, instants, counters, gauges, histograms.
+"""Structured telemetry core: spans, instants, counters, gauges.
 
 Design constraints (see docs/observability.md):
 
@@ -9,22 +9,26 @@ Design constraints (see docs/observability.md):
   single flag, not an edit.
 - **Thread-safe.**  The checkpoint manager emits ``ckpt.save`` spans from
   its async writer thread while the trainer emits ``train.step`` spans
-  from the main thread.  Sink emission and counter/histogram accumulation
-  are lock-protected; the span *stack* (for nesting depth / parent
+  from the main thread.  Sink emission and counter accumulation are
+  lock-protected; the span *stack* (for nesting depth / parent
   attribution) is thread-local, so concurrent spans never see each other
   as parents.
+- **On the profiler's clock.**  A live span also enters a
+  ``jax.profiler.TraceAnnotation`` of the same name with its constructor
+  attributes, so a ``jax.profiler`` trace shows the program's host spans
+  beside the device ops (``step`` and ``what`` become event stats).
 - **Events are plain dicts** (JSON-ready), one schema for every sink:
 
-      {"name": str, "kind": "span"|"instant"|"counter"|"gauge"|"hist",
+      {"name": str, "kind": "span"|"instant"|"counter"|"gauge",
        "ts": float seconds since the Telemetry epoch,
        "dur": float seconds (spans only),
        "tid": int python thread id, "depth": int, "parent": str|None,
-       "value"/"total": numbers (counter/gauge/hist),
+       "value"/"total": numbers (counter/gauge),
        "attrs": {str: json-able}}
 
-The module-level ``span``/``instant``/``counter``/``gauge``/``histogram``
-helpers delegate to a process-global ``Telemetry`` (disabled by default)
-that ``configure()`` swaps in — library code instruments against the
+The module-level ``span``/``instant``/``counter``/``gauge`` helpers
+delegate to a process-global ``Telemetry`` (disabled by default) that
+``configure()`` swaps in — library code instruments against the
 module API and launch scripts decide whether anything is recorded.
 """
 
@@ -34,13 +38,14 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "Telemetry",
     "configure",
     "counter",
     "gauge",
     "get_telemetry",
-    "histogram",
     "instant",
     "set_telemetry",
     "span",
@@ -73,9 +78,10 @@ class _Span:
     """Live span: records wall time between ``__enter__`` and ``__exit__``
     and emits one ``kind="span"`` event on exit (including on exception,
     in which case the event carries an ``error`` attr and the exception
-    propagates)."""
+    propagates).  While open it also holds a profiler ``TraceAnnotation``
+    of the same name and constructor attributes."""
 
-    __slots__ = ("_tel", "name", "attrs", "t0", "depth", "parent")
+    __slots__ = ("_tel", "name", "attrs", "t0", "depth", "parent", "_ann")
 
     def __init__(self, tel: "Telemetry", name: str, attrs: Dict[str, Any]):
         self._tel = tel
@@ -84,6 +90,7 @@ class _Span:
         self.t0 = 0.0
         self.depth = 0
         self.parent: Optional[str] = None
+        self._ann = TraceAnnotation(name, **attrs)
 
     def set(self, **attrs) -> "_Span":
         """Merge attrs into the span mid-flight (e.g. byte counts known
@@ -96,11 +103,13 @@ class _Span:
         self.depth = len(stack)
         self.parent = stack[-1].name if stack else None
         stack.append(self)
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
         stack = self._tel._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -123,9 +132,8 @@ class _Span:
 
 class Telemetry:
     """Event router: validates nothing, timestamps everything, fans events
-    out to ``sinks`` under a lock.  Counters and histograms additionally
-    accumulate in-process so totals/summaries survive even with no sink
-    attached."""
+    out to ``sinks`` under a lock.  Counters additionally accumulate
+    in-process so totals survive even with no sink attached."""
 
     def __init__(self, enabled: bool = True, sinks: Optional[List] = None):
         self.enabled = enabled
@@ -134,7 +142,6 @@ class Telemetry:
         self._lock = threading.Lock()
         self._local = threading.local()
         self.counters: Dict[str, float] = {}
-        self.hists: Dict[str, List[float]] = {}
 
     # -- internals ---------------------------------------------------------
 
@@ -223,35 +230,6 @@ class Telemetry:
             }
         )
 
-    def histogram(self, name: str, value: float, **attrs) -> None:
-        if not self.enabled:
-            return
-        with self._lock:
-            self.hists.setdefault(name, []).append(float(value))
-        self._emit(
-            {
-                "name": name,
-                "kind": "hist",
-                "ts": time.perf_counter() - self.epoch,
-                "tid": threading.get_ident(),
-                "value": float(value),
-                "attrs": attrs,
-            }
-        )
-
-    def hist_summary(self, name: str) -> Optional[Dict[str, float]]:
-        """min/mean/max/n over every recorded ``histogram(name, ...)``."""
-        with self._lock:
-            vals = list(self.hists.get(name, ()))
-        if not vals:
-            return None
-        return {
-            "n": len(vals),
-            "min": min(vals),
-            "max": max(vals),
-            "mean": sum(vals) / len(vals),
-        }
-
     def close(self) -> None:
         with self._lock:
             for sink in self.sinks:
@@ -299,7 +277,3 @@ def counter(name: str, inc: float = 1.0, **attrs) -> None:
 
 def gauge(name: str, value: float, **attrs) -> None:
     _GLOBAL.gauge(name, value, **attrs)
-
-
-def histogram(name: str, value: float, **attrs) -> None:
-    _GLOBAL.histogram(name, value, **attrs)

@@ -64,6 +64,7 @@ def adamw_init(params, optimizer_dtype=jnp.float32) -> Dict[str, Any]:
     }
 
 
+@jax.named_scope("optimizer")
 def adamw_update(
     cfg: OptimizerConfig,
     params,

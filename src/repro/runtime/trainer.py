@@ -141,11 +141,13 @@ class Trainer:
         self.host_fetches = 0
         self._stop = False
 
-    def _fetch(self, x):
-        """Blocking device->host fetch of a metric value (counted)."""
+    def _fetch(self, x, what: str):
+        """Blocking device->host fetch of a metric value (counted), marked
+        ``train.fetch`` with the value's name."""
         self.host_fetches += 1
         obs.counter("train.host_fetches")
-        return jax.device_get(x)
+        with obs.span("train.fetch", what=what):
+            return jax.device_get(x)
 
     # -- fault handling ------------------------------------------------------
 
@@ -306,8 +308,24 @@ class Trainer:
         # -- apply: ONE permutation pass over params and both Adam moment
         # trees (they must move with their weights or the optimizer
         # mismatches history), then the routing tables.
+        with obs.span("train.migrate", step=step):
+            new_state = self._apply_migration(state, plans, moe_positions,
+                                              have_reps)
+        dt = time.perf_counter() - t0
+        record.update({"seconds": dt, "applied": True})
+        self.migrations.append(record)
+        self.log(
+            f"[migrate] step={step} imbalance={imb:.2f}->{imb_post:.2f} "
+            f"swaps={total_swaps} replicas={n_replicas} ({dt*1e3:.0f} ms)"
+        )
+        return new_state
+
+    def _apply_migration(self, state, plans, moe_positions, have_reps):
+        """Permute the expert tensors of params and both Adam moments by
+        ``plans`` and re-place the result on the incoming shardings."""
         import jax.numpy as jnp
 
+        params = state["params"]
         new_blocks = list(params["blocks"])
         new_m_blocks = list(state["m"]["blocks"])
         new_v_blocks = list(state["v"]["blocks"])
@@ -341,16 +359,7 @@ class Trainer:
         # feeding off-plan leaves back into the step would either
         # recompile or silently gather.
         live_shardings = jax.tree.map(lambda x: x.sharding, state)
-        new_state = jax.device_put(new_state, live_shardings)
-        dt = time.perf_counter() - t0
-        record.update({"seconds": dt, "applied": True})
-        obs.histogram("train.migrate_s", dt, step=step)
-        self.migrations.append(record)
-        self.log(
-            f"[migrate] step={step} imbalance={imb:.2f}->{imb_post:.2f} "
-            f"swaps={total_swaps} replicas={n_replicas} ({dt*1e3:.0f} ms)"
-        )
-        return new_state
+        return jax.device_put(new_state, live_shardings)
 
     # -- recovery helpers ------------------------------------------------------
 
@@ -458,7 +467,7 @@ class Trainer:
                 + (f"V={plan.vstages} " if plan.vstages > 1 else "")
                 + f"(M={plan.microbatches or 2 * plan.pp})"
             )
-        start_step = int(self._fetch(state["step"]))
+        start_step = int(self._fetch(state["step"], "step"))
         if self.ckpt is not None:
             try:
                 abstract, shardings = self._abstract_and_shardings(state)
@@ -504,11 +513,12 @@ class Trainer:
                 # true wall time.  loss/grad_norm stay on device except on
                 # log steps and skips — fetching them every step serializes
                 # the device against the host (the old hot-loop bug).
-                skipped = bool(self._fetch(metrics.get("skipped", 0)))
+                skipped = bool(
+                    self._fetch(metrics.get("skipped", 0), "skipped")
+                )
                 sp.set(skipped=skipped)
             dt = time.perf_counter() - t0
             self.step_times.append(dt)
-            obs.histogram("train.step_s", dt, step=step)
             # Straggler detection on the step-time EMA.
             if len(self.step_times) > 5:
                 ema = float(np.mean(self.step_times[-20:-1]))
@@ -522,8 +532,8 @@ class Trainer:
                 # The sentinel refused the update (state unchanged): count
                 # the streak, roll back to the last good checkpoint once it
                 # crosses the budget, and re-enter AT the restored step.
-                loss = float(self._fetch(metrics["loss"]))
-                gnorm = float(self._fetch(metrics["grad_norm"]))
+                loss = float(self._fetch(metrics["loss"], "loss"))
+                gnorm = float(self._fetch(metrics["grad_norm"], "grad_norm"))
                 obs.instant(
                     "train.anomaly", step=step, loss=loss, grad_norm=gnorm
                 )
@@ -547,7 +557,9 @@ class Trainer:
                 # Migration controller EMA: stays per-step on purpose — the
                 # SIGTERM-restart tests pin the controller bit-exact, and
                 # thinning the EMA feed would change its trajectory.
-                loads = np.asarray(self._fetch(metrics["expert_load"]))
+                loads = np.asarray(
+                    self._fetch(metrics["expert_load"], "expert_load")
+                )
                 # (reps, n_moe_pos, E) -> stack order (pos-major, rep)
                 loads = np.concatenate(
                     [loads[:, i, :] for i in range(loads.shape[1])]
@@ -555,13 +567,14 @@ class Trainer:
                 self.load_stats.update(loads)
             state = self._maybe_migrate(state, step + 1)
             if step % self.cfg.log_every == 0:
-                loss = float(self._fetch(metrics["loss"]))
-                self.losses.append((step, loss))
-                obs.gauge("train.loss", loss, step=step)
-                self.log(
-                    f"[train] step={step} loss={loss:.4f} "
-                    f"({dt*1e3:.0f} ms/step)"
-                )
+                with obs.span("train.log", step=step):
+                    loss = float(self._fetch(metrics["loss"], "loss"))
+                    self.losses.append((step, loss))
+                    obs.gauge("train.loss", loss, step=step)
+                    self.log(
+                        f"[train] step={step} loss={loss:.4f} "
+                        f"({dt*1e3:.0f} ms/step)"
+                    )
             if self.ckpt is not None and self.ckpt.should_save(step + 1):
                 self.ckpt.save(
                     step + 1, state, blocking=False,

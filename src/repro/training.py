@@ -143,6 +143,7 @@ def make_train_step(
     compute_dtype = DTYPES[lm.plan.compute_dtype]
     pipelined = lm.plan.pp_axis is not None and lm.plan.pp > 1
 
+    @jax.named_scope("optimizer")
     def cast(params):
         return jax.tree.map(
             lambda p: p.astype(compute_dtype)
@@ -190,13 +191,14 @@ def make_train_step(
             metrics.pop("expert_load", None)
         new_state = {"params": new_params, **new_opt}
         # Anomaly sentinel: a poisoned update must not reach the state.
-        ok = jnp.isfinite(loss) & jnp.isfinite(opt_metrics["grad_norm"])
-        if gnorm_skip_cap is not None:
-            ok = ok & (opt_metrics["grad_norm"] < gnorm_skip_cap)
-        new_state = jax.tree.map(
-            lambda new, old: jnp.where(ok, new, old), new_state, state
-        )
-        metrics["skipped"] = jnp.logical_not(ok).astype(jnp.int32)
+        with jax.named_scope("optimizer"), jax.named_scope("sentinel"):
+            ok = jnp.isfinite(loss) & jnp.isfinite(opt_metrics["grad_norm"])
+            if gnorm_skip_cap is not None:
+                ok = ok & (opt_metrics["grad_norm"] < gnorm_skip_cap)
+            new_state = jax.tree.map(
+                lambda new, old: jnp.where(ok, new, old), new_state, state
+            )
+            metrics["skipped"] = jnp.logical_not(ok).astype(jnp.int32)
         return new_state, metrics
 
     return train_step

@@ -77,15 +77,9 @@ def test_counters_gauges_histograms_accumulate():
     tel.counter("c")
     tel.counter("c", 2.0)
     tel.gauge("g", 7.5)
-    for v in (1.0, 2.0, 3.0):
-        tel.histogram("h", v)
     assert tel.counters["c"] == 3.0
-    assert tel.hist_summary("h") == {
-        "n": 3, "min": 1.0, "max": 3.0, "mean": 2.0,
-    }
-    assert tel.hist_summary("missing") is None
     kinds = [e["kind"] for e in ring.events()]
-    assert kinds == ["counter", "counter", "gauge", "hist", "hist", "hist"]
+    assert kinds == ["counter", "counter", "gauge"]
     # counter events carry the running total
     assert ring.events()[1]["total"] == 3.0
 
@@ -103,9 +97,8 @@ def test_disabled_mode_is_null_singleton_and_silent():
     tel.instant("i")
     tel.counter("c")
     tel.gauge("g", 1.0)
-    tel.histogram("h", 1.0)
     assert ring.events() == []
-    assert tel.counters == {} and tel.hists == {}
+    assert tel.counters == {}
 
 
 def test_disabled_mode_zero_allocation():
@@ -127,6 +120,58 @@ def test_disabled_mode_zero_allocation():
     tracemalloc.stop()
     retained = sum(d.size_diff for d in snap2.compare_to(snap1, "lineno"))
     assert retained == 0, f"disabled telemetry retained {retained}B in obs/core"
+
+
+def _profiled(fn, tmp_path):
+    """Run ``fn`` under a ``jax.profiler`` trace; return the host-plane
+    events as ``(name, start_ns, dur_ns, stats)``."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    return [
+        (ev.name, ev.start_ns, ev.duration_ns, dict(ev.stats))
+        for plane in ProfileData.from_file(str(path)).planes
+        if plane.name.startswith("/host")
+        for line in plane.lines
+        for ev in line.events
+    ]
+
+
+def test_live_span_is_on_the_profiler_clock(tmp_path):
+    """A live span enters a TraceAnnotation of its name with its
+    constructor attributes; attrs set later stay in the event only."""
+    tel, ring = _tel()
+
+    def work():
+        with tel.span("t.outer", step=3) as sp:
+            with tel.span("t.inner", what="skipped"):
+                pass
+            sp.set(late=1)
+
+    evs = {n: (s, d, st) for n, s, d, st in _profiled(work, tmp_path)
+           if n.startswith("t.")}
+    assert set(evs) == {"t.outer", "t.inner"}
+    assert evs["t.outer"][2] == {"step": 3}
+    assert evs["t.inner"][2] == {"what": "skipped"}
+    (s0, d0, _), (s1, d1, _) = evs["t.outer"], evs["t.inner"]
+    assert s0 <= s1 and s1 + d1 <= s0 + d0
+    assert ring.events()[-1]["attrs"] == {"step": 3, "late": 1}
+
+
+def test_disabled_span_stays_off_the_profiler_clock(tmp_path):
+    tel = obs.Telemetry(enabled=False)
+
+    def work():
+        with tel.span("t.off", step=1):
+            pass
+
+    assert not [e for e in _profiled(work, tmp_path) if e[0] == "t.off"]
 
 
 # -- thread safety -----------------------------------------------------------
@@ -520,6 +565,32 @@ def test_trainer_host_fetch_cadence():
     assert out["last_step"] == 7 and not out["anomalies"]
     # 1 (start_step) + 8 (skipped flag) + 2 (loss at steps 0 and 4)
     assert trainer.host_fetches == 1 + 8 + 2
+
+
+def test_trainer_spans_name_the_host_work():
+    """Every blocking fetch is a ``train.fetch`` span naming its value,
+    inside the step span for the skip flag and inside ``train.log`` for
+    the loss; the data and step spans carry their step."""
+    ring = obs.RingBufferSink()
+    prev = obs.set_telemetry(obs.Telemetry(sinks=[ring]))
+    try:
+        trainer, _ = _fit_tiny_trainer(total_steps=3, log_every=2)
+    finally:
+        obs.set_telemetry(prev)
+    spans = [e for e in ring.events() if e["kind"] == "span"]
+    fetches = [e for e in spans if e["name"] == "train.fetch"]
+    assert [e["attrs"]["what"] for e in fetches] == [
+        "step", "skipped", "loss", "skipped", "skipped", "loss"]
+    assert len(fetches) == trainer.host_fetches
+    assert {e["parent"] for e in fetches if e["attrs"]["what"] == "skipped"
+            } == {"train.step"}
+    assert {e["parent"] for e in fetches if e["attrs"]["what"] == "loss"
+            } == {"train.log"}
+    for name in ("train.data", "train.step"):
+        assert [e["attrs"]["step"] for e in spans if e["name"] == name] \
+            == [0, 1, 2]
+    assert [e["attrs"]["step"] for e in spans if e["name"] == "train.log"] \
+        == [0, 2]
 
 
 def test_trainer_step_not_retraced():
