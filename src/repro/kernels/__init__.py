@@ -2,7 +2,9 @@
 
 * ``moe_gemm``        -- grouped (per-expert) GEMM; the tall-and-skinny
                          regime of fine-grained MoE (paper Fig 4)
-* ``flash_attention`` -- block-tiled attention (paper SSIV-A benchmarks it)
+* ``flash_attention`` -- block-tiled attention (paper SSIV-A benchmarks it);
+                         ``ops.causal_attention`` trains causal attention
+                         through JAX's block-sparse TPU splash kernel
 * ``ssd``             -- Mamba2 SSD intra-chunk kernel (mamba2/jamba archs)
 
 Each kernel ships with ``ops.py`` (the jit'd public wrapper with an

@@ -1,9 +1,11 @@
 """Core transformer layers: norms, rotary embeddings, GQA attention, FFN.
 
-All functions are pure; parameters are plain dict pytrees.  Attention has a
-selectable implementation: "xla" (jnp reference, used by dry-runs — GSPMD
-inserts the K/V all-gathers for sequence-sharded inputs) or "pallas"
-(flash-attention TPU kernel from repro.kernels, validated in interpret mode).
+All functions are pure; parameters are plain dict pytrees.  Causal
+attention with no cache trains through a block-sparse flash kernel where
+``attention_path`` finds a one-device TPU; elsewhere ``impl`` selects "xla"
+(jnp reference, used by dry-runs — GSPMD inserts the K/V all-gathers for
+sequence-sharded inputs) or "pallas" (the forward-only flash-attention hand
+kernel from repro.kernels, validated in interpret mode).
 """
 
 from __future__ import annotations
@@ -16,6 +18,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
+
+from repro import obs
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -227,11 +231,29 @@ def attention(
     return out.reshape(b, s_q, hq, d)
 
 
+def attention_path(backend: str, s: int, *, window: Optional[int],
+                   cached: bool, mesh_size: int) -> str:
+    """Which attention ``attention_proj`` runs: "flash" where the causal
+    block-sparse kernel (``kernels.flash_attention.ops.causal_attention``)
+    can run — a TPU, no cache, no window, ``s`` a multiple of its 128-row
+    tiles and one device (a mesh would need the kernel under
+    ``shard_map``) — and "xla" elsewhere."""
+    if (backend == "tpu" and not cached and window is None and s % 128 == 0
+            and mesh_size == 1):
+        return "flash"
+    return "xla"
+
+
 @jax.named_scope("attention")
 def attention_proj(params, x, cfg, positions, *, impl="xla", window=None,
                    cache=None, cache_index=None, return_kv=False, plan=None):
     """Full attention sub-layer: QKV proj -> rope -> attention -> out proj,
     under the ``attention`` scope (the compiled step's op metadata).
+
+    The attention itself is chosen by backend and shape (``attention_path``,
+    counted as ``attention.path`` at trace time): the trainable flash kernel
+    where it can run, else ``impl`` selects the opt-in hand kernel
+    ("pallas", forward only) or the XLA reference ("xla").
 
     cache: optional dict {"k": (b, S, hkv, d), "v": ...} — decode path.
     return_kv=True additionally returns the freshly computed K/V (prefill).
@@ -251,7 +273,20 @@ def attention_proj(params, x, cfg, positions, *, impl="xla", window=None,
     k = positional_embed(k, positions, cfg.rope_type, cfg.rope_theta)
 
     new_cache = None
-    if cache is not None and "block_table" in cache:
+    path = attention_path(
+        jax.default_backend(), s, window=window, cached=cache is not None,
+        mesh_size=1 if plan is None else plan.mesh.size,
+    )
+    obs.counter("attention.path", path=path)
+    if path == "flash":
+        from repro.kernels.flash_attention import ops as fa_ops
+
+        out = fa_ops.causal_attention(
+            q, k, v, logit_softcap=cfg.attn_logit_softcap
+        )
+        if return_kv:
+            new_cache = {"k": k, "v": v}
+    elif cache is not None and "block_table" in cache:
         # Paged decode (continuous batching): append the new K/V rows to
         # their (page, slot) cells, materialize the prefix via the block
         # table, attend with per-sequence offsets/lengths.  Inactive batch

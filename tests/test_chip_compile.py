@@ -112,3 +112,19 @@ def test_flash_attention_forward_compiles(one_chip):
         return fa_ops.flash_attention(q, k, v, causal=True, interpret=False)
 
     _assert_mosaic(fwd, q, kv, kv)
+
+
+def test_causal_attention_grad_compiles(one_chip):
+    """The trainable splash path at the cell's shapes: the forward and the
+    fused dq/dkv kernel both lower through Mosaic."""
+    bf = jnp.bfloat16
+    q = _spec((4, S, HQ, DH), bf, one_chip)
+    kv = _spec((4, S, HKV, DH), bf, one_chip)
+
+    def loss(q, k, v):
+        out = fa_ops.causal_attention(q, k, v, interpret=False)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2

@@ -198,6 +198,120 @@ def test_flash_attention(b, hq, hkv, s, d, window, cap, dtype):
 
 
 @pytest.mark.parametrize(
+    "b,hq,hkv,s,d,cap,dtype",
+    [
+        (1, 6, 2, 512, 64, None, jnp.bfloat16),  # GQA
+        (2, 4, 4, 256, 128, None, jnp.float32),  # MHA
+        (1, 4, 2, 256, 64, 30.0, jnp.bfloat16),  # softcap
+    ],
+)
+def test_causal_attention_matches_xla(b, hq, hkv, s, d, cap, dtype):
+    """The trainable splash entry point against ``layers.attention`` (one q
+    chunk): the output and the gradients w.r.t. q, k and v."""
+    from repro.models import layers as L
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(ks[0], (b, s, hq, d), dtype)
+    k = jax.random.normal(ks[1], (b, s, hkv, d), dtype)
+    v = jax.random.normal(ks[2], (b, s, hkv, d), dtype)
+    g = jax.random.normal(ks[3], (b, s, hq, d), dtype)
+
+    def run(attn):
+        def loss(q, k, v):
+            out = attn(q, k, v)
+            return jnp.sum((out * g).astype(jnp.float32)), out
+
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))(
+            q, k, v
+        )
+
+    (_, out), grads = run(lambda q, k, v: fa_ops.causal_attention(
+        q, k, v, logit_softcap=cap, interpret=True))
+    (_, ref), ref_grads = run(lambda q, k, v: L.attention(
+        q, k, v, logit_softcap=cap))
+    for got, want in [(out, ref), *zip(grads, ref_grads)]:
+        want = np.asarray(want, np.float32)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), want,
+            rtol=TOL[dtype], atol=TOL[dtype] * np.abs(want).max(),
+        )
+
+
+@pytest.mark.parametrize("s,block", [(4096, 1024), (1536, 512), (256, 256),
+                                     (384, 128), (640, 128)])
+def test_causal_attention_block_divides_s(s, block):
+    assert fa_ops.block_size(s) == block
+
+
+FLASH_OK = dict(backend="tpu", s=4096, window=None, cached=False,
+                mesh_size=1)
+
+
+@pytest.mark.parametrize(
+    "change,path",
+    [
+        ({}, "flash"),
+        ({"s": 128}, "flash"),
+        ({"window": 4096}, "xla"),
+        ({"cached": True}, "xla"),
+        ({"s": 4000}, "xla"),
+        ({"mesh_size": 4}, "xla"),
+        ({"backend": "cpu"}, "xla"),
+    ],
+)
+def test_attention_path(change, path):
+    """The routing is a function of what the code observes: backend,
+    sequence length, window, cache and mesh size."""
+    from repro.models import layers as L
+
+    kw = {**FLASH_OK, **change}
+    backend, s = kw.pop("backend"), kw.pop("s")
+    assert L.attention_path(backend, s, **kw) == path
+
+
+def test_attention_proj_on_cpu_takes_xla_path():
+    """On the CPU backend ``attention_proj`` runs the XLA formula, bit for
+    bit, and counts one ``attention.path`` of ``xla`` per traced call."""
+    import types
+
+    from repro import obs
+    from repro.models import layers as L
+
+    cfg = types.SimpleNamespace(num_heads=4, num_kv_heads=2, head_dim=16,
+                                rope_type="rope", rope_theta=1e4,
+                                attn_logit_softcap=None)
+    b, s, dm = 2, 256, 32
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    params = {
+        "wq": jax.random.normal(ks[0], (dm, 64)) * 0.2,
+        "wk": jax.random.normal(ks[1], (dm, 32)) * 0.2,
+        "wv": jax.random.normal(ks[2], (dm, 32)) * 0.2,
+        "wo": jax.random.normal(ks[3], (64, dm)) * 0.2,
+    }
+    x = jax.random.normal(ks[4], (b, s, dm))
+    pos = jnp.broadcast_to(jnp.arange(s), (b, s))
+    ring = obs.RingBufferSink()
+    prev = obs.set_telemetry(obs.Telemetry(sinks=[ring]))
+    try:
+        out, _ = L.attention_proj(params, x, cfg, pos)
+    finally:
+        obs.set_telemetry(prev)
+    paths = [e["attrs"]["path"] for e in ring.events()
+             if e["kind"] == "counter" and e["name"] == "attention.path"]
+    assert paths == ["xla"]
+
+    def heads(w):
+        y = jnp.einsum("bsd,dk->bsk", x, w)
+        return y.reshape(b, s, -1, 16)
+
+    q = L.apply_rope(heads(params["wq"]), pos, 1e4)
+    k = L.apply_rope(heads(params["wk"]), pos, 1e4)
+    ref = L.attention(q, k, heads(params["wv"]))
+    ref = jnp.einsum("bsk,kd->bsd", ref.reshape(b, s, 64), params["wo"])
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+@pytest.mark.parametrize(
     "b,nc,cl,h,p,n", [(1, 2, 32, 4, 16, 8), (2, 2, 64, 8, 32, 16)]
 )
 def test_ssd_intra_chunk(b, nc, cl, h, p, n):
