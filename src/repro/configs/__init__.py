@@ -3,6 +3,7 @@
 from repro.configs.base import (
     ArchConfig,
     Block,
+    MLACfg,
     MoECfg,
     SSMCfg,
     ShapeSpec,
@@ -24,6 +25,7 @@ from repro.configs.gemma2_9b import CONFIG as GEMMA2_9B
 from repro.configs.yi_9b import CONFIG as YI_9B
 from repro.configs.qwen2_vl_7b import CONFIG as QWEN2_VL_7B
 from repro.configs.jamba_1_5_large_398b import CONFIG as JAMBA_1_5_LARGE
+from repro.configs import moonlight_16b_a3b
 from repro.configs import piper_paper
 
 ARCHS = {
@@ -43,6 +45,8 @@ ARCHS = {
         piper_paper.M10B_E128,
         piper_paper.M10B_E256,
         piper_paper.SUPER_545B,
+        moonlight_16b_a3b.CONFIG,
+        moonlight_16b_a3b.CONFIG_EP8,
     )
 }
 
@@ -72,7 +76,7 @@ def list_archs():
 
 
 __all__ = [
-    "ArchConfig", "Block", "MoECfg", "SSMCfg", "ShapeSpec", "SHAPES",
+    "ArchConfig", "Block", "MLACfg", "MoECfg", "SSMCfg", "ShapeSpec", "SHAPES",
     "TRAIN_4K", "PREFILL_32K", "DECODE_32K", "LONG_500K", "shape_applicable",
     "ARCHS", "ASSIGNED", "get_arch", "list_archs",
 ]

@@ -84,10 +84,48 @@ class MoECfg:
     # replicated expert's rows compute source-locally on every EP rank —
     # off the a2a wire — splitting its load across groups by token origin.
     max_replicas: int = 0
+    # Router scoring: "softmax" (top-k of the softmax, renormalised) or
+    # "sigmoid" (DeepSeek-V3: top-k of sigmoid scores plus a selection
+    # bias, the weights being the unbiased scores of the chosen experts,
+    # renormalised and times ``routed_scale``).
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+    # Auxiliary-loss-free balancing (DeepSeek-V3 §2.1.2): after each step
+    # every expert's selection bias moves by ``bias_update_speed`` towards
+    # the mean load; > 0 adds the layer's int32 "router_bias" leaf, which
+    # counts those moves.
+    bias_update_speed: float = 0.0
+    # Balance loss: per token group (Switch) or per sequence (DeepSeek-V3's
+    # sequence-wise loss on normalised sigmoid scores).
+    seq_aux: bool = False
+    # One chip's share of a layer spread over ``ep_share`` chips: the
+    # router keeps all ``num_experts`` outputs, this chip holds experts
+    # [ep_rank * E/ep_share, (ep_rank + 1) * E/ep_share) and the layer
+    # returns their part of the output (plus the shared experts).
+    ep_share: int = 1
+    ep_rank: int = 0
 
     def __post_init__(self):
         assert self.dispatch in DISPATCH_MODES, self.dispatch
         assert self.max_replicas >= 0, self.max_replicas
+        assert self.scoring in ("softmax", "sigmoid"), self.scoring
+        assert self.bias_update_speed == 0 or self.scoring == "sigmoid"
+        assert self.num_experts % self.ep_share == 0, (
+            self.num_experts, self.ep_share)
+        assert 0 <= self.ep_rank < self.ep_share, self.ep_rank
+        if self.ep_share > 1:
+            # A share holds a fixed slice of the experts: no replica may
+            # land here and only the dropless path computes a slice.
+            assert self.max_replicas == 0, "a share holds no replicas"
+            assert self.dispatch == "ragged", "a share needs ragged dispatch"
+
+    @property
+    def experts_held(self) -> int:
+        return self.num_experts // self.ep_share
+
+    @property
+    def first_held(self) -> int:
+        return self.ep_rank * self.experts_held
 
 
 @dataclass(frozen=True)
@@ -105,8 +143,26 @@ class SSMCfg:
         return (self.expand * d_model) // self.head_dim
 
 
+@dataclass(frozen=True)
+class MLACfg:
+    """DeepSeek-V2/V3 multi-head latent attention (``q_lora_rank`` null:
+    queries are projected directly).  Keys and values come from one
+    latent of ``kv_lora_rank`` per token (RMS-normed, then projected up to
+    every head's ``qk_nope_head_dim`` key and ``v_head_dim`` value) plus a
+    ``qk_rope_head_dim`` rotary key that all heads share."""
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
 # Per-layer block description: (mixer, ffn)
-#   mixer: "attn" | "attn_local" | "mamba"
+#   mixer: "attn" | "attn_local" | "mla" | "mamba"
 #   ffn:   "dense" | "moe" | "none"
 Block = Tuple[str, str]
 
@@ -133,6 +189,11 @@ class ArchConfig:
     block_pattern: Tuple[Block, ...]
     moe: Optional[MoECfg] = None
     ssm: Optional[SSMCfg] = None
+    mla: Optional[MLACfg] = None
+    # Leading layers with a dense FFN before the tiled ``block_pattern``
+    # (DeepSeek's ``first_k_dense_replace``); their mixer is the pattern's
+    # first.
+    first_k_dense: int = 0
     # attention details
     rope_type: str = "rope"  # rope | mrope | none
     rope_theta: float = 10_000.0
@@ -158,15 +219,29 @@ class ArchConfig:
 
     def __post_init__(self):
         assert self.num_heads % self.num_kv_heads == 0, self.name
-        assert self.num_layers % len(self.block_pattern) == 0, (
-            f"{self.name}: num_layers={self.num_layers} not a multiple of "
+        body = self.num_layers - self.first_k_dense
+        assert body > 0 and body % len(self.block_pattern) == 0, (
+            f"{self.name}: num_layers={self.num_layers} less "
+            f"first_k_dense={self.first_k_dense} is not a multiple of "
             f"pattern period {len(self.block_pattern)}"
         )
+        if any(m == "mla" for m, _ in self.block_pattern):
+            assert self.mla is not None and self.num_kv_heads == self.num_heads
+            assert self.head_dim == self.mla.qk_head_dim, self.name
+
+    @property
+    def reps(self) -> int:
+        """Repetitions of ``block_pattern`` after the dense prefix."""
+        return (self.num_layers - self.first_k_dense) // len(self.block_pattern)
+
+    @property
+    def prefix_block(self) -> Block:
+        return (self.block_pattern[0][0], "dense")
 
     @property
     def layers(self) -> Tuple[Block, ...]:
-        reps = self.num_layers // len(self.block_pattern)
-        return self.block_pattern * reps
+        return ((self.prefix_block,) * self.first_k_dense
+                + self.block_pattern * self.reps)
 
     @property
     def pattern_period(self) -> int:
@@ -178,7 +253,8 @@ class ArchConfig:
 
     @property
     def num_attn_layers(self) -> int:
-        return sum(1 for m, _ in self.layers if m.startswith("attn"))
+        return sum(1 for m, _ in self.layers
+                   if m.startswith("attn") or m == "mla")
 
     @property
     def num_mamba_layers(self) -> int:
@@ -198,6 +274,16 @@ class ArchConfig:
         d, hq, hkv = self.d_model, self.q_dim, self.kv_dim
         return d * hq + 2 * d * hkv + hq * d  # Wq, Wk, Wv, Wo
 
+    def mla_params(self) -> int:
+        """Wq, the latent down-projection (with the shared rope key), the
+        latent's norm scale, the up-projection to keys and values, Wo."""
+        m, d, H = self.mla, self.d_model, self.num_heads
+        return (d * H * m.qk_head_dim
+                + d * (m.kv_lora_rank + m.qk_rope_head_dim)
+                + m.kv_lora_rank
+                + m.kv_lora_rank * H * (m.qk_nope_head_dim + m.v_head_dim)
+                + H * m.v_head_dim * d)
+
     @property
     def n_mat(self) -> int:
         """Weight matrices per FFN (paper Table II: 3 for SwiGLU)."""
@@ -212,7 +298,7 @@ class ArchConfig:
         expert = self.n_mat * self.d_model * m.d_ff
         router = self.d_model * m.num_experts
         shared = m.num_shared_experts * expert
-        return m.num_experts * expert + shared + router
+        return m.experts_held * expert + shared + router
 
     def mamba_params(self) -> int:
         assert self.ssm is not None
@@ -232,6 +318,8 @@ class ArchConfig:
         p = 2 * self.d_model  # two RMSNorm scales
         if mixer.startswith("attn"):
             p += self.attn_params()
+        elif mixer == "mla":
+            p += self.mla_params()
         elif mixer == "mamba":
             p += self.mamba_params()
         if ffn == "dense":
@@ -249,14 +337,21 @@ class ArchConfig:
         return body + embed + head + self.d_model  # final norm
 
     def active_params(self) -> int:
-        """Parameters touched per token (MoE: top-k + shared experts only)."""
+        """Parameters a token's matmuls touch, with the norm scales (MoE:
+        top-k + shared experts only; on a share, the held experts at their
+        expected top_k * held / E routed rows a token).  An untied input
+        embedding is a gather of one row, so its table does not count; a
+        tied one counts once, as the head."""
         total = self.total_params()
+        if not self.tie_embeddings:
+            total -= self.vocab_size * self.d_model
         if self.moe is None:
             return total
         m = self.moe
         expert = self.n_mat * self.d_model * m.d_ff
-        inactive = (m.num_experts - m.top_k) * expert * self.num_moe_layers
-        return total - inactive
+        held = m.experts_held * expert
+        active = m.top_k * held // m.num_experts
+        return total - (held - active) * self.num_moe_layers
 
     # -- utilities ----------------------------------------------------------
 
@@ -267,12 +362,25 @@ class ArchConfig:
     def replace(self, **kw) -> "ArchConfig":
         return dataclasses.replace(self, **kw)
 
+    def share(self, chips: int, rank: int = 0) -> "ArchConfig":
+        """What one of ``chips`` chips that share each layer holds: its
+        ``1/chips`` of every MoE layer's experts (the router keeps its
+        width) and of the vocabulary."""
+        assert self.moe is not None, self.name
+        return self.replace(
+            name=f"{self.name}-ep{chips}" if rank == 0
+            else f"{self.name}-ep{chips}r{rank}",
+            vocab_size=self.vocab_size // chips,
+            moe=dataclasses.replace(self.moe, ep_share=chips, ep_rank=rank,
+                                    dispatch="ragged"),
+        )
+
     def reduced(self) -> "ArchConfig":
         """A tiny same-family config for CPU smoke tests."""
         period = len(self.block_pattern)
-        n_layers = period * min(2, self.num_layers // period)
+        n_layers = period * min(2, self.reps) + self.first_k_dense
         kw = dict(
-            num_layers=max(n_layers, period),
+            num_layers=max(n_layers, period + self.first_k_dense),
             d_model=64,
             num_heads=4,
             num_kv_heads=2,
@@ -287,7 +395,14 @@ class ArchConfig:
                 num_experts=min(self.moe.num_experts, 8),
                 top_k=min(self.moe.top_k, 2),
                 d_ff=64,
+                num_shared_experts=min(self.moe.num_shared_experts, 1),
+                ep_share=min(self.moe.ep_share, 4),
+                ep_rank=0,
             )
+        if self.mla is not None:
+            kw.update(num_heads=2, num_kv_heads=2, head_dim=24,
+                      mla=MLACfg(kv_lora_rank=16, qk_nope_head_dim=16,
+                                 qk_rope_head_dim=8, v_head_dim=16))
         if self.ssm is not None:
             kw["ssm"] = dataclasses.replace(
                 self.ssm, state_size=16, head_dim=16, chunk_size=32

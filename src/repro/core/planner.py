@@ -107,7 +107,7 @@ def _schedule_candidates(
     if PP <= 1:
         return [(DEFAULT_SCHEDULE, 1)]
     out: List[Tuple[str, int]] = []
-    reps = arch.num_layers // max(len(arch.block_pattern), 1)
+    reps = arch.reps
     rps = reps // PP if reps % PP == 0 else 0  # pattern-reps per stage
     for schedule in SCHEDULES:
         if schedule == "interleaved_1f1b":

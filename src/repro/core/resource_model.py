@@ -52,6 +52,9 @@ class ModelShape:
     n_attn: int = -1  # attention mixers (SSM archs have fewer); -1 -> L
     cf: float = 1.25  # capacity factor (prices the padding-FLOPs tax)
     H_kv: int = -1  # KV heads (GQA) — sizes the serving KV-cache; -1 -> H
+    # Exact attention parameters a layer (MLA's latent projections); 0 ->
+    # the paper's 4 d^2.
+    attn_params: int = 0
 
     def __post_init__(self):
         if self.n_attn < 0:
@@ -61,15 +64,21 @@ class ModelShape:
 
     @classmethod
     def from_arch(cls, a: ArchConfig) -> "ModelShape":
+        """The arch's symbols.  MLA is priced by its exact parameters and
+        the mean of its q/k and v head dims (exact for the score and value
+        products); a share of an expert layer by its held experts and the
+        rows a token is expected to send them."""
+        mla = a.mla if any(m == "mla" for m, _ in a.layers) else None
+        m = a.moe
         return cls(
             d_model=a.d_model,
             L=a.num_layers,
             L_moe=a.num_moe_layers,
             H=a.num_heads,
-            d_h=a.head_dim,
-            E=a.moe.num_experts if a.moe else 0,
-            E_s=a.moe.num_shared_experts if a.moe else 0,
-            k=a.moe.top_k if a.moe else 0,
+            d_h=(mla.qk_head_dim + mla.v_head_dim) // 2 if mla else a.head_dim,
+            E=m.experts_held if m else 0,
+            E_s=m.num_shared_experts if m else 0,
+            k=m.top_k * m.experts_held / m.num_experts if m else 0,
             n_mat=a.n_mat,
             d_ffn_moe=a.moe.d_ff if a.moe else 0,
             d_ffn_dense=a.d_ff,
@@ -77,6 +86,7 @@ class ModelShape:
             n_attn=a.num_attn_layers,
             cf=a.moe.capacity_factor if a.moe else 1.25,
             H_kv=a.num_kv_heads,
+            attn_params=a.mla_params() if mla else 0,
         )
 
     # -- parameter counts (paper Table III) ---------------------------------
@@ -85,7 +95,7 @@ class ModelShape:
     def attn_params_per_layer(self) -> int:
         # Paper uses 4 d^2 (MHA); with GQA it is d*(H*dh) + 2*d*(Hkv*dh) +
         # (H*dh)*d.  We keep the paper's 4d^2 for fidelity when H*dh == d.
-        return 4 * self.d_model * self.d_model
+        return self.attn_params or 4 * self.d_model * self.d_model
 
     @property
     def expert_params(self) -> int:

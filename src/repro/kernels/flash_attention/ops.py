@@ -69,8 +69,11 @@ def block_size(s: int) -> int:
 def _splash_kernel(s: int, hq: int, softcap: Optional[float],
                    interpret: bool):
     """The splash kernel for one shape, built once: its mask info is
-    computed on the host in numpy, which a retrace must not repeat.  GQA
-    needs nothing here: the kernel reads kv head ``h // (hq // hkv)``."""
+    computed on the host in numpy, which a retrace must not repeat, and
+    made concrete arrays (``ensure_compile_time_eval``), so that two
+    traces of one step — the scans of a leading dense layer and of the
+    pattern — may share it.  GQA needs nothing here: the kernel reads kv
+    head ``h // (hq // hkv)``."""
     b = block_size(s)
     sizes = splash.BlockSizes(
         block_q=b, block_kv=b, block_kv_compute=b,
@@ -78,26 +81,28 @@ def _splash_kernel(s: int, hq: int, softcap: Optional[float],
         use_fused_bwd_kernel=True,
     )
     mask = splash.MultiHeadMask([splash.CausalMask((s, s))] * hq)
-    return splash.make_splash_mha_single_device(
-        mask, block_sizes=sizes, attn_logits_soft_cap=softcap,
-        interpret=interpret,
-    )
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mha_single_device(
+            mask, block_sizes=sizes, attn_logits_soft_cap=softcap,
+            interpret=interpret,
+        )
 
 
 def causal_attention(
     q: jax.Array,  # (b, s, hq, d) — model layout
     k: jax.Array,  # (b, s, hkv, d)
-    v: jax.Array,
+    v: jax.Array,  # (b, s, hkv, dv); MLA's dv (128) is under its d (192)
     *,
     logit_softcap: Optional[float] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """Causal GQA attention with a backward, for training and prefill.
+    """Causal GQA attention with a backward, for training and prefill;
+    returns (b, s, hq, dv).
 
     ``s`` must be a multiple of 128 and ``hq`` of ``hkv``.  q is scaled by
     ``1/sqrt(d)`` before the kernel; for d = 64 or 256 that is exact in
-    bf16, for other head dims it rounds once in q's dtype where
-    ``models.layers.attention`` divides the fp32 scores instead.
+    bf16, for other head dims (MLA's 192) it rounds once in q's dtype
+    where ``models.layers.attention`` divides the fp32 scores instead.
     """
     interpret = interpret_mode() if interpret is None else interpret
     _, s, hq, d = q.shape

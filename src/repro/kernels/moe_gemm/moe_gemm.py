@@ -54,11 +54,13 @@ def _matmul_kernel(x_ref, w_ref, o_ref, *, k_steps: int):
 
 def _block(dim: int, preferred: int) -> int:
     """Largest divisor of ``dim`` that is <= preferred (MXU-aligned whenever
-    the dim allows it)."""
+    the dim allows it), or the whole dim where that divisor is no multiple
+    of the 128 lanes (Mosaic tiles a block's minor dims by 128 unless the
+    block spans the array: Moonlight's d_ff of 1408 = 11 x 128)."""
     b = min(dim, preferred)
     while dim % b:
         b -= 1
-    return b
+    return b if b % 128 == 0 else dim
 
 
 @functools.partial(

@@ -169,7 +169,7 @@ def setup(args):
             # that deep.  Clamp to the largest feasible divisor — an explicit
             # --vstages is respected (and asserted) as given.
             pp = int(args.mesh.split(",")[0])
-            reps = arch.num_layers // len(arch.block_pattern)
+            reps = arch.reps
             rps = max(reps // pp, 1)
             want = vstages
             vstages = max(v for v in range(1, min(vstages, rps) + 1)
@@ -186,8 +186,10 @@ def setup(args):
     if arch.moe is not None:
         import dataclasses
 
+        # A share's slice of the experts runs the dropless path only.
         dispatch = args.dispatch or (
-            best.dispatch if best is not None else arch.moe.dispatch
+            best.dispatch if best is not None and arch.moe.ep_share == 1
+            else arch.moe.dispatch
         )
         if dispatch != arch.moe.dispatch:
             arch = arch.replace(

@@ -149,8 +149,9 @@ def attention(
     q_chunks: int = 1,
     plan=None,
 ) -> jax.Array:
-    """Reference GQA attention (fp32 softmax).  ``kv_len`` masks cache slots
-    beyond the current length during decode.
+    """Reference GQA attention (fp32 softmax), scores scaled by
+    ``1/sqrt(q's head dim)``; v's head dim may differ.  ``kv_len`` masks
+    cache slots beyond the current length during decode.
 
     ``q_chunks > 1`` evaluates query blocks sequentially with
     rematerialization (softmax is row-wise, so q-chunking is exact) — the
@@ -203,7 +204,7 @@ def attention(
             if chunk_ns is not None
             else outs
         )
-        return outs.transpose(1, 0, 2, 3, 4).reshape(b, s_q, hq, d)
+        return outs.transpose(1, 0, 2, 3, 4).reshape(b, s_q, hq, -1)
 
     hkv = k.shape[2]
     groups = hq // hkv
@@ -228,7 +229,7 @@ def attention(
         scores = jnp.where(mask[None, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
-    return out.reshape(b, s_q, hq, d)
+    return out.reshape(b, s_q, hq, v.shape[-1])
 
 
 def attention_path(backend: str, s: int, *, window: Optional[int],
@@ -273,11 +274,7 @@ def attention_proj(params, x, cfg, positions, *, impl="xla", window=None,
     k = positional_embed(k, positions, cfg.rope_type, cfg.rope_theta)
 
     new_cache = None
-    path = attention_path(
-        jax.default_backend(), s, window=window, cached=cache is not None,
-        mesh_size=1 if plan is None else plan.mesh.size,
-    )
-    obs.counter("attention.path", path=path)
+    path = _counted_path(s, window, cache is not None, plan)
     if path == "flash":
         from repro.kernels.flash_attention import ops as fa_ops
 
@@ -327,45 +324,103 @@ def attention_proj(params, x, cfg, positions, *, impl="xla", window=None,
             logit_softcap=cfg.attn_logit_softcap,
         )
     else:
-        # Bound the fp32 score temp to ~512 query rows per chunk.
-        q_chunks = max(s // 512, 1) if s >= 1024 else 1
-        if plan is not None and q_chunks > 1:
-            # PERF: gather K/V across the sequence shards ONCE per layer.
-            # Left to GSPMD, the seq-sharded contraction turns into
-            # psum-of-partial-outputs + softmax-stat reductions INSIDE the
-            # q-chunk loop — q_chunks x remat-visits times the traffic
-            # (measured 16x on granite train_4k; EXPERIMENTS.md §Perf).
-            from jax.sharding import NamedSharding
-
-            from repro.models.model import safe_spec
-
-            ns = NamedSharding(
-                plan.mesh, safe_spec(plan, k.shape, ("batch", None, None, None))
-            )
-            k = _checkpoint_name(
-                lax.with_sharding_constraint(k, ns), "kv_gathered"
-            )
-            v = _checkpoint_name(
-                lax.with_sharding_constraint(v, ns), "kv_gathered"
-            )
-            # Keep q (and the output, below) sequence-sharded — otherwise
-            # GSPMD replicates the whole attention computation to match the
-            # now-replicated K/V.
-            q_ns = NamedSharding(
-                plan.mesh, safe_spec(plan, q.shape, ("batch", "seq", None, None))
-            )
-            q = lax.with_sharding_constraint(q, q_ns)
-        out = attention(
-            q, k, v, window=window, logit_softcap=cfg.attn_logit_softcap,
-            q_chunks=q_chunks, plan=plan,
-        )
-        if plan is not None and q_chunks > 1:
-            out = lax.with_sharding_constraint(out, q_ns)
+        out = _xla_causal(q, k, v, window, cfg.attn_logit_softcap, plan)
         if return_kv:
             new_cache = {"k": k, "v": v}
     out = out.reshape(b, s, cfg.num_heads * cfg.head_dim)
     out = jnp.einsum("bsk,kd->bsd", out, params["wo"])
     return out, new_cache
+
+
+def _counted_path(s: int, window, cached: bool, plan) -> str:
+    """``attention_path`` for this backend and mesh, counted as
+    ``attention.path`` at trace time."""
+    path = attention_path(
+        jax.default_backend(), s, window=window, cached=cached,
+        mesh_size=1 if plan is None else plan.mesh.size,
+    )
+    obs.counter("attention.path", path=path)
+    return path
+
+
+def _xla_causal(q, k, v, window, logit_softcap, plan):
+    """Causal attention with no cache through the XLA reference, its fp32
+    score temp bounded by q-chunks of ~512 rows."""
+    s = q.shape[1]
+    q_chunks = max(s // 512, 1) if s >= 1024 else 1
+    if plan is not None and q_chunks > 1:
+        # PERF: gather K/V across the sequence shards ONCE per layer.
+        # Left to GSPMD, the seq-sharded contraction turns into
+        # psum-of-partial-outputs + softmax-stat reductions INSIDE the
+        # q-chunk loop — q_chunks x remat-visits times the traffic
+        # (measured 16x on granite train_4k; EXPERIMENTS.md §Perf).
+        from jax.sharding import NamedSharding
+
+        from repro.models.model import safe_spec
+
+        ns = NamedSharding(
+            plan.mesh, safe_spec(plan, k.shape, ("batch", None, None, None))
+        )
+        k = _checkpoint_name(
+            lax.with_sharding_constraint(k, ns), "kv_gathered"
+        )
+        v = _checkpoint_name(
+            lax.with_sharding_constraint(v, ns), "kv_gathered"
+        )
+        # Keep q (and the output, below) sequence-sharded — otherwise
+        # GSPMD replicates the whole attention computation to match the
+        # now-replicated K/V.
+        q_ns = NamedSharding(
+            plan.mesh, safe_spec(plan, q.shape, ("batch", "seq", None, None))
+        )
+        q = lax.with_sharding_constraint(q, q_ns)
+    out = attention(
+        q, k, v, window=window, logit_softcap=logit_softcap,
+        q_chunks=q_chunks, plan=plan,
+    )
+    if plan is not None and q_chunks > 1:
+        out = lax.with_sharding_constraint(out, q_ns)
+    return out
+
+
+@jax.named_scope("attention")
+def mla_proj(params, x, cfg, positions, *, plan=None):
+    """Multi-head latent attention sub-layer (DeepSeek-V3, q_lora_rank
+    null), in its expanded training form, under the ``attention`` scope:
+
+    q = x Wq split into a nope and a rope part per head; the latent
+    ``x W_kv_a`` splits into c (``kv_lora_rank``) and one rope key that
+    every head shares; c is RMS-normed and projected up to each head's
+    nope key and value (``attention.mla_kv``).  Causal attention over keys
+    [k_nope, k_rope] at scale ``1/sqrt(qk_head_dim)`` runs through the
+    path ``attention_path`` picks, with v's narrower head.  RoPE rotates
+    the two halves of the rope dims.  No cache: serving refuses MLA."""
+    m = cfg.mla
+    b, s, _ = x.shape
+    H, dn, dr = cfg.num_heads, m.qk_nope_head_dim, m.qk_rope_head_dim
+    q = jnp.einsum("bsd,dk->bsk", x, params["wq"]).reshape(b, s, H, dn + dr)
+    with jax.named_scope("attention.mla_kv"):
+        kv_a = jnp.einsum("bsd,dk->bsk", x, params["w_kv_a"])
+        c = rms_norm(kv_a[..., :m.kv_lora_rank], params["kv_norm"],
+                     cfg.norm_eps)
+        kv = jnp.einsum("bsc,ck->bsk", c, params["w_kv_b"]).reshape(
+            b, s, H, dn + m.v_head_dim)
+    k_rope = apply_rope(kv_a[..., None, m.kv_lora_rank:], positions,
+                        cfg.rope_theta)
+    q = jnp.concatenate(
+        [q[..., :dn], apply_rope(q[..., dn:], positions, cfg.rope_theta)],
+        axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_rope, (b, s, H, dr))], axis=-1)
+    v = kv[..., dn:]
+    if _counted_path(s, None, False, plan) == "flash":
+        from repro.kernels.flash_attention import ops as fa_ops
+
+        out = fa_ops.causal_attention(q, k, v)
+    else:
+        out = _xla_causal(q, k, v, None, None, plan)
+    out = out.reshape(b, s, H * m.v_head_dim)
+    return jnp.einsum("bsk,kd->bsd", out, params["wo"]), None
 
 
 # ---------------------------------------------------------------------------

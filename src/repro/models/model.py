@@ -64,6 +64,22 @@ def _attn_tree(a: ArchConfig) -> Dict[str, ParamMeta]:
     }
 
 
+def _mla_tree(a: ArchConfig) -> Dict[str, ParamMeta]:
+    m, d, H = a.mla, a.d_model, a.num_heads
+    r = m.kv_lora_rank
+    return {
+        "wq": ParamMeta((d, H * m.qk_head_dim), ("embed", "model_out"),
+                        fan_in=d),
+        "w_kv_a": ParamMeta((d, r + m.qk_rope_head_dim), ("embed", None),
+                            fan_in=d),
+        "kv_norm": ParamMeta((r,), (None,), init="zeros"),
+        "w_kv_b": ParamMeta((r, H * (m.qk_nope_head_dim + m.v_head_dim)),
+                            (None, "model_out"), fan_in=r),
+        "wo": ParamMeta((H * m.v_head_dim, d), ("model_out", "embed"),
+                        fan_in=H * m.v_head_dim),
+    }
+
+
 def _dense_ffn_tree(a: ArchConfig) -> Dict[str, ParamMeta]:
     d, f = a.d_model, a.d_ff
     t = {
@@ -77,14 +93,19 @@ def _dense_ffn_tree(a: ArchConfig) -> Dict[str, ParamMeta]:
 
 def _moe_tree(a: ArchConfig) -> Dict[str, ParamMeta]:
     m = a.moe
-    d, f, E = a.d_model, m.d_ff, m.num_experts
+    d, f, E, Eh = a.d_model, m.d_ff, m.num_experts, m.experts_held
     t = {
         "w_router": ParamMeta((d, E), (None, None), fan_in=d),
-        "w_up": ParamMeta((E, d, f), ("expert", None, "expert_ffn"), fan_in=d),
-        "w_down": ParamMeta((E, f, d), ("expert", "expert_ffn", None), fan_in=f),
+        "w_up": ParamMeta((Eh, d, f), ("expert", None, "expert_ffn"), fan_in=d),
+        "w_down": ParamMeta((Eh, f, d), ("expert", "expert_ffn", None), fan_in=f),
         # logical expert -> physical slot routing table (expert migration)
         "assignment": ParamMeta((E,), (None,), init="arange", dtype="int32"),
     }
+    if m.bias_update_speed > 0:
+        # Selection bias in units of the update speed: the count of its
+        # +-1 moves (see ``moe.update_router_bias``); no gradient.
+        t["router_bias"] = ParamMeta((E,), (None,), init="fill", fan_in=0,
+                                     dtype="int32")
     if m.max_replicas > 0:
         # Hot-expert replica channels: logical id per channel, sentinel E =
         # free.  Replicated rows compute source-locally on each EP rank.
@@ -92,7 +113,7 @@ def _moe_tree(a: ArchConfig) -> Dict[str, ParamMeta]:
             (m.max_replicas,), (None,), init="fill", fan_in=E, dtype="int32"
         )
     if a.ffn_activation == "swiglu":
-        t["w_gate"] = ParamMeta((E, d, f), ("expert", None, "expert_ffn"), fan_in=d)
+        t["w_gate"] = ParamMeta((Eh, d, f), ("expert", None, "expert_ffn"), fan_in=d)
     if m.num_shared_experts > 0:
         fs = f * m.num_shared_experts
         t["w_shared_up"] = ParamMeta((d, fs), ("embed", "model_out"), fan_in=d)
@@ -136,6 +157,8 @@ def _block_tree(a: ArchConfig, block) -> Dict[str, Any]:
     }
     if mixer.startswith("attn"):
         t["mixer"] = _attn_tree(a)
+    elif mixer == "mla":
+        t["mixer"] = _mla_tree(a)
     elif mixer == "mamba":
         t["mixer"] = _mamba_tree(a)
     if ffn != "none":
@@ -144,22 +167,28 @@ def _block_tree(a: ArchConfig, block) -> Dict[str, Any]:
     return t
 
 
-def param_tree(a: ArchConfig) -> Dict[str, Any]:
-    reps = a.num_layers // len(a.block_pattern)
-    vp = a.padded_vocab(VOCAB_PAD_MULTIPLE)
-    blocks = tuple(
+def _stacked_blocks(a: ArchConfig, pattern, reps: int):
+    return tuple(
         jax.tree.map(
             lambda m: m.stacked(reps),
             _block_tree(a, blk),
             is_leaf=lambda x: isinstance(x, ParamMeta),
         )
-        for blk in a.block_pattern
+        for blk in pattern
     )
+
+
+def param_tree(a: ArchConfig) -> Dict[str, Any]:
+    vp = a.padded_vocab(VOCAB_PAD_MULTIPLE)
     tree: Dict[str, Any] = {
         "embed": ParamMeta((vp, a.d_model), ("vocab", "model_out"), init="embed"),
-        "blocks": blocks,
+        "blocks": _stacked_blocks(a, a.block_pattern, a.reps),
         "final_norm": ParamMeta((a.d_model,), (None,), init="zeros"),
     }
+    if a.first_k_dense:
+        # The leading dense layers, stacked like one pattern position.
+        tree["prefix"] = _stacked_blocks(a, (a.prefix_block,),
+                                         a.first_k_dense)
     if not a.tie_embeddings:
         tree["lm_head"] = ParamMeta(
             (a.d_model, vp), ("model_out", "vocab"), fan_in=a.d_model
@@ -247,6 +276,19 @@ def safe_spec(plan: MeshPlan, shape, logical) -> P:
         else:
             dims.append(rule[0] if len(rule) == 1 else tuple(rule))
     return P(*dims)
+
+
+def refuse(a: ArchConfig, what: str, *, mla: bool) -> None:
+    """Raise for a model ``what`` cannot run yet: with ``mla``, an MLA
+    mixer (serving has no latent cache); leading dense layers always.
+    Serving refuses where a cache is made (``init_cache``,
+    ``init_paged_cache``, ``prefill``): the decode paths need one."""
+    if mla and any(m == "mla" for m, _ in a.block_pattern):
+        raise NotImplementedError(
+            f"{a.name}: {what} has no latent (MLA) cache yet")
+    if a.first_k_dense:
+        raise NotImplementedError(
+            f"{a.name}: {what} does not run leading dense layers yet")
 
 
 # ---------------------------------------------------------------------------
@@ -345,6 +387,7 @@ class LanguageModel:
         if self.plan.pp_axis is not None:
             from repro.core import pipeline
 
+            refuse(a, "the pipeline executor", mla=False)
             x, embed_fn, embed_params = self._pipeline_inputs(params, batch)
             b, s = x.shape[:2]
             positions = jnp.broadcast_to(
@@ -358,6 +401,12 @@ class LanguageModel:
         x = self._embed(params, batch)
         b, s = x.shape[:2]
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
+        if a.first_k_dense:
+            x, _, _ = transformer.stack_forward(
+                params["prefix"], x, a, self.plan, positions=positions,
+                impl=self.impl, token_sharded=token_sharded,
+                pattern=(a.prefix_block,),
+            )
         return transformer.stack_forward(
             params["blocks"], x, a, self.plan,
             positions=positions, impl=self.impl,
@@ -434,6 +483,7 @@ class LanguageModel:
 
         a = self.arch
         assert self.plan.pp_axis is not None, "loss_and_grads needs a PP plan"
+        refuse(a, "the pipeline executor", mla=False)
         x, embed_fn, embed_params = self._pipeline_inputs(params, batch)
         if embed_params is None and a.tie_embeddings:
             # Frontend inputs skip the in-pipeline lookup, but a tied head
@@ -524,6 +574,7 @@ class LanguageModel:
 
     def init_cache(self, batch: int, cache_len: int, dtype=jnp.bfloat16):
         a = self.arch
+        refuse(a, "serving", mla=True)
         reps = a.num_layers // len(a.block_pattern)
         caches = []
         for mixer, _ in a.block_pattern:
@@ -616,6 +667,7 @@ class LanguageModel:
         from repro.serving import kv_cache as kv_lib
 
         a = self.arch
+        refuse(a, "serving", mla=True)
         reps = a.num_layers // len(a.block_pattern)
         pools = []
         for mixer, _ in a.block_pattern:
@@ -752,6 +804,7 @@ class LanguageModel:
     def prefill(self, params, batch):
         """Forward over a prompt, emitting (last-position logits, cache)."""
         a = self.arch
+        refuse(a, "serving", mla=True)
         x = self._embed(params, batch)
         b, s = x.shape[:2]
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))

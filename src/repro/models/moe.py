@@ -49,7 +49,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Dict, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,33 +69,95 @@ def _all_axes(plan: MeshPlan) -> Tuple[str, ...]:
 
 
 @jax.named_scope("moe.router")
-def _route(x_tokens: jax.Array, w_router: jax.Array, moe: MoECfg):
-    """Top-k routing. x_tokens: (T, d) -> (weights (T,k), ids (T,k), probs)."""
+def _route(x_tokens: jax.Array, w_router: jax.Array, moe: MoECfg,
+           bias: Optional[jax.Array] = None):
+    """Top-k routing. x_tokens: (T, d) -> (weights (T,k), ids (T,k),
+    scores (T,E), logits).
+
+    softmax: the top-k of the softmax, renormalised.  sigmoid (DeepSeek-V3
+    noaux_tc, one group): the top-k of the sigmoid scores plus ``bias``
+    (E,) chooses the experts; their weights are the unbiased scores,
+    renormalised, times ``moe.routed_scale``."""
     logits = jnp.einsum(
         "td,de->te", x_tokens.astype(jnp.float32), w_router.astype(jnp.float32)
     )
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_i = lax.top_k(probs, moe.top_k)
-    top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
-    return top_w, top_i, probs, logits
+    if moe.scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_w, top_i = lax.top_k(probs, moe.top_k)
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+        return top_w, top_i, probs, logits
+    scores = jax.nn.sigmoid(logits)
+    choice = scores if bias is None else scores + bias
+    _, top_i = lax.top_k(lax.stop_gradient(choice), moe.top_k)
+    top_w = jnp.take_along_axis(scores, top_i, axis=-1)
+    top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+    return top_w * moe.routed_scale, top_i, scores, logits
 
 
 @jax.named_scope("moe.router")
-def _aux_losses(probs, logits, top_i, moe: MoECfg, axes):
-    """Switch-style load-balancing aux loss + router z-loss, meaned over the
-    global token population via psum over every mesh axis."""
+def _aux_losses(probs, logits, top_i, moe: MoECfg, axes, rows: int = 1,
+                sp_axes=()):
+    """Load-balancing aux loss (Switch-style, meaned over the global token
+    population via psum over every mesh axis; or, with ``moe.seq_aux``,
+    DeepSeek-V3's per sequence over the ``rows`` here) + router z-loss,
+    and the global expert counts."""
     T = probs.shape[0]
     E = moe.num_experts
     counts = jnp.zeros((E,), jnp.float32).at[top_i.reshape(-1)].add(1.0)
     totals = lax.psum(jnp.float32(T), axes) if axes else jnp.float32(T)
     counts_g = lax.psum(counts, axes) if axes else counts
-    probs_sum = lax.psum(probs.sum(0), axes) if axes else probs.sum(0)
-    frac_tokens = counts_g / (totals * moe.top_k)
-    frac_probs = probs_sum / totals
-    aux = E * jnp.sum(frac_tokens * frac_probs) * moe.aux_loss_coef
+    if moe.seq_aux:
+        aux = _seq_aux_loss(probs, top_i, rows, moe, sp_axes, axes)
+    else:
+        probs_sum = lax.psum(probs.sum(0), axes) if axes else probs.sum(0)
+        frac_tokens = counts_g / (totals * moe.top_k)
+        frac_probs = probs_sum / totals
+        aux = E * jnp.sum(frac_tokens * frac_probs) * moe.aux_loss_coef
+    if not moe.z_loss_coef:
+        return aux, jnp.float32(0.0), counts_g
     z_local = jnp.sum(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
     z = (lax.psum(z_local, axes) if axes else z_local) / totals * moe.z_loss_coef
     return aux, z, counts_g
+
+
+@jax.named_scope("moe.router")
+def _seq_aux_loss(scores, top_i, rows: int, moe: MoECfg, sp_axes, axes):
+    """DeepSeek-V3's sequence-wise balance loss (arXiv:2412.19437, eqs.
+    17-20): per sequence, ``coef * sum_e f_e P_e`` with f_e = E/(k s) x the
+    sequence's rows sent to expert e and P_e the mean of its scores
+    normalised over the experts; meaned over the sequences.  A sequence
+    split over the ``sp_axes`` has its sums taken over them first."""
+    T, E = scores.shape
+    s_l = T // rows
+    norm = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    row = jnp.repeat(jnp.arange(T, dtype=jnp.int32) // s_l, moe.top_k)
+    cnt = jnp.zeros((rows, E), jnp.float32).at[row, top_i.reshape(-1)].add(1.0)
+    p_sum = norm.reshape(rows, s_l, E).sum(axis=1)
+    s = jnp.float32(s_l)
+    if sp_axes:
+        cnt, p_sum, s = (lax.psum(t, sp_axes) for t in (cnt, p_sum, s))
+    per_row = jnp.sum(cnt * E / (moe.top_k * s) * (p_sum / s), axis=-1)
+    tot, n = jnp.sum(per_row), jnp.float32(rows)
+    if axes:
+        tot, n = lax.psum(tot, axes), lax.psum(n, axes)
+    return tot / n * moe.aux_loss_coef
+
+
+@jax.named_scope("moe.bias_update")
+def update_router_bias(blocks, loads: jax.Array, arch: ArchConfig):
+    """DeepSeek-V3's auxiliary-loss-free balancing, after a step: each MoE
+    layer's ``router_bias`` (in units of ``bias_update_speed``) moves by
+    sign(mean load - load) per expert.  ``blocks``: the stacked pattern
+    positions; ``loads``: the step's (reps, n_moe_positions, E) counts."""
+    out = list(blocks)
+    moe_pos = [i for i, (_, f) in enumerate(arch.block_pattern) if f == "moe"]
+    for j, i in enumerate(moe_pos):
+        load = loads[:, j, :]
+        move = jnp.sign(jnp.mean(load, axis=-1, keepdims=True) - load)
+        ffn = dict(out[i]["ffn"])
+        ffn["router_bias"] = ffn["router_bias"] + move.astype(jnp.int32)
+        out[i] = {**out[i], "ffn": ffn}
+    return tuple(out)
 
 
 def _capacity(T: int, moe: MoECfg) -> int:
@@ -338,48 +400,92 @@ def _moe_ragged_sharded(xt, top_phys, top_w, wu_f, wg_f, wd_f,
     return _combine_expert_outputs(vals, flat_w, keep_s[inv], T, k, d)
 
 
+# A share's chunk of held rows: twice what its experts take under even
+# routing.  Skewed routing reaches into further chunks (dropless).
+HELD_SLACK = 2
+
+
+def _held_chunk(T: int, k: int, E_l: int, E: int) -> int:
+    """Rows of the first chunk of :func:`_held_rows`: HELD_SLACK x the rows
+    that E_l of E experts take under even routing, in 128-row tiles, at
+    most the T x min(k, E_l) rows they can take at all."""
+    even = -(-T * k * E_l // E)
+    return min(T * min(k, E_l), -(-HELD_SLACK * even // 128) * 128)
+
+
+def _further_chunks(total: int, rows: int) -> List[int]:
+    """Rows of the further chunks, up to ``total``: an eighth of the first
+    chunk's (in 128-row tiles), doubling up to the first's, so that a step
+    whose held rows just pass the first chunk runs only a small one."""
+    sizes, size, left = [], max(128, rows // 8 // 128 * 128), total - rows
+    while left > 0:
+        sizes.append(min(size, left))
+        left -= sizes[-1]
+        size = min(2 * size, rows)
+    return sizes
+
+
 @jax.named_scope("moe.dispatch")
-def _moe_ragged_decode(xt, top_phys, top_w, wu_f, wg_f, wd_f,
-                       activation: str, moe: MoECfg,
-                       ep_size: int, skip=None):
-    """Ragged weight-parallel decode (token_sharded=False): tokens are
-    replicated over the "ep" axis; each rank locally sorts the replicated
-    rows by LOCAL expert id (rows routed to other ranks' experts get the
-    sentinel E_l and sort to the never-computed tail), runs the ragged
-    grouped FFN over exactly its own experts' rows, scatters partial
-    outputs back to flat (token, k) order, and combines with psum("ep") —
-    the same static slot layout capacity decode uses, minus the (E, C, d)
-    zero padding and minus the drops.  This is the ROADMAP's "ragged decode
-    needs per-rank local sorting of the replicated rows" follow-up.
-    """
+def _held_rows(xt, top_phys, top_w, wu_f, wg_f, wd_f, activation: str,
+               k: int, first: int, E_l: int, E: int, skip=None):
+    """What experts [first, first + E_l) add to each token — one rank's
+    local experts, or one chip's share of a layer: (T, d) float32, the
+    weighted sum over the (token, k) rows routed to them.
+
+    The rows are sorted by held expert, rows routed elsewhere to a tail.
+    Only held rows are gathered, in chunks: the first, of
+    :func:`_held_chunk` rows, always runs; each further one
+    (:func:`_further_chunks`) only where the held rows reach into it,
+    rematerialised so that a chunk not run keeps nothing for the backward
+    pass.  A chunk's rows past the held ones are zero padding, so a step's
+    work does not follow the routing unless it reaches a further chunk.  A
+    chunk's outputs are weighted and added into their tokens, whose
+    backward is a gather of the chunk's rows."""
     T, d = xt.shape
-    k = moe.top_k
-    E = moe.num_experts
-    E_l = E // ep_size
     flat_e = top_phys.reshape(-1)
-    flat_w = top_w.reshape(-1)
-    g = lax.axis_index("ep") if ep_size > 1 else 0
-    lid = flat_e - g * E_l
+    lid = flat_e - first
     local = (lid >= 0) & (lid < E_l)
     if skip is not None:
         local = local & ~skip  # replica rows: handled by the replica path
     lid = jnp.where(local, lid, E_l).astype(jnp.int32)  # sentinel tail
-    order = jnp.argsort(lid)  # stable: local rows first, by expert
+    order = jnp.argsort(lid)  # stable: held rows first, by expert
     counts = jnp.zeros((E_l + 1,), jnp.int32).at[lid].add(1)
     offsets = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32),
          jnp.cumsum(counts[:E_l]).astype(jnp.int32)]
     )
-    xs = jnp.take(xt, order // k, axis=0)  # (T*k, d) local-expert-sorted
-    ys = _ragged_rows_ffn(xs, wu_f, wg_f, wd_f, offsets, activation)
-    # Rows past offsets[E_l] (other ranks' experts) come back zero, so the
-    # inverse scatter leaves non-local rows zero and the psum sums each
-    # row's single owning rank.
-    vals = jnp.zeros((flat_e.shape[0], d), ys.dtype).at[order].set(ys)
-    if ep_size > 1:
-        vals = lax.psum(vals, "ep")
-    keep = jnp.ones_like(flat_e, dtype=bool)  # dropless
-    return _combine_expert_outputs(vals, flat_w, keep, T, k, d)
+    total = T * min(k, E_l)
+    rows = _held_chunk(T, k, E_l, E)
+    tok = order[:total] // k
+    w = jnp.take(top_w.reshape(-1), order[:total])
+
+    def chunk(xt, wu, wg, wd, tok_c, w_c, off_c):
+        # Rows past off_c[E_l] (routed elsewhere) are padding: zero rows
+        # that the last held expert takes, so that every grid step does a
+        # held row's work and a chunk costs the same whatever the routing.
+        size = tok_c.shape[0]
+        pad = jnp.arange(size) >= off_c[E_l]
+        xs = jnp.where(pad[:, None], 0, jnp.take(xt, tok_c, axis=0))
+        w_c = jnp.where(pad, 0, w_c)
+        ys = _ragged_rows_ffn(xs, wu, wg, wd, off_c.at[E_l].set(size),
+                              activation)
+        with jax.named_scope("moe.combine"):
+            return jnp.zeros((T, d), jnp.float32).at[tok_c].add(
+                ys.astype(jnp.float32) * w_c[:, None])
+
+    def operands(s, size):
+        return (xt, wu_f, wg_f, wd_f, tok[s:s + size], w[s:s + size],
+                jnp.clip(offsets - s, 0, size))
+
+    y = chunk(*operands(0, rows))
+    more = jax.checkpoint(chunk)
+    s = rows
+    for size in _further_chunks(total, rows):
+        y = y + lax.cond(offsets[E_l] > s, more,
+                         lambda *a: jnp.zeros((T, d), jnp.float32),
+                         *operands(s, size))
+        s += size
+    return y
 
 
 # -- hot-expert replication (migration planner escape hatch) ----------------
@@ -550,12 +656,19 @@ def moe_ffn_local(
     b, s, d = x.shape
     T = b * s
     xt = x.reshape(T, d)
-    top_w, top_i, probs, logits = _route(xt, params["w_router"], moe)
-    aux, z, counts = _aux_losses(probs, logits, top_i, moe, ())
+    top_w, top_i, probs, logits = _route(xt, params["w_router"], moe,
+                                         _router_bias(params, moe))
+    aux, z, counts = _aux_losses(probs, logits, top_i, moe, (), rows=b)
     with jax.named_scope("moe.router"):
         top_phys = params["assignment"][top_i]
     wg = params.get("w_gate")
-    if moe.dispatch == "ragged":
+    if moe.ep_share > 1:
+        y = _held_rows(
+            xt, top_phys, top_w, params["w_up"], wg, params["w_down"],
+            arch.ffn_activation, moe.top_k, moe.first_held, moe.experts_held,
+            E,
+        ).astype(x.dtype)
+    elif moe.dispatch == "ragged":
         y = _moe_ragged_local(
             xt, top_phys, top_w, params["w_up"], wg, params["w_down"],
             arch.ffn_activation, E, moe.top_k,
@@ -574,21 +687,35 @@ def moe_ffn_local(
         vals = y_buf[flat_e, pos]
         y = _combine_expert_outputs(vals, flat_w, keep, T, moe.top_k, d)
     y = y.reshape(b, s, d)
-
-    if moe.num_shared_experts > 0:
-        from repro.models import layers
-
-        y = y + layers.dense_ffn(
-            {
-                "w_up": params["w_shared_up"],
-                "w_gate": params.get("w_shared_gate"),
-                "w_down": params["w_shared_down"],
-            },
-            x,
-            arch.ffn_activation,
-        )
+    if moe.num_shared_experts:
+        y = y + _shared_experts(params, x, arch)
     metrics = {"moe_aux_loss": aux, "moe_z_loss": z, "expert_load": counts}
     return y, metrics
+
+
+def _router_bias(params, moe: MoECfg) -> Optional[jax.Array]:
+    """The selection bias (E,) float32, or None without one."""
+    if "router_bias" not in params:
+        return None
+    with jax.named_scope("moe.router"):
+        return params["router_bias"].astype(jnp.float32) * moe.bias_update_speed
+
+
+@jax.named_scope("moe.shared")
+def _shared_experts(params, x, arch: ArchConfig):
+    """The always-active shared experts: one dense FFN over all tokens.
+    On a share every chip computes them alike."""
+    from repro.models import layers
+
+    return layers.dense_ffn(
+        {
+            "w_up": params["w_shared_up"],
+            "w_gate": params.get("w_shared_gate"),
+            "w_down": params["w_shared_down"],
+        },
+        x,
+        arch.ffn_activation,
+    )
 
 
 def moe_ffn(
@@ -612,8 +739,10 @@ def moe_ffn(
     assert moe is not None
     mesh = plan.mesh
     ep_size = plan.ep
+    # A share stands for the other chips of its layer; it runs no exchange.
+    assert ep_size == 1 or moe.ep_share == 1, "a share runs without EP"
     E = moe.num_experts
-    E_l = E // ep_size
+    E_l = moe.experts_held // ep_size
     axes = _all_axes(plan)
 
     import numpy as _np
@@ -641,14 +770,17 @@ def moe_ffn(
     # tests pin (metrics must be invariant to the mesh factoring).
     metric_axes = axes if token_sharded else (dp_spec or ())
 
-    def body(wr, wu, wg, wd, assignment, replicas, xl):
+    def body(wr, wu, wg, wd, assignment, replicas, bias, xl):
         b_l, s_l, d = xl.shape
         T = b_l * s_l
         xt = xl.reshape(T, d)
-        top_w, top_i, probs, logits = _route(xt, wr, moe)
+        top_w, top_i, probs, logits = _route(
+            xt, wr, moe, bias if bias.shape[0] else None)
         # Metrics/aux use LOGICAL expert ids; dispatch uses PHYSICAL slots
         # via the migration routing table.
-        aux, z, counts = _aux_losses(probs, logits, top_i, moe, metric_axes)
+        aux, z, counts = _aux_losses(
+            probs, logits, top_i, moe, metric_axes, rows=b_l,
+            sp_axes=sp_spec if token_sharded else ())
         with jax.named_scope("moe.router"):
             top_phys = assignment[top_i]
 
@@ -713,11 +845,17 @@ def moe_ffn(
             # EP the whole block is processed ragged.  Decode (replicated
             # tokens): each rank sorts locally by its own expert ids and
             # partial outputs combine via psum("ep") — no capacity buffers.
-            if not token_sharded:
-                y = _moe_ragged_decode(
+            if not token_sharded or moe.ep_share > 1:
+                # Each rank (or the share) computes its own experts' part.
+                g = lax.axis_index("ep") if ep_size > 1 else 0
+                y = _held_rows(
                     xt, top_phys, top_w, wu_f, wg_f, wd_f,
-                    arch.ffn_activation, moe, ep_size, skip=rep_row,
+                    arch.ffn_activation, moe.top_k, moe.first_held + g * E_l,
+                    E_l, E, skip=rep_row,
                 )
+                if ep_size > 1:
+                    y = lax.psum(y, "ep")
+                y = y.astype(xt.dtype)
             elif ep_size > 1:
                 y = _moe_ragged_sharded(
                     xt, top_phys, top_w, wu_f, wg_f, wd_f,
@@ -783,6 +921,9 @@ def moe_ffn(
     replicas = params.get("replicas")
     if replicas is None:
         replicas = jnp.zeros((0,), jnp.int32)
+    bias = _router_bias(params, moe)
+    if bias is None:
+        bias = jnp.zeros((0,), jnp.float32)
     in_specs = (
         wr_spec,
         wu_spec,
@@ -790,14 +931,15 @@ def moe_ffn(
         wd_spec,
         P(None),
         P(None),
+        P(None),
         x_spec,
     )
     out_specs = (x_spec, {"moe_aux_loss": P(), "moe_z_loss": P(), "expert_load": P()})
 
-    def wrapped(wr, wu, wg_, wd, assignment, replicas_, xl):
+    def wrapped(wr, wu, wg_, wd, assignment, replicas_, bias_, xl):
         return body(
             wr, wu, wg_ if wg is not None else None, wd, assignment,
-            replicas_, xl,
+            replicas_, bias_, xl,
         )
 
     # Manual over every non-pipeline axis.  When nested inside the pipeline
@@ -822,20 +964,10 @@ def moe_ffn(
         params["w_down"],
         params["assignment"],
         replicas,
+        bias,
         x,
     )
 
-    # Shared (always-active) experts — a dense FFN over all tokens.
-    if moe.num_shared_experts > 0:
-        from repro.models import layers
-
-        y = y + layers.dense_ffn(
-            {
-                "w_up": params["w_shared_up"],
-                "w_gate": params.get("w_shared_gate"),
-                "w_down": params["w_shared_down"],
-            },
-            x,
-            arch.ffn_activation,
-        )
+    if moe.num_shared_experts:
+        y = y + _shared_experts(params, x, arch)
     return y, metrics

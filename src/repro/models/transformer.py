@@ -44,7 +44,10 @@ def apply_block(
     new_cache = None
 
     h = L.rms_norm(x, params["norm_mixer"], arch.norm_eps)
-    if mixer.startswith("attn"):
+    if mixer == "mla":
+        out, new_cache = L.mla_proj(params["mixer"], h, arch, positions,
+                                    plan=plan)
+    elif mixer.startswith("attn"):
         window = arch.sliding_window if mixer == "attn_local" else None
         out, new_cache = L.attention_proj(
             params["mixer"],
@@ -110,18 +113,21 @@ def stack_forward(
     impl: str = "xla",
     token_sharded: bool = True,
     unroll: bool = False,
+    pattern=None,
 ):
-    """Run the full layer stack via scan-over-reps.
+    """Run the layer stack via scan-over-reps of ``pattern`` (default the
+    arch's ``block_pattern``; the leading dense layers pass their own).
 
     Returns (x, {"moe_aux_loss","moe_z_loss"} scalars, expert_load
     (reps, n_moe_positions, E) or None).
     """
-    has_moe = arch.num_moe_layers > 0
+    pattern = arch.block_pattern if pattern is None else pattern
+    has_moe = any(f == "moe" for _, f in pattern)
 
     def body(carry, rep_params):
         h, aux, z = carry
         loads = []
-        for pos, blk in enumerate(arch.block_pattern):
+        for pos, blk in enumerate(pattern):
             h, metrics, _ = apply_block(
                 blk,
                 rep_params[pos],

@@ -18,6 +18,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig, ShapeSpec
 from repro.models import model as model_lib
+from repro.models import moe as moe_lib
 from repro.models.model import LanguageModel, safe_spec
 from repro.optim.optimizer import OptimizerConfig, adamw_init, adamw_update
 from repro.sharding import MeshPlan
@@ -142,6 +143,12 @@ def make_train_step(
     """
     compute_dtype = DTYPES[lm.plan.compute_dtype]
     pipelined = lm.plan.pp_axis is not None and lm.plan.pp > 1
+    moe = lm.arch.moe
+    bias_update = moe is not None and moe.bias_update_speed > 0
+    if bias_update and pipelined:
+        raise NotImplementedError(
+            f"{lm.arch.name}: the pipeline executor returns no expert loads "
+            f"for the router-bias update")
 
     @jax.named_scope("optimizer")
     def cast(params):
@@ -189,6 +196,9 @@ def make_train_step(
         metrics = {**metrics, **opt_metrics}
         if metrics.get("expert_load") is None:
             metrics.pop("expert_load", None)
+        if bias_update:
+            new_params = {**new_params, "blocks": moe_lib.update_router_bias(
+                new_params["blocks"], metrics["expert_load"], lm.arch)}
         new_state = {"params": new_params, **new_opt}
         # Anomaly sentinel: a poisoned update must not reach the state.
         with jax.named_scope("optimizer"), jax.named_scope("sentinel"):

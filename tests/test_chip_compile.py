@@ -128,3 +128,29 @@ def test_causal_attention_grad_compiles(one_chip):
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile().as_text()
     assert text.count("tpu_custom_call") >= 2
+
+
+def test_share_kernels_compile_at_moonlight_widths(one_chip):
+    """One chip's share of a Moonlight layer (8 of 64 experts, d_model 2048,
+    d_ff 1408 = 11 x 128, 8192 tokens x top-6 rows with a sentinel tail):
+    the ragged FFN, forward and backward; and the splash kernel at MLA's
+    q/k head of 192 and v head of 128."""
+    bf = jnp.bfloat16
+    rows, e, d, f = 8192 * 6, 8, 2048, 1408
+    args = (_spec((rows, d), bf, one_chip), _spec((e, d, f), bf, one_chip),
+            _spec((e, d, f), bf, one_chip), _spec((e, f, d), bf, one_chip),
+            _spec((e + 1,), jnp.int32, one_chip))
+
+    def loss(x, wu, wg, wd, offsets):
+        y = mm_ops.ragged_ffn(x, wu, wg, wd, offsets, interpret=False)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    _assert_mosaic(jax.grad(loss, argnums=(0, 1, 2, 3)), *args)
+
+    def attn(q, k, v):
+        out = fa_ops.causal_attention(q, k, v, interpret=False)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    qk = _spec((1, 8192, 16, 192), bf, one_chip)
+    v = _spec((1, 8192, 16, 128), bf, one_chip)
+    _assert_mosaic(jax.grad(attn, argnums=(0, 1, 2)), qk, qk, v)

@@ -158,6 +158,33 @@ def test_ragged_ffn_custom_vjp_matches_jax_grad(activation):
         )
 
 
+def test_ragged_ffn_ignores_a_long_unowned_tail():
+    """Rows no expert owns fill most of x (one chip's share of a layer,
+    whose rows for absent experts sort to a tail): the static grid's
+    surplus items leave them zero with zero gradient, and the owned rows'
+    outputs and gradients equal those of the owned rows alone."""
+    counts = [7, 0, 23, 1]
+    x, _, offs, E, T = _ragged_case(counts, K=32, N=32, dtype=jnp.float32)
+    x_tail = jnp.concatenate([x, jnp.ones((93, 32))])
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    wu, wg = (jax.random.normal(k, (E, 32, 48)) * 0.2 for k in ks[:2])
+    wd = jax.random.normal(ks[2], (E, 48, 32)) * 0.2
+
+    def grads(x):
+        def f(x, wu, wg, wd):
+            y = mm_ops.ragged_ffn(x, wu, wg, wd, offs, interpret=True, bm=16)
+            return jnp.sum(jnp.sin(y)), y
+        return jax.grad(f, argnums=(0, 1, 2, 3), has_aux=True)(x, wu, wg, wd)
+
+    (g_tail, y_tail), (g_own, y_own) = grads(x_tail), grads(x)
+    np.testing.assert_array_equal(y_tail[:T], y_own)
+    assert not np.asarray(y_tail[T:]).any()
+    assert not np.asarray(g_tail[0][T:]).any()
+    np.testing.assert_array_equal(g_tail[0][:T], g_own[0])
+    for a, b in zip(g_tail[1:], g_own[1:]):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+
+
 def test_ragged_matmul_empty_tail_rows_zero():
     """Rows beyond offsets[-1] (padding) must come back exactly zero."""
     counts = [5, 3]
