@@ -29,7 +29,9 @@ from repro.core.platform import Platform
 
 # Row-tile granularity of the ragged grouped-GEMM kernel
 # (kernels/moe_gemm bm): the only padding the ragged dispatch pays is the
-# masked tile tails, < bm rows per occupied expert.
+# masked tile tails, < bm rows per occupied expert.  The kernels now pick
+# 128-512 rows from the shapes (``moe_gemm.row_tile``); this model keeps
+# 128.
 RAGGED_TILE_ROWS = 128
 
 
@@ -1075,7 +1077,7 @@ def serving_dispatch_costs(m: ModelShape, s: ServeSetup) -> DispatchCosts:
             bytes_per_layer=3.0 * rows * m.E * 4.0,
         )
     # Ragged issues one bm-row tile per occupied (expert, tile) work item;
-    # bm adapts down to the replicated row count (kernels.moe_gemm._row_block)
+    # bm adapts down to the replicated row count (as moe_gemm.row_tile)
     bm = min(RAGGED_TILE_ROWS, max(-(-s.batch * m.k // 16) * 16, 16))
     occupied = min(E_l, rows) if rows >= 1.0 else 1.0
     c_e = rows / max(occupied, 1.0)

@@ -5,16 +5,11 @@ paper's skinny-GEMM hot spot (§II-A, Fig 4): fine-grained experts make both
 M (tokens-per-expert) and N (= d_ffn/TP) small, so a naive per-expert loop
 starves the MXU.  The padded kernel:
 
-* tiles (M, N, K) into MXU-aligned blocks that fit VMEM —
-  default (128, 128, 512): x-block + w-block + out-block =
-  (128*512 + 512*128 + 128*128)*4 B ≈ 0.6 MB, far under the ~16 MB VMEM
-  budget, leaving room for double buffering;
+* tiles (M, N, K) into (128, 128, 512) blocks, clamped to divisors of the
+  actual dims so tiny experts still launch well-formed blocks;
 * walks the grid (E, M/bm, N/bn, K/bk) with K innermost so each output tile
   is revisited across K steps and accumulated in float32 (bf16 inputs,
-  fp32 accumulation — MXU-native);
-* clamps block shapes to divisors of the actual dims so tiny experts
-  (granite: d_ffn = 512, tokens/expert in the hundreds) still launch
-  well-formed blocks instead of padding to 128-cubes.
+  fp32 accumulation — MXU-native).
 
 The **ragged** kernels are the dropless (MegaBlocks-style) path: the input
 is one (T, K) matrix of token rows *sorted by expert*, plus a per-expert
@@ -27,6 +22,24 @@ overlapping expert with the out-of-range rows masked (blend-store), which
 is what bounds the padding waste at < bm rows per expert instead of
 ``C - c_e`` rows per expert.
 
+Their blocks come from the shapes (:func:`ragged_tiles`), so that a call
+reads each operand from HBM about once:
+
+* ``bk`` = the whole K and ``bn`` = the whole N where the blocks fit the
+  VMEM budget.  The grid then has one step per work item; consecutive
+  items of one expert keep the weight block's index, so Pallas skips its
+  copy and each expert's weights are read once per run of its row tiles,
+  and each token tile is read once, not once per output strip.  Where the
+  double-buffered blocks and the kernel's temporaries exceed the budget,
+  ``bn`` shrinks first, then ``bk``, through the block sizes Mosaic
+  accepts (the whole dim, or a divisor that is a multiple of 128).
+* ``bm`` (:func:`row_tile`): the largest of 512, 256 and 128 rows whose
+  at most E straddling revisits and surplus items stay within an eighth of
+  the T/bm row tiles.
+* Each launch sets ``vmem_limit_bytes`` from its blocks' estimate with
+  headroom, at most ``VMEM_CAP`` (sized for v5e's 128 MiB of VMEM); the
+  default scoped limit would refuse whole-K/N blocks at Moonlight's widths.
+
 Every program-id-derived value is hoisted out of the ``pl.when`` bodies
 below, which keeps the bodies free of grid queries in both the Mosaic and
 the interpret-mode lowering.
@@ -35,6 +48,7 @@ the interpret-mode lowering.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +75,78 @@ def _block(dim: int, preferred: int) -> int:
     while dim % b:
         b -= 1
     return b if b % 128 == 0 else dim
+
+
+# VMEM of one TPU v5e core is 128 MiB.  The picker keeps a ragged kernel's
+# estimate within VMEM_BUDGET; its limit adds headroom up to VMEM_CAP.
+VMEM_BUDGET = 80 * 2**20
+VMEM_CAP = 100 * 2**20
+
+
+class Tiles(NamedTuple):
+    bm: int
+    bn: int
+    bk: int
+    vmem_bytes: int  # the estimate of the blocks and temporaries
+    vmem_limit_bytes: int
+    grid_steps: int
+
+
+def row_tile(T: int, E: int) -> int:
+    """Rows of a ragged kernel's tile: the largest of 512, 256 and 128 for
+    which the at most E straddling revisits and surplus work items stay
+    within an eighth of the T/bm row tiles, at most T rounded up to the
+    16-row sublane tile (an unaligned second-to-minor block dim would not
+    lower under Mosaic; 16 covers fp32 and bf16)."""
+    bm = next((b for b in (512, 256) if 8 * E * b <= T), 128)
+    return min(bm, max(-(-T // 16) * 16, 16))
+
+
+def _block_options(dim: int):
+    """The blocks Mosaic accepts for ``dim``, largest first: the whole dim,
+    then its divisors that are multiples of 128."""
+    return [dim] + [b for b in range(128 * ((dim - 1) // 128), 127, -128)
+                    if dim % b == 0]
+
+
+def _vmem_bytes(kernel: str, bm: int, bn: int, bk: int, a_dtype,
+                b_dtype) -> int:
+    """VMEM a ragged launch needs: its double-buffered blocks, its scratch
+    and the kernel body's fp32 temporaries.  ``a_dtype`` is the row
+    operand's; ``b_dtype`` the weights' (the cotangent rows' for dw)."""
+    a, b = jnp.dtype(a_dtype).itemsize, jnp.dtype(b_dtype).itemsize
+    tile = bm * bn * 4  # one fp32 (bm, bn) tile
+    if kernel == "ragged_dw_f32":
+        blocks = bm * bk * a + bm * bn * b + bk * bn * 4
+        # Both operands cast to fp32, and the (bk, bn) product.
+        return 2 * blocks + bm * bk * 4 + tile + bk * bn * 4
+    w = 2 if kernel == "ragged_gate_up_silu_f32" else 1
+    blocks = bm * bk * a + w * bk * bn * b + (2 * w - 1) * tile
+    cast = bk * bn * 4 * w if a != b else 0  # weights cast to fp32
+    # w accumulators, then w products and the blend-store's value.
+    return 2 * blocks + w * tile + (w + 1) * tile + cast
+
+
+def ragged_tiles(kernel: str, T: int, E: int, K: int, N: int, a_dtype,
+                 b_dtype, *, bm=None, bn=None, bk=None,
+                 budget: int = VMEM_BUDGET) -> Tiles:
+    """Blocks of one ragged launch, from its shapes: ``kernel`` is
+    ``ragged_gate_up_silu_f32``, ``ragged_matmul_f32`` or ``ragged_dw_f32``
+    over T rows of E experts, contracting K into N outputs (dw: rows of
+    (T, K) against (T, N) into (E, K, N)).  A block given is taken as
+    ``_block`` makes it; one left None is picked (module docstring)."""
+    bm = row_tile(T, E) if bm is None else bm
+    bks = _block_options(K) if bk is None else [_block(K, bk)]
+    bns = _block_options(N) if bn is None else [_block(N, bn)]
+    for k in bks:
+        for n in bns:
+            est = _vmem_bytes(kernel, bm, n, k, a_dtype, b_dtype)
+            if est <= budget:
+                limit = min(VMEM_CAP, est + est // 4 + 4 * 2**20)
+                steps = (K // k) * (N // n) * (-(-T // bm) + E)
+                return Tiles(bm, n, k, est, limit, steps)
+    raise ValueError(f"{kernel}: no blocks of ({bm}, {N}, {K}) fit "
+                     f"{budget} bytes of VMEM")
 
 
 @functools.partial(
@@ -183,17 +269,19 @@ def ragged_matmul_f32(
     offsets: jax.Array,  # (E+1,) int32; offsets[E] <= T
     *,
     bm: int = 128,
-    bn: int = 128,
-    bk: int = 512,
+    bn=None,
+    bk=None,
     interpret: bool = False,
 ) -> jax.Array:
     """out[t] = x[t] @ w[expert_of(t)] for the occupied rows t <
-    offsets[E]; rows beyond are zeroed.  fp32 accumulation."""
+    offsets[E]; rows beyond are zeroed.  fp32 accumulation.  Blocks left
+    None are picked by :func:`ragged_tiles`."""
     T, K = x.shape
     E, K2, N = w.shape
     assert K == K2 and T % bm == 0, (x.shape, w.shape, bm)
-    bn = _block(N, bn)
-    bk = _block(K, bk)
+    t = ragged_tiles("ragged_matmul_f32", T, E, K, N, x.dtype, w.dtype,
+                     bm=bm, bn=bn, bk=bk)
+    bn, bk = t.bn, t.bk
     k_steps = K // bk
     G = num_work_items(T, bm, E)
     tile_m, grp, valid, _ = ragged_metadata(offsets, bm, E, G)
@@ -216,6 +304,8 @@ def ragged_matmul_f32(
         functools.partial(_ragged_mm_kernel, bm=bm, k_steps=k_steps),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, N), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=t.vmem_limit_bytes),
         interpret=interpret,
     )(tile_m, grp, valid, offsets.astype(jnp.int32), x, w)
     # Rows no expert owns (padding tail) are uninitialized VMEM — zero them
@@ -259,8 +349,8 @@ def ragged_gate_up_silu_f32(
     offsets: jax.Array,  # (E+1,)
     *,
     bm: int = 128,
-    bn: int = 128,
-    bk: int = 512,
+    bn=None,
+    bk=None,
     interpret: bool = False,
 ):
     """Fused ragged gate·up·SiLU: one launch computes h = silu(x@wg)·(x@wu)
@@ -268,8 +358,9 @@ def ragged_gate_up_silu_f32(
     T, K = x.shape
     E, K2, F = w_gate.shape
     assert K == K2 and T % bm == 0, (x.shape, w_gate.shape, bm)
-    bn = _block(F, bn)
-    bk = _block(K, bk)
+    t = ragged_tiles("ragged_gate_up_silu_f32", T, E, K, F, x.dtype,
+                     w_gate.dtype, bm=bm, bn=bn, bk=bk)
+    bn, bk = t.bn, t.bk
     k_steps = K // bk
     G = num_work_items(T, bm, E)
     tile_m, grp, valid, _ = ragged_metadata(offsets, bm, E, G)
@@ -301,6 +392,8 @@ def ragged_gate_up_silu_f32(
         functools.partial(_ragged_gate_up_kernel, bm=bm, k_steps=k_steps),
         grid_spec=grid_spec,
         out_shape=[sh, sh, sh],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=t.vmem_limit_bytes),
         interpret=interpret,
     )(tile_m, grp, valid, offsets.astype(jnp.int32), x, w_gate, w_up)
     rows = jnp.arange(T, dtype=jnp.int32)[:, None]
@@ -341,20 +434,22 @@ def ragged_dw_f32(
     num_groups: int,
     *,
     bm: int = 128,
-    bn: int = 128,
-    bk: int = 512,
+    bn=None,
+    bk=None,
     interpret: bool = False,
 ) -> jax.Array:
     """Ragged dgrad (transposed grouped GEMM): dW[e] = x_e^T @ g_e, the
     expert-weight gradient of a ragged GEMM.  Work items run innermost so
     each expert's (K, N) accumulator tile stays resident across its
-    row-tiles."""
+    row-tiles; with the whole K and N (the picker's choice where it fits)
+    the grid is (1, 1, G) and x and g are each read once."""
     T, K = x.shape
     T2, N = g.shape
     E = num_groups
     assert T == T2 and T % bm == 0, (x.shape, g.shape, bm)
-    bk = _block(K, bk)
-    bn = _block(N, bn)
+    t = ragged_tiles("ragged_dw_f32", T, E, K, N, x.dtype, g.dtype,
+                     bm=bm, bn=bn, bk=bk)
+    bn, bk = t.bn, t.bk
     G = num_work_items(T, bm, E)
     tile_m, grp, valid, is_first = ragged_metadata(offsets, bm, E, G)
 
@@ -377,6 +472,8 @@ def ragged_dw_f32(
         functools.partial(_ragged_dw_kernel, bm=bm),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((E, K, N), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=t.vmem_limit_bytes),
         interpret=interpret,
     )(tile_m, grp, valid, is_first, offsets.astype(jnp.int32), x, g)
     # Experts with zero rows get no work item: their tiles are uninitialized.

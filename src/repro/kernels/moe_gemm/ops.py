@@ -24,6 +24,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels import interpret_mode
 from repro.kernels.moe_gemm import moe_gemm
 
@@ -67,51 +68,63 @@ def _pad_rows(x: jax.Array, bm: int):
     return jnp.pad(x, ((0, T_pad - T), (0, 0))), T
 
 
-def _row_block(T: int, preferred: int = 128) -> int:
-    """Row-tile size: rows are padded *up* to a bm multiple (they are
-    ragged, not a divisor constraint), so bound bm by T rounded to the
-    TPU sublane tile (16 covers both fp32 and bf16) — an unaligned
-    second-to-minor block dim would not lower under Mosaic."""
-    return min(preferred, max((T + 15) // 16 * 16, 16))
+def _launch(kernel, a, b, E: int, *args, bm: int, bn, bk, interpret: bool):
+    """One ragged kernel call on rows ``a`` (T, K) and ``b`` (weights
+    (E, K, N), or dw's cotangent rows (T, N)), its blocks picked from
+    the shapes where ``bn``/``bk`` are None, and counted at trace time as
+    ``moe_gemm.tiles``."""
+    name = kernel.__name__
+    t = moe_gemm.ragged_tiles(name, a.shape[0], E, a.shape[1],
+                              b.shape[-1], a.dtype, b.dtype,
+                              bm=bm, bn=bn, bk=bk)
+    obs.counter("moe_gemm.tiles", kernel=name, bm=t.bm, bn=t.bn, bk=t.bk,
+                grid_steps=t.grid_steps)
+    return kernel(a, b, *args, bm=t.bm, bn=t.bn, bk=t.bk,
+                  interpret=interpret)
 
 
-def ragged_matmul(x, w, offsets, *, interpret=None, bm=None, **blocks):
+def ragged_matmul(x, w, offsets, *, interpret=None, bm=None, bn=None,
+                  bk=None):
     """out[t] = x[t] @ w[expert_of(t)] for rows sorted by expert.
 
     x: (T, K); w: (E, K, N); offsets: (E+1,) int32 with offsets[E] <= T.
     Rows beyond offsets[E] (padding) produce zeros.  Returns x.dtype.
+    Blocks left None are picked from the shapes (``moe_gemm.ragged_tiles``).
     """
     interpret = interpret_mode() if interpret is None else interpret
-    bm = _row_block(x.shape[0]) if bm is None else bm
+    E = w.shape[0]
+    bm = moe_gemm.row_tile(x.shape[0], E) if bm is None else bm
     xp, T = _pad_rows(x, bm)
-    out = moe_gemm.ragged_matmul_f32(
-        xp, w, offsets, bm=bm, interpret=interpret, **blocks
-    )
+    out = _launch(moe_gemm.ragged_matmul_f32, xp, w, E, offsets, bm=bm,
+                  bn=bn, bk=bk, interpret=interpret)
     return out[:T].astype(x.dtype)
 
 
 @functools.lru_cache(maxsize=None)
-def _make_ragged_ffn(activation: str, interpret: bool, bm: int, bn: int,
-                     bk: int):
+def _make_ragged_ffn(activation: str, interpret: bool, bm: int, bn, bk):
     """Build the custom-VJP ragged grouped FFN for one static config.
 
     Forward: fused gate·up·SiLU launch (emits fp32 pre-activations as
     residuals) + one ragged down-projection GEMM.
     Backward: dh and dx as ragged GEMMs against the transposed expert
     weights, dW as ragged dgrads — fp32 accumulation throughout; cotangents
-    are cast back to the primal dtypes at the boundary.
+    are cast back to the primal dtypes at the boundary.  Each launch takes
+    its own blocks (``_launch``); they share ``bm``, the rows' padding.
     """
-    mm = partial(moe_gemm.ragged_matmul_f32, bm=bm, bn=bn, bk=bk,
-                 interpret=interpret)
-    dw = partial(moe_gemm.ragged_dw_f32, bm=bm, bn=bn, bk=bk,
-                 interpret=interpret)
+    blocks = dict(bm=bm, bn=bn, bk=bk, interpret=interpret)
+
+    def mm(x, w, offsets):
+        return _launch(moe_gemm.ragged_matmul_f32, x, w, w.shape[0],
+                       offsets, **blocks)
+
+    def dw(x, g, offsets, E):
+        return _launch(moe_gemm.ragged_dw_f32, x, g, E, offsets, E,
+                       **blocks)
 
     def _hidden(x, w_up, w_gate, offsets):
         if activation == "swiglu":
-            return moe_gemm.ragged_gate_up_silu_f32(
-                x, w_gate, w_up, offsets, bm=bm, bn=bn, bk=bk,
-                interpret=interpret,
-            )
+            return _launch(moe_gemm.ragged_gate_up_silu_f32, x, w_gate,
+                           w_gate.shape[0], w_up, offsets, **blocks)
         a_u = mm(x, w_up, offsets)
         return jax.nn.gelu(a_u), None, a_u
 
@@ -165,17 +178,20 @@ def _make_ragged_ffn(activation: str, interpret: bool, bm: int, bn: int,
 
 def ragged_ffn(tokens, w_up, w_gate, w_down, offsets,
                activation: str = "swiglu", *, interpret=None,
-               bm=None, bn: int = 128, bk: int = 512):
+               bm=None, bn=None, bk=None):
     """Dropless grouped expert FFN over sorted token rows.
 
     tokens: (T, d) rows sorted by expert; offsets: (E+1,) int32 prefix sums
     (offsets[E] = occupied rows <= T).  Differentiable end-to-end via the
     custom VJP; rows >= offsets[E] get zero output and zero gradient.
+    Blocks left None are picked from the shapes, per launch
+    (``moe_gemm.ragged_tiles``).
     """
     if activation == "swiglu" and w_gate is None:
         raise ValueError("swiglu ragged_ffn requires w_gate")
     interpret = interpret_mode() if interpret is None else interpret
-    bm = _row_block(tokens.shape[0]) if bm is None else bm
+    bm = moe_gemm.row_tile(tokens.shape[0], w_up.shape[0]) if bm is None \
+        else bm
     xp, T = _pad_rows(tokens, bm)
     ffn = _make_ragged_ffn(activation, interpret, bm, bn, bk)
     if activation != "swiglu":

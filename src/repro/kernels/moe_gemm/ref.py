@@ -60,6 +60,15 @@ def ragged_matmul(x: jax.Array, w: jax.Array, offsets: jax.Array):
     return jnp.where(own, out, 0.0).astype(x.dtype)
 
 
+def ragged_dw(x: jax.Array, g: jax.Array, offsets: jax.Array, E: int):
+    """dW[e] = x_e^T @ g_e over each expert's rows, in fp32; zero for an
+    expert with no rows."""
+    e = row_experts(offsets, x.shape[0])
+    onehot = (e[:, None] == jnp.arange(E)).astype(jnp.float32)
+    return jnp.einsum("te,tk,tn->ekn", onehot, x.astype(jnp.float32),
+                      g.astype(jnp.float32))
+
+
 def ragged_ffn(tokens, w_up, w_gate, w_down, offsets,
                activation: str = "swiglu"):
     """Dropless grouped FFN oracle over sorted rows; differentiable, so it

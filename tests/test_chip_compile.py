@@ -19,7 +19,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.moe_gemm import ops as mm_ops
 
-T_ROWS = 65_536  # 16,384 tokens x top-8: one 4 x 4096 batch's expert rows
+T_ROWS = 131_072  # 16,384 tokens x top-8: one 4 x 4096 batch's expert rows
 E, D, F = 40, 1536, 512
 HQ, HKV, DH, S = 24, 8, 64, 4096
 
@@ -154,3 +154,27 @@ def test_share_kernels_compile_at_moonlight_widths(one_chip):
     qk = _spec((1, 8192, 16, 192), bf, one_chip)
     v = _spec((1, 8192, 16, 128), bf, one_chip)
     _assert_mosaic(jax.grad(attn, argnums=(0, 1, 2)), qk, qk, v)
+
+
+def test_share_grad_compiles_at_moonlights_first_chunk(one_chip):
+    """The ragged FFN's grad at the first chunk of one chip's share of a
+    Moonlight layer as the cell runs it (``moe._held_chunk``: 24,576 rows
+    of 8 held experts): the picker's whole-K/N blocks need more VMEM than
+    the default scoped limit, so this compiles only with the limit each
+    launch sets from its blocks."""
+    from repro.models.moe import _held_chunk
+
+    bf = jnp.bfloat16
+    rows, e, d, f = _held_chunk(2 * 8192, 6, 8, 64), 8, 2048, 1408
+    assert rows == 24_576
+    args = (_spec((rows, d), bf, one_chip), _spec((e, d, f), bf, one_chip),
+            _spec((e, d, f), bf, one_chip), _spec((e, f, d), bf, one_chip),
+            _spec((e + 1,), jnp.int32, one_chip))
+
+    def loss(x, wu, wg, wd, offsets):
+        y = mm_ops.ragged_ffn(x, wu, wg, wd, offsets, interpret=False)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        *args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 8

@@ -82,6 +82,12 @@ RAGGED_COUNTS = [
     [1, 1, 1, 1, 1, 96, 1, 1],  # near-degenerate skew
 ]
 
+# Row tiles of 16 (many tiles and straddles), and the blocks the picker
+# takes from the shapes: the whole K and N, and a row tile larger than
+# most experts' row counts.
+RAGGED_BLOCKS = {"bm16": dict(bm=16), "picked": {}}
+BLOCK_IDS = list(RAGGED_BLOCKS)
+
 
 def _ragged_case(counts, K, N, dtype, seed=0):
     counts = np.asarray(counts)
@@ -93,11 +99,13 @@ def _ragged_case(counts, K, N, dtype, seed=0):
     return x, w, offs, E, T
 
 
+@pytest.mark.parametrize("blocks", BLOCK_IDS)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("counts", RAGGED_COUNTS)
-def test_ragged_matmul(counts, dtype):
+def test_ragged_matmul(counts, dtype, blocks):
     x, w, offs, E, T = _ragged_case(counts, K=48, N=64, dtype=dtype)
-    out = mm_ops.ragged_matmul(x, w, offs, interpret=True, bm=16)
+    out = mm_ops.ragged_matmul(x, w, offs, interpret=True,
+                               **RAGGED_BLOCKS[blocks])
     ref = mm_ref.ragged_matmul(x, w, offs)
     tol = TOL[dtype]
     np.testing.assert_allclose(
@@ -106,9 +114,10 @@ def test_ragged_matmul(counts, dtype):
     )
 
 
+@pytest.mark.parametrize("blocks", BLOCK_IDS)
 @pytest.mark.parametrize("activation", ["swiglu", "gelu"])
 @pytest.mark.parametrize("counts", RAGGED_COUNTS)
-def test_ragged_ffn_matches_oracle(counts, activation):
+def test_ragged_ffn_matches_oracle(counts, activation, blocks):
     x, _, offs, E, T = _ragged_case(counts, K=32, N=32, dtype=jnp.float32)
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     d, f = 32, 48
@@ -116,15 +125,16 @@ def test_ragged_ffn_matches_oracle(counts, activation):
     wg = jax.random.normal(ks[1], (E, d, f)) * 0.2 if activation == "swiglu" else None
     wd = jax.random.normal(ks[2], (E, f, d)) * 0.2
     out = mm_ops.ragged_ffn(x, wu, wg, wd, offs, activation,
-                            interpret=True, bm=16)
+                            interpret=True, **RAGGED_BLOCKS[blocks])
     ref = mm_ref.ragged_ffn(x, wu, wg, wd, offs, activation)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5
     )
 
 
+@pytest.mark.parametrize("blocks", BLOCK_IDS)
 @pytest.mark.parametrize("activation", ["swiglu", "gelu"])
-def test_ragged_ffn_custom_vjp_matches_jax_grad(activation):
+def test_ragged_ffn_custom_vjp_matches_jax_grad(activation, blocks):
     """The hand-written backward (two ragged GEMMs + ragged dgrads) must
     equal jax.grad through the differentiable XLA reference."""
     counts = [7, 0, 83, 1, 9]
@@ -139,7 +149,7 @@ def test_ragged_ffn_custom_vjp_matches_jax_grad(activation):
     def kernel_loss(x, wu, wg, wd):
         wg_ = wg if activation == "swiglu" else None
         y = mm_ops.ragged_ffn(x, wu, wg_, wd, offs, activation,
-                              interpret=True, bm=16)
+                              interpret=True, **RAGGED_BLOCKS[blocks])
         return (y * cot).sum()
 
     def ref_loss(x, wu, wg, wd):
@@ -158,7 +168,8 @@ def test_ragged_ffn_custom_vjp_matches_jax_grad(activation):
         )
 
 
-def test_ragged_ffn_ignores_a_long_unowned_tail():
+@pytest.mark.parametrize("blocks", BLOCK_IDS)
+def test_ragged_ffn_ignores_a_long_unowned_tail(blocks):
     """Rows no expert owns fill most of x (one chip's share of a layer,
     whose rows for absent experts sort to a tail): the static grid's
     surplus items leave them zero with zero gradient, and the owned rows'
@@ -169,10 +180,12 @@ def test_ragged_ffn_ignores_a_long_unowned_tail():
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
     wu, wg = (jax.random.normal(k, (E, 32, 48)) * 0.2 for k in ks[:2])
     wd = jax.random.normal(ks[2], (E, 48, 32)) * 0.2
+    # The tail must not change the row tile, or the sums run otherwise.
+    bm = RAGGED_BLOCKS[blocks].get("bm", 16 * -(-T // 16))
 
     def grads(x):
         def f(x, wu, wg, wd):
-            y = mm_ops.ragged_ffn(x, wu, wg, wd, offs, interpret=True, bm=16)
+            y = mm_ops.ragged_ffn(x, wu, wg, wd, offs, interpret=True, bm=bm)
             return jnp.sum(jnp.sin(y)), y
         return jax.grad(f, argnums=(0, 1, 2, 3), has_aux=True)(x, wu, wg, wd)
 
@@ -185,14 +198,181 @@ def test_ragged_ffn_ignores_a_long_unowned_tail():
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
 
 
-def test_ragged_matmul_empty_tail_rows_zero():
+@pytest.mark.parametrize("bm", [8, None])
+def test_ragged_matmul_empty_tail_rows_zero(bm):
     """Rows beyond offsets[-1] (padding) must come back exactly zero."""
-    counts = [5, 3]
     offs = jnp.asarray([0, 5, 8], jnp.int32)
     x = jax.random.normal(jax.random.PRNGKey(0), (16, 32))  # 8 pad rows
     w = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 16))
-    out = np.asarray(mm_ops.ragged_matmul(x, w, offs, interpret=True, bm=8))
+    out = np.asarray(mm_ops.ragged_matmul(x, w, offs, interpret=True, bm=bm))
     assert (out[8:] == 0).all()
+
+
+# Widths with several 128-multiples, so that split blocks (several output
+# strips and K steps) and the picker's whole-K/N blocks both run.
+SPLIT = dict(bn=128, bk=128)
+WIDE_BLOCKS = {"bm16": dict(bm=16), "picked": {}, "split": dict(bm=16, **SPLIT),
+               "split_bm32": dict(bm=32, **SPLIT)}
+
+
+@pytest.mark.parametrize("blocks", list(WIDE_BLOCKS))
+@pytest.mark.parametrize("counts", [[7, 0, 83, 1, 9], [40, 0, 0, 2]])
+def test_ragged_dw_matches_oracle(counts, blocks):
+    """The ragged dgrad against its einsum oracle, bf16 token rows and fp32
+    cotangents as the FFN's backward passes them; an expert with no rows
+    gets a zero gradient."""
+    from repro.kernels.moe_gemm import moe_gemm
+
+    x, _, offs, E, T = _ragged_case(counts, K=256, N=8, dtype=jnp.bfloat16)
+    g = jax.random.normal(jax.random.PRNGKey(4), (T, 384), jnp.float32)
+    kw = {"bm": moe_gemm.row_tile(T, E), **WIDE_BLOCKS[blocks]}
+    pad = -T % kw["bm"]
+    xp = jnp.pad(x, ((0, pad), (0, 0)))
+    gp = jnp.pad(g, ((0, pad), (0, 0)))
+    out = moe_gemm.ragged_dw_f32(xp, gp, offs, E, interpret=True, **kw)
+    ref = mm_ref.ragged_dw(x, g, offs, E)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-4)
+    empty = np.asarray(counts) == 0
+    assert not np.asarray(out)[empty].any()
+
+
+@pytest.mark.parametrize("blocks", list(WIDE_BLOCKS))
+def test_ragged_ffn_wide_blocks_match_oracle(blocks):
+    """The FFN and its custom VJP at widths of 256 and 384, where split
+    blocks take several K steps and output strips and the picker takes
+    whole K and N, against the XLA reference."""
+    counts = [7, 0, 83, 1, 9]
+    x, _, offs, E, T = _ragged_case(counts, K=256, N=8, dtype=jnp.float32)
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    d, f = 256, 384
+    wu, wg = (jax.random.normal(k, (E, d, f)) * 0.05 for k in ks[:2])
+    wd = jax.random.normal(ks[2], (E, f, d)) * 0.05
+
+    def loss(fn, **kw):
+        def f(x, wu, wg, wd):
+            y = fn(x, wu, wg, wd, offs, **kw)
+            return jnp.sum(jnp.sin(y)), y
+        return jax.grad(f, argnums=(0, 1, 2, 3), has_aux=True)(x, wu, wg, wd)
+
+    gk, yk = loss(mm_ops.ragged_ffn, interpret=True, **WIDE_BLOCKS[blocks])
+    gr, yr = loss(mm_ref.ragged_ffn)
+    np.testing.assert_allclose(np.asarray(yk), np.asarray(yr),
+                               rtol=1e-4, atol=1e-4)
+    for name, a, b in zip(("dx", "dwu", "dwg", "dwd"), gk, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4, err_msg=name)
+
+
+def _ffn_launches(d, f):
+    """(kernel, K, N, row dtype, other dtype) of the ragged FFN's launches,
+    forward and backward, as ``ops._make_ragged_ffn`` makes them."""
+    bf, f32 = jnp.bfloat16, jnp.float32
+    mm, dw = "ragged_matmul_f32", "ragged_dw_f32"
+    return [("ragged_gate_up_silu_f32", d, f, bf, bf),  # gate, up
+            (mm, f, d, f32, bf),  # down
+            (mm, d, f, f32, bf),  # dh
+            (mm, f, d, f32, bf),  # dx_gate, dx_up
+            (dw, f, d, f32, f32),  # dW_down
+            (dw, d, f, bf, f32)]  # dW_gate, dW_up
+
+
+@pytest.mark.parametrize("T,E,d,f", [(131_072, 40, 1536, 512),
+                                     (24_576, 8, 2048, 1408)],
+                         ids=["granite", "moonlight_share"])
+def test_tiles_take_whole_k_and_n_at_the_cells_widths(T, E, d, f):
+    """At granite's widths (16,384 tokens x top-8) and at the first chunk
+    of one chip's share of a Moonlight layer, every launch of the FFN takes
+    the whole K and N, 256-row tiles, one grid step per work item, and a
+    VMEM limit above its estimate and within the cap."""
+    from repro.kernels.moe_gemm import moe_gemm
+
+    for kernel, K, N, a, b in _ffn_launches(d, f):
+        t = moe_gemm.ragged_tiles(kernel, T, E, K, N, a, b)
+        assert (t.bm, t.bk, t.bn) == (256, K, N), (kernel, t)
+        assert t.grid_steps == T // 256 + E
+        assert t.vmem_bytes < t.vmem_limit_bytes <= moe_gemm.VMEM_CAP
+        assert t.vmem_bytes <= moe_gemm.VMEM_BUDGET
+
+
+def test_row_tile_follows_rows_and_experts():
+    from repro.kernels.moe_gemm import moe_gemm
+
+    assert moe_gemm.row_tile(131_072, 40) == 256  # 512 would waste 15.6 %
+    assert moe_gemm.row_tile(49_152, 8) == 512
+    assert moe_gemm.row_tile(3_072, 8) == 128
+    assert moe_gemm.row_tile(100, 5) == 112  # 100 rows to the 16-row tile
+    assert moe_gemm.row_tile(1, 1) == 16
+
+
+def test_tiles_shrink_n_then_k_to_fit_the_budget():
+    """Where the whole K and N cannot fit, the picker shrinks N first, then
+    K, through the blocks Mosaic accepts, and never passes the budget; with
+    no block that fits it refuses."""
+    from repro.kernels.moe_gemm import moe_gemm
+
+    f32, mm = jnp.float32, "ragged_matmul_f32"
+    t = moe_gemm.ragged_tiles(mm, 65_536, 8, 4096, 8192, f32, f32)
+    assert (t.bm, t.bk, t.bn) == (512, 4096, 1024)  # bn 2048 does not fit
+    assert t.vmem_bytes <= moe_gemm.VMEM_BUDGET
+    assert t.vmem_bytes < t.vmem_limit_bytes <= moe_gemm.VMEM_CAP
+    # 8320 = 65 x 128: no bn of 8320, 1664 or 640 fits beside a whole K of
+    # 16,384, so K halves; 640 then fits.
+    wider = moe_gemm.ragged_tiles(mm, 65_536, 8, 16_384, 8320, f32, f32)
+    assert (wider.bk, wider.bn) == (8192, 640)
+    assert wider.vmem_bytes < wider.vmem_limit_bytes <= moe_gemm.VMEM_CAP
+    small = moe_gemm.ragged_tiles("ragged_dw_f32", 65_536, 8, 8192, 8192,
+                                  f32, f32, budget=8 * 2**20)
+    assert (small.bk, small.bn) == (512, 256)
+    assert small.vmem_bytes <= 8 * 2**20
+    with pytest.raises(ValueError, match="fit"):
+        moe_gemm.ragged_tiles("ragged_dw_f32", 65_536, 8, 9000, 9000,
+                              f32, f32)
+
+
+def test_ffn_counts_each_launch_with_its_tiles():
+    """``moe_gemm.tiles`` is counted once per traced kernel launch: two in
+    the forward, six more in the backward, each with the blocks it ran
+    (at full widths, traced without running, every launch takes one grid
+    step per work item)."""
+    from repro import obs
+
+    bf = jnp.bfloat16
+    T, E, d, f = 24_576, 8, 2048, 1408
+    args = (jax.ShapeDtypeStruct((T, d), bf),
+            jax.ShapeDtypeStruct((E, d, f), bf),
+            jax.ShapeDtypeStruct((E, d, f), bf),
+            jax.ShapeDtypeStruct((E, f, d), bf),
+            jax.ShapeDtypeStruct((E + 1,), jnp.int32))
+
+    def fwd(x, wu, wg, wd, offs):
+        return mm_ops.ragged_ffn(x, wu, wg, wd, offs, interpret=False)
+
+    def loss(*a):
+        return jnp.sum(fwd(*a).astype(jnp.float32))
+
+    ring = obs.RingBufferSink()
+
+    def tiles():
+        return [e["attrs"] for e in ring.events()
+                if e["kind"] == "counter" and e["name"] == "moe_gemm.tiles"]
+
+    prev = obs.set_telemetry(obs.Telemetry(sinks=[ring]))
+    try:
+        jax.eval_shape(fwd, *args)
+        n_fwd = len(tiles())
+        jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2, 3)), *args)
+    finally:
+        obs.set_telemetry(prev)
+    tiles = tiles()
+    assert n_fwd == 2 and len(tiles) == 2 + 8
+    assert [t["kernel"] for t in tiles[:2]] == ["ragged_gate_up_silu_f32",
+                                                "ragged_matmul_f32"]
+    kinds = sorted(t["kernel"] for t in tiles[4:])
+    assert kinds == ["ragged_dw_f32"] * 3 + ["ragged_matmul_f32"] * 3
+    for t in tiles:
+        assert t["bm"] == 256 and t["grid_steps"] == T // 256 + E, t
+        assert {t["bk"], t["bn"]} == {d, f}, t
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
