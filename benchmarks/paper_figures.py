@@ -327,7 +327,7 @@ def kernels() -> List[Row]:
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels.moe_gemm import ops as mm_ops, ref as mm_ref
+    from repro.kernels.moe_gemm import ref as mm_ref
 
     E, M, K, N = 8, 128, 256, 256
     key = jax.random.PRNGKey(0)

@@ -169,7 +169,6 @@ def pipelined_stack_forward(
     plan: MeshPlan,
     *,
     positions: jax.Array,
-    impl: str = "xla",
     num_microbatches: Optional[int] = None,
     vstages: Optional[int] = None,
     embed_fn=None,  # (embed_params, tokens (b_mu, s)) -> (b_mu, s, d)
@@ -204,7 +203,7 @@ def pipelined_stack_forward(
     if V > 1:
         return _pipelined_stack_forward_v(
             block_params, x, arch, plan, V,
-            positions=positions, impl=impl,
+            positions=positions,
             num_microbatches=num_microbatches,
             embed_fn=embed_fn, embed_params=embed_params,
         )
@@ -246,7 +245,6 @@ def pipelined_stack_forward(
                 arch,
                 plan,
                 positions=pos_mu,
-                impl=impl,
                 token_sharded=True,
                 unroll=True,
             )
@@ -357,7 +355,7 @@ def pipelined_stack_forward(
 
 def _pipelined_stack_forward_v(
     block_params, x, arch: ArchConfig, plan: MeshPlan, V: int, *,
-    positions, impl, num_microbatches, embed_fn, embed_params,
+    positions, num_microbatches, embed_fn, embed_params,
 ):
     """Vstage F-projection executor (see ``pipelined_stack_forward``):
     interprets ``schedules.forward_tick_tables_v`` — per tick, each stage
@@ -434,7 +432,7 @@ def _pipelined_stack_forward_v(
             inp = constrain(jnp.where(first_chunk, x0, h_in))
             h_out, aux_d, loads_d = transformer.stack_forward(
                 chunk, inp, arch, plan,
-                positions=pos_mu, impl=impl, token_sharded=True,
+                positions=pos_mu, token_sharded=True,
                 unroll=True,
             )
             h_out = constrain(h_out)
@@ -553,7 +551,6 @@ def pipelined_step(
     head_params,
     schedule: Optional[str] = None,
     vstages: Optional[int] = None,
-    impl: str = "xla",
     num_microbatches: Optional[int] = None,
     embed_fn=None,
     embed_params=None,
@@ -698,7 +695,7 @@ def pipelined_step(
             inp = constrain(jnp.where(first_chunk, x_emb, h_in))
             h_out, aux_d, loads_d = transformer.stack_forward(
                 chunk, inp, arch, plan,
-                positions=pos_mu, impl=impl, token_sharded=True, unroll=True,
+                positions=pos_mu, token_sharded=True, unroll=True,
             )
             return (
                 constrain(h_out),

@@ -1,11 +1,10 @@
 """Pallas TPU kernels for the compute hot-spots the paper identifies:
 
-* ``moe_gemm``        -- grouped (per-expert) GEMM; the tall-and-skinny
-                         regime of fine-grained MoE (paper Fig 4)
-* ``flash_attention`` -- block-tiled attention (paper SSIV-A benchmarks it);
-                         ``ops.causal_attention`` trains causal attention
+* ``moe_gemm``        -- ragged grouped (per-expert) GEMM of the dropless
+                         dispatch; the tall-and-skinny regime of
+                         fine-grained MoE (paper Fig 4)
+* ``flash_attention`` -- ``ops.causal_attention`` trains causal attention
                          through JAX's block-sparse TPU splash kernel
-* ``ssd``             -- Mamba2 SSD intra-chunk kernel (mamba2/jamba archs)
 
 Each kernel ships with ``ops.py`` (the jit'd public wrapper with an
 ``interpret`` switch) and ``ref.py`` (pure-jnp oracle, called only by tests

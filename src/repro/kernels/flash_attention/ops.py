@@ -1,12 +1,8 @@
-"""Public wrappers: layout adaptation + interpret switch.
+"""Trainable causal GQA attention through JAX's TPU splash-attention kernel
+(``causal_attention``): fully masked blocks are skipped in the forward, dq
+and dkv kernels, with an fp32 online softmax and a custom VJP.
 
-The model keeps activations as (b, s, h, d); the kernels want (b, h, s, d).
-
-* ``flash_attention``  -- the repo's forward-only hand kernel (causal,
-  window, softcap); no VJP.
-* ``causal_attention`` -- trainable causal GQA attention through JAX's TPU
-  splash-attention kernel: fully masked blocks are skipped in the forward,
-  dq and dkv kernels, with an fp32 online softmax and a custom VJP.
+The model keeps activations as (b, s, h, d); the kernel wants (b, h, s, d).
 """
 
 from __future__ import annotations
@@ -20,34 +16,6 @@ import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 from repro.kernels import interpret_mode
-from repro.kernels.flash_attention import flash_attention as fa
-
-
-def flash_attention(
-    q: jax.Array,  # (b, s, hq, d) — model layout
-    k: jax.Array,  # (b, s, hkv, d)
-    v: jax.Array,
-    *,
-    causal: bool = True,
-    window: Optional[int] = None,
-    logit_softcap: Optional[float] = None,
-    interpret: Optional[bool] = None,
-    bq: int = 256,
-    bk: int = 256,
-) -> jax.Array:
-    interpret = interpret_mode() if interpret is None else interpret
-    out = fa.flash_attention(
-        q.transpose(0, 2, 1, 3),
-        k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3),
-        causal=causal,
-        window=window,
-        softcap=logit_softcap,
-        bq=bq,
-        bk=bk,
-        interpret=interpret,
-    )
-    return out.transpose(0, 2, 1, 3)
 
 
 # The q/kv block of the forward and the fused backward kernel at long

@@ -1,19 +1,14 @@
-"""Grouped per-expert GEMM Pallas TPU kernels: padded (capacity) + ragged.
+"""Ragged grouped per-expert GEMM Pallas TPU kernels.
 
-Computes ``out[e] = x[e] @ w[e]`` for E experts in one launch.  This is the
-paper's skinny-GEMM hot spot (§II-A, Fig 4): fine-grained experts make both
-M (tokens-per-expert) and N (= d_ffn/TP) small, so a naive per-expert loop
-starves the MXU.  The padded kernel:
+Computes each expert's rows times its weights for E experts in one launch.
+This is the paper's skinny-GEMM hot spot (§II-A, Fig 4): fine-grained
+experts make both M (tokens-per-expert) and N (= d_ffn/TP) small, so a
+naive per-expert loop starves the MXU.  The kernels accumulate in float32
+(bf16 inputs, fp32 accumulation — MXU-native).
 
-* tiles (M, N, K) into (128, 128, 512) blocks, clamped to divisors of the
-  actual dims so tiny experts still launch well-formed blocks;
-* walks the grid (E, M/bm, N/bn, K/bk) with K innermost so each output tile
-  is revisited across K steps and accumulated in float32 (bf16 inputs,
-  fp32 accumulation — MXU-native).
-
-The **ragged** kernels are the dropless (MegaBlocks-style) path: the input
-is one (T, K) matrix of token rows *sorted by expert*, plus a per-expert
-prefix-sum ``offsets`` (E+1,).  A work-item list maps each grid step to the
+They are the dropless (MegaBlocks-style) path: the input is one (T, K)
+matrix of token rows *sorted by expert*, plus a per-expert prefix-sum
+``offsets`` (E+1,).  A work-item list maps each grid step to the
 (row-tile, expert) pairs that actually contain tokens, delivered to the
 index maps through scalar prefetch, so only occupied tiles are launched —
 an expert with c_e rows costs ceil(c_e/bm) tiles instead of a fixed
@@ -54,16 +49,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-
-def _matmul_kernel(x_ref, w_ref, o_ref, *, k_steps: int):
-    @pl.when(pl.program_id(3) == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    o_ref[0, ...] += jnp.dot(
-        x_ref[0], w_ref[0], preferred_element_type=jnp.float32
-    )
 
 
 def _block(dim: int, preferred: int) -> int:
@@ -147,42 +132,6 @@ def ragged_tiles(kernel: str, T: int, E: int, K: int, N: int, a_dtype,
                 return Tiles(bm, n, k, est, limit, steps)
     raise ValueError(f"{kernel}: no blocks of ({bm}, {N}, {K}) fit "
                      f"{budget} bytes of VMEM")
-
-
-@functools.partial(
-    jax.jit, static_argnames=("bm", "bn", "bk", "interpret")
-)
-def grouped_matmul_f32(
-    x: jax.Array,  # (E, M, K)
-    w: jax.Array,  # (E, K, N)
-    *,
-    bm: int = 128,
-    bn: int = 128,
-    bk: int = 512,
-    interpret: bool = False,
-) -> jax.Array:
-    """Float32-accumulated grouped matmul; cast at the call site."""
-    E, M, K = x.shape
-    E2, K2, N = w.shape
-    assert E == E2 and K == K2, (x.shape, w.shape)
-
-    bm = _block(M, bm)
-    bn = _block(N, bn)
-    bk = _block(K, bk)
-    k_steps = K // bk
-    grid = (E, M // bm, N // bn, k_steps)
-
-    return pl.pallas_call(
-        functools.partial(_matmul_kernel, k_steps=k_steps),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda e, m, n, k: (e, m, k)),
-            pl.BlockSpec((1, bk, bn), lambda e, m, n, k: (e, k, n)),
-        ],
-        out_specs=pl.BlockSpec((1, bm, bn), lambda e, m, n, k: (e, m, n)),
-        out_shape=jax.ShapeDtypeStruct((E, M, N), jnp.float32),
-        interpret=interpret,
-    )(x, w)
 
 
 # ---------------------------------------------------------------------------

@@ -1,15 +1,11 @@
-"""Public jit'd wrappers for the grouped expert GEMM kernels.
+"""Public jit'd wrappers for the ragged grouped expert GEMM kernels.
 
-Two families:
-
-* ``grouped_matmul`` / ``grouped_ffn`` — the padded capacity-dispatch path:
-  (E, C, d) buffers, three dense launches.
-* ``ragged_matmul`` / ``ragged_ffn`` — the dropless path: one (T, d) matrix
-  of token rows sorted by expert + per-expert ``offsets`` (E+1,).
-  ``ragged_ffn`` carries a ``jax.custom_vjp`` so the backward pass also runs
-  as ragged kernels (two ragged GEMMs for dh/dx + ragged dgrads for the
-  expert weights) with fp32 accumulation in both directions — ``jax.grad``
-  through it never sees the Pallas internals.
+``ragged_matmul`` / ``ragged_ffn`` run the dropless path: one (T, d) matrix
+of token rows sorted by expert + per-expert ``offsets`` (E+1,).
+``ragged_ffn`` carries a ``jax.custom_vjp`` so the backward pass also runs as
+ragged kernels (two ragged GEMMs for dh/dx + ragged dgrads for the expert
+weights) with fp32 accumulation in both directions — ``jax.grad`` through it
+never sees the Pallas internals.
 
 Precision contract: bf16 (or fp32) inputs, fp32 accumulation everywhere,
 and the hidden activation stays fp32 *between* launches — the only cast
@@ -19,7 +15,6 @@ back to the input dtype happens after the final down-projection.
 from __future__ import annotations
 
 import functools
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -27,36 +22,6 @@ import jax.numpy as jnp
 from repro import obs
 from repro.kernels import interpret_mode
 from repro.kernels.moe_gemm import moe_gemm
-
-
-def grouped_matmul(x, w, *, interpret=None, **blocks):
-    interpret = interpret_mode() if interpret is None else interpret
-    out = moe_gemm.grouped_matmul_f32(x, w, interpret=interpret, **blocks)
-    return out.astype(x.dtype)
-
-
-def grouped_ffn(tokens, w_up, w_gate, w_down, activation: str = "swiglu",
-                *, interpret=None, **blocks):
-    """Expert FFN: three grouped GEMMs + gated activation (elementwise ops
-    fused by XLA between kernel launches).
-
-    The hidden activation h is kept in fp32 between the up/gate and down
-    launches: casting it to the token dtype would silently truncate the
-    fp32 accumulation the kernel exists to provide (the down-projection
-    contracts over d_ffn, so the truncation error compounds with width).
-    """
-    interpret = interpret_mode() if interpret is None else interpret
-    mm = partial(moe_gemm.grouped_matmul_f32, interpret=interpret, **blocks)
-    if activation == "swiglu":
-        h = jax.nn.silu(mm(tokens, w_gate)) * mm(tokens, w_up)
-    else:
-        h = jax.nn.gelu(mm(tokens, w_up))
-    return mm(h, w_down).astype(tokens.dtype)
-
-
-# ---------------------------------------------------------------------------
-# Ragged (dropless) path
-# ---------------------------------------------------------------------------
 
 
 def _pad_rows(x: jax.Array, bm: int):

@@ -2,9 +2,8 @@
 
 Both the padded (E, C, d) capacity layout and the ragged sorted-rows +
 offsets layout have an oracle here.  The ragged oracles gather the full
-per-row expert weight (O(T·d·f) temp) — they exist for correctness
-reference and as the XLA fallback of the ragged dispatch path on shapes
-where that temp is acceptable; the Pallas kernels are the perf path.
+per-row expert weight (O(T·d·f) temp): they are references for tests and
+benchmarks, and the ragged dispatch always runs the Pallas kernels.
 """
 
 from __future__ import annotations
@@ -23,7 +22,7 @@ def grouped_matmul(x: jax.Array, w: jax.Array) -> jax.Array:
 def grouped_ffn(tokens, w_up, w_gate, w_down, activation: str = "swiglu"):
     """tokens: (E, C, d) -> (E, C, d); the MoE expert-FFN oracle.
 
-    Mirrors the kernel path's precision contract: the hidden activation
+    Mirrors the expert FFN's precision contract: the hidden activation
     stays fp32 until after the down-projection.
     """
     if activation == "swiglu":

@@ -49,7 +49,6 @@ def parse_args(argv=None):
                          "model's Young-Daly optimal interval (clamped to "
                          "[1, steps/2]), else 50")
     ap.add_argument("--corpus", default=None, help="memmap token corpus path")
-    ap.add_argument("--impl", default="xla", choices=["xla", "pallas"])
     ap.add_argument("--dispatch", default=None,
                     help="MoE expert dispatch (capacity|ragged); default: "
                          "the planner's ranked choice")
@@ -230,7 +229,7 @@ def setup(args):
           + (f" vstages={plan.vstages}"
              if plan.pp > 1 and plan.vstages > 1 else ""))
 
-    lm = LanguageModel(arch, plan, impl=args.impl)
+    lm = LanguageModel(arch, plan)
     # Warm up for at most a tenth of the run: a run shorter than the
     # default 100-step warmup would otherwise never reach its learning rate.
     opt = OptimizerConfig(lr=args.lr, total_steps=args.steps,

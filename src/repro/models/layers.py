@@ -2,10 +2,9 @@
 
 All functions are pure; parameters are plain dict pytrees.  Causal
 attention with no cache trains through a block-sparse flash kernel where
-``attention_path`` finds a one-device TPU; elsewhere ``impl`` selects "xla"
-(jnp reference, used by dry-runs — GSPMD inserts the K/V all-gathers for
-sequence-sharded inputs) or "pallas" (the forward-only flash-attention hand
-kernel from repro.kernels, validated in interpret mode).
+``attention_path`` finds a one-device TPU; everywhere else (windows, caches,
+meshes, other backends) it runs the jnp formula, where GSPMD inserts the
+K/V all-gathers for sequence-sharded inputs.
 """
 
 from __future__ import annotations
@@ -246,15 +245,15 @@ def attention_path(backend: str, s: int, *, window: Optional[int],
 
 
 @jax.named_scope("attention")
-def attention_proj(params, x, cfg, positions, *, impl="xla", window=None,
-                   cache=None, cache_index=None, return_kv=False, plan=None):
+def attention_proj(params, x, cfg, positions, *, window=None, cache=None,
+                   cache_index=None, return_kv=False, plan=None):
     """Full attention sub-layer: QKV proj -> rope -> attention -> out proj,
     under the ``attention`` scope (the compiled step's op metadata).
 
     The attention itself is chosen by backend and shape (``attention_path``,
     counted as ``attention.path`` at trace time): the trainable flash kernel
-    where it can run, else ``impl`` selects the opt-in hand kernel
-    ("pallas", forward only) or the XLA reference ("xla").
+    where it can run, else the XLA formula (``_xla_causal`` with no cache,
+    the paged and dense decode branches with one).
 
     cache: optional dict {"k": (b, S, hkv, d), "v": ...} — decode path.
     return_kv=True additionally returns the freshly computed K/V (prefill).
@@ -315,13 +314,6 @@ def attention_proj(params, x, cfg, positions, *, impl="xla", window=None,
             window=window,
             logit_softcap=cfg.attn_logit_softcap,
             kv_len=cache_index + s,
-        )
-    elif impl == "pallas":
-        from repro.kernels.flash_attention import ops as fa_ops
-
-        out = fa_ops.flash_attention(
-            q, k, v, causal=True, window=window,
-            logit_softcap=cfg.attn_logit_softcap,
         )
     else:
         out = _xla_causal(q, k, v, window, cfg.attn_logit_softcap, plan)

@@ -297,12 +297,13 @@ def refuse(a: ArchConfig, what: str, *, mla: bool) -> None:
 
 
 class LanguageModel:
-    """Bundles an ArchConfig + MeshPlan + kernel implementation choice."""
+    """Bundles an ArchConfig + MeshPlan.  Each layer picks its kernel from
+    what it observes: attention from backend, shape and mesh
+    (``layers.attention_path``), the expert FFN from the dispatch mode."""
 
-    def __init__(self, arch: ArchConfig, plan: MeshPlan, impl: str = "xla"):
+    def __init__(self, arch: ArchConfig, plan: MeshPlan):
         self.arch = arch
         self.plan = plan
-        self.impl = impl
         self.vp = arch.padded_vocab(VOCAB_PAD_MULTIPLE)
 
     # -- embedding / head ---------------------------------------------------
@@ -395,7 +396,7 @@ class LanguageModel:
             )
             return pipeline.pipelined_stack_forward(
                 params["blocks"], x, a, self.plan,
-                positions=positions, impl=self.impl,
+                positions=positions,
                 embed_fn=embed_fn, embed_params=embed_params,
             )
         x = self._embed(params, batch)
@@ -404,12 +405,12 @@ class LanguageModel:
         if a.first_k_dense:
             x, _, _ = transformer.stack_forward(
                 params["prefix"], x, a, self.plan, positions=positions,
-                impl=self.impl, token_sharded=token_sharded,
+                token_sharded=token_sharded,
                 pattern=(a.prefix_block,),
             )
         return transformer.stack_forward(
             params["blocks"], x, a, self.plan,
-            positions=positions, impl=self.impl,
+            positions=positions,
             token_sharded=token_sharded,
         )
 
@@ -507,7 +508,6 @@ class LanguageModel:
             head_params=head_params,
             schedule=schedule,
             vstages=vstages,
-            impl=self.impl,
             embed_fn=embed_fn,
             embed_params=embed_params,
         )
@@ -640,7 +640,6 @@ class LanguageModel:
                     a,
                     self.plan,
                     positions=positions,
-                    impl=self.impl,
                     cache=rep_cache[pos],
                     cache_index=index,
                     token_sharded=False,
@@ -714,7 +713,6 @@ class LanguageModel:
                     a,
                     self.plan,
                     positions=positions,
-                    impl=self.impl,
                     return_cache=True,
                     token_sharded=True,
                 )
@@ -778,7 +776,6 @@ class LanguageModel:
                     a,
                     self.plan,
                     positions=positions,
-                    impl=self.impl,
                     cache=pc,
                     token_sharded=False,
                 )
@@ -820,7 +817,6 @@ class LanguageModel:
                     a,
                     self.plan,
                     positions=positions,
-                    impl=self.impl,
                     return_cache=True,
                     token_sharded=True,
                 )

@@ -199,11 +199,12 @@ def _dispatch_indices(top_i, top_w, E: int, capacity: int):
 
 @jax.named_scope("moe.experts")
 def _expert_ffn(tokens, w_up, w_gate, w_down, activation: str):
-    """Grouped expert GEMM. tokens: (E_l, C_r, d).
+    """Grouped expert GEMM of capacity dispatch, as XLA einsums.
+    tokens: (E_l, C_r, d).
 
-    fp32 accumulation (preferred_element_type) so the bf16 XLA baseline is
-    numerically comparable with the Pallas kernels, which accumulate in
-    fp32 natively; only the final down-projection casts back.
+    The matmuls accumulate in fp32 (``preferred_element_type``) and the
+    hidden activation stays fp32 into the down-projection, as in the ragged
+    kernels; only the output casts back to the tokens' dtype.
     """
     f32 = jnp.float32
     if activation == "swiglu":
@@ -217,13 +218,6 @@ def _expert_ffn(tokens, w_up, w_gate, w_down, activation: str):
                                    preferred_element_type=f32))
     out = jnp.einsum("ecf,efd->ecd", h, w_down, preferred_element_type=f32)
     return out.astype(tokens.dtype)
-
-
-@jax.named_scope("moe.experts")
-def _expert_ffn_pallas(tokens, w_up, w_gate, w_down, activation: str):
-    from repro.kernels.moe_gemm import ops as moe_ops
-
-    return moe_ops.grouped_ffn(tokens, w_up, w_gate, w_down, activation)
 
 
 # -- ragged (sort-based, dropless) dispatch ---------------------------------
@@ -600,7 +594,7 @@ def _select_a2a(plan: MeshPlan):
 
 
 @jax.named_scope("moe.dispatch")
-def _moe_capacity_sharded(buf, wu_f, wg_f, wd_f, activation: str, ffn_fn,
+def _moe_capacity_sharded(buf, wu_f, wg_f, wd_f, activation: str,
                           ep_size: int, E_l: int, capacity: int, d: int,
                           a2a, chunks: int):
     """Capacity-mode EP dispatch -> grouped FFN -> combine, chunked along
@@ -621,7 +615,7 @@ def _moe_capacity_sharded(buf, wu_f, wg_f, wd_f, activation: str, ffn_fn,
             .transpose(1, 0, 2, 3)
             .reshape(E_l, ep_size * size, d)
         )
-        expert_out = ffn_fn(expert_in, wu_f, wg_f, wd_f, activation)
+        expert_out = _expert_ffn(expert_in, wu_f, wg_f, wd_f, activation)
         return (
             expert_out.reshape(E_l, ep_size, size, d)
             .transpose(1, 0, 2, 3)
@@ -643,8 +637,6 @@ def moe_ffn_local(
     params: Dict[str, jax.Array],
     x: jax.Array,  # (b, s, d) — the caller's full (replicated) token block
     arch: ArchConfig,
-    *,
-    impl: str = "xla",
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Collective-free single-rank MoE: the exact routing/capacity/expert
     math of :func:`moe_ffn`'s body with EP = 1 and no mesh — the reference
@@ -679,9 +671,7 @@ def moe_ffn_local(
             top_phys, top_w, E, capacity
         )
         buf = _scatter_to_buffers(xt, flat_e, pos, keep, E, capacity)
-
-        ffn_fn = _expert_ffn_pallas if impl == "pallas" else _expert_ffn
-        y_buf = ffn_fn(
+        y_buf = _expert_ffn(
             buf, params["w_up"], wg, params["w_down"], arch.ffn_activation
         )
         vals = y_buf[flat_e, pos]
@@ -725,7 +715,6 @@ def moe_ffn(
     plan: MeshPlan,
     *,
     token_sharded: bool = True,
-    impl: str = "xla",
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """MoE FFN sub-layer (global view; explicit shard_map inside).
 
@@ -760,7 +749,6 @@ def moe_ffn(
     wu_spec = P("ep", None, ("data", "tp"))
     wd_spec = P("ep", ("data", "tp"), None)
 
-    ffn_fn = _expert_ffn_pallas if impl == "pallas" else _expert_ffn
     # In the decode path tokens are replicated over ep/tp — mean metrics
     # over the axes the batch dim is ACTUALLY sharded on.  ``dp_spec`` is
     # None when the batch does not divide the dp axes (e.g. batch-1
@@ -888,7 +876,7 @@ def moe_ffn(
 
         if token_sharded and ep_size > 1:
             y_buf = _moe_capacity_sharded(
-                buf, wu_f, wg_f, wd_f, arch.ffn_activation, ffn_fn,
+                buf, wu_f, wg_f, wd_f, arch.ffn_activation,
                 ep_size, E_l, capacity, d, a2a, chunks,
             )
             vals = y_buf[flat_e, pos]
@@ -897,7 +885,8 @@ def moe_ffn(
             # psum partial outputs over "ep".
             g = lax.axis_index("ep") if ep_size > 1 else 0
             local = lax.dynamic_slice_in_dim(buf, g * E_l, E_l, axis=0)
-            expert_out = ffn_fn(local, wu_f, wg_f, wd_f, arch.ffn_activation)
+            expert_out = _expert_ffn(local, wu_f, wg_f, wd_f,
+                                     arch.ffn_activation)
             y_local = jnp.zeros((E, capacity, d), expert_out.dtype)
             y_local = lax.dynamic_update_slice_in_dim(
                 y_local, expert_out, g * E_l, axis=0

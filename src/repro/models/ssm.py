@@ -14,8 +14,8 @@ Sharding note: the reference CUDA implementation fuses z/x/B/C/dt into one
 shards exactly over the ("ep","tp") axes — a fused projection would put the
 z/x/B/C/dt split points inside shards and force GSPMD reshards.  Same math.
 
-``repro.kernels.ssd`` provides the Pallas TPU kernel for the intra-chunk
-part; this module is the pure-jnp reference and the dry-run path.
+The whole block is jnp, the intra-chunk term included; XLA lowers it on
+every backend.
 """
 
 from __future__ import annotations
@@ -48,7 +48,6 @@ def ssd_chunked(
     C: jax.Array,  # (b, l, g, n)
     chunk: int,
     initial_state: Optional[jax.Array] = None,  # (b, h, p, n)
-    use_pallas: bool = False,
     head_group: int = 32,
 ) -> Tuple[jax.Array, jax.Array]:
     """Chunked SSD. Returns (y (b,l,h,p), final_state (b,h,p,n)).
@@ -75,10 +74,7 @@ def ssd_chunked(
         @jax.checkpoint
         def group(carry, xs):
             xi, dti, ai = xs
-            y, fin = ssd_chunked(
-                xi, dti, ai, B, C, chunk,
-                use_pallas=use_pallas, head_group=h,
-            )
+            y, fin = ssd_chunked(xi, dti, ai, B, C, chunk, head_group=h)
             return carry, (y, fin)
 
         _, (ys, fins) = lax.scan(group, jnp.float32(0.0), (xg, dtg, ag))
@@ -102,16 +98,11 @@ def ssd_chunked(
 
     A_cs = jnp.cumsum(dAc, axis=2)  # (b, nc, cl, h)
 
-    if use_pallas:
-        from repro.kernels.ssd import ops as ssd_ops
-
-        Y_diag = ssd_ops.ssd_intra_chunk(xc, dAc, Bc, Cc)
-    else:
-        # Intra-chunk ("diagonal block") term.
-        L = jnp.exp(segsum(dAc.transpose(0, 1, 3, 2)))  # (b, nc, h, cl, cl)
-        Y_diag = jnp.einsum(
-            "bclhn,bcshn,bchls,bcshp->bclhp", Cc, Bc, L.astype(Cc.dtype), xc
-        )
+    # Intra-chunk ("diagonal block") term.
+    L = jnp.exp(segsum(dAc.transpose(0, 1, 3, 2)))  # (b, nc, h, cl, cl)
+    Y_diag = jnp.einsum(
+        "bclhn,bcshn,bchls,bcshp->bclhp", Cc, Bc, L.astype(Cc.dtype), xc
+    )
 
     # Per-chunk states.
     decay_states = jnp.exp(A_cs[:, :, -1:, :] - A_cs)  # (b, nc, cl, h)
@@ -181,7 +172,6 @@ def mamba_block(
     *,
     cache: Optional[Dict[str, jax.Array]] = None,
     return_cache: bool = False,
-    impl: str = "xla",
 ):
     """Mamba2 mixer sub-layer.
 
@@ -248,10 +238,7 @@ def mamba_block(
         Bg = B_c.reshape(b, l, s.n_groups, s.state_size)
         Cg = C_c.reshape(b, l, s.n_groups, s.state_size)
         chunk = min(s.chunk_size, l)
-        y, final = ssd_chunked(
-            xh, dt_s.astype(x.dtype), a, Bg, Cg, chunk,
-            use_pallas=(impl == "pallas"),
-        )
+        y, final = ssd_chunked(xh, dt_s.astype(x.dtype), a, Bg, Cg, chunk)
         y = y + xh * params["D"][None, None, :, None].astype(x.dtype)
         y = y.reshape(b, l, d_in)
         if return_cache:

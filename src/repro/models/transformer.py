@@ -32,7 +32,6 @@ def apply_block(
     plan: MeshPlan,
     *,
     positions: Optional[jax.Array],
-    impl: str = "xla",
     cache: Optional[Dict[str, jax.Array]] = None,
     cache_index=None,
     return_cache: bool = False,
@@ -54,7 +53,6 @@ def apply_block(
             h,
             arch,
             positions,
-            impl=impl,
             window=window,
             cache=cache,
             cache_index=cache_index,
@@ -68,7 +66,6 @@ def apply_block(
             arch,
             cache=cache,
             return_cache=return_cache,
-            impl=impl,
         )
     else:
         raise ValueError(mixer)
@@ -85,7 +82,6 @@ def apply_block(
                 arch,
                 plan,
                 token_sharded=token_sharded,
-                impl=impl,
             )
         else:
             raise ValueError(ffn)
@@ -110,7 +106,6 @@ def stack_forward(
     plan: MeshPlan,
     *,
     positions: Optional[jax.Array],
-    impl: str = "xla",
     token_sharded: bool = True,
     unroll: bool = False,
     pattern=None,
@@ -135,7 +130,6 @@ def stack_forward(
                 arch,
                 plan,
                 positions=positions,
-                impl=impl,
                 token_sharded=token_sharded,
             )
             if metrics:

@@ -87,33 +87,6 @@ def test_ragged_ffn_grad_compiles(one_chip):
                    *_ragged_args(one_chip))
 
 
-def test_grouped_ffn_forward_compiles(one_chip):
-    # Capacity buffers of that batch: C = ceil(16384 * 8 / 40 * 1.25).
-    bf = jnp.bfloat16
-    args = (
-        _spec((E, 4096, D), bf, one_chip),
-        _spec((E, D, F), bf, one_chip),
-        _spec((E, D, F), bf, one_chip),
-        _spec((E, F, D), bf, one_chip),
-    )
-
-    def fwd(tokens, wu, wg, wd):
-        return mm_ops.grouped_ffn(tokens, wu, wg, wd, interpret=False)
-
-    _assert_mosaic(fwd, *args)
-
-
-def test_flash_attention_forward_compiles(one_chip):
-    bf = jnp.bfloat16
-    q = _spec((1, S, HQ, DH), bf, one_chip)
-    kv = _spec((1, S, HKV, DH), bf, one_chip)
-
-    def fwd(q, k, v):
-        return fa_ops.flash_attention(q, k, v, causal=True, interpret=False)
-
-    _assert_mosaic(fwd, q, kv, kv)
-
-
 def test_causal_attention_grad_compiles(one_chip):
     """The trainable splash path at the cell's shapes: the forward and the
     fused dq/dkv kernel both lower through Mosaic."""

@@ -30,7 +30,7 @@ else:
 
 
 @_property
-def test_hill_climb_reduces_imbalance(E=8, ep=2, seed=0):
+def test_hill_climb_reduces_imbalance(E, ep, seed):
     E = (E // ep) * ep
     if E < ep:
         return

@@ -4,8 +4,8 @@ These are the dispatch-mode guarantees that must hold in every container
 (no hypothesis dependency; the randomized sweeps over the same checks live
 in tests/test_moe_properties.py):
 
-* where capacity mode drops nothing, ragged == capacity on outputs AND
-  grads, for both the XLA and Pallas (custom-VJP) implementations;
+* where capacity mode drops nothing, ragged (the Pallas kernels and their
+  custom VJP) == capacity (XLA einsums) on outputs AND grads;
 * where capacity mode drops, ragged still equals the no-drop oracle;
 * degenerate skews: E=1 and all-tokens-to-one-expert.
 """
@@ -43,7 +43,7 @@ def moe_setup():
     return arch, plan, ffn
 
 
-def check_parity_no_drops(arch, plan, ffn, seed, impls=("xla", "pallas")):
+def check_parity_no_drops(arch, plan, ffn, seed):
     """Shared body for the deterministic and hypothesis parity tests."""
     E, k = arch.moe.num_experts, arch.moe.top_k
     # C >= T*k: capacity mode provably keeps everything.
@@ -52,35 +52,27 @@ def check_parity_no_drops(arch, plan, ffn, seed, impls=("xla", "pallas")):
     x = jax.random.normal(jax.random.PRNGKey(seed), (2, 16, arch.d_model))
     x = x * 0.5
     with plan.mesh:
-        for impl in impls:
-            yc, _ = moe_lib.moe_ffn_local(ffn, x, cap, impl=impl)
-            yr, _ = moe_lib.moe_ffn_local(ffn, x, rag, impl=impl)
-            np.testing.assert_allclose(
-                np.asarray(yc), np.asarray(yr), atol=1e-5, err_msg=impl
-            )
-        # grads: ragged (the pallas impl exercises the custom VJP) vs
-        # capacity XLA autodiff
-        gc = jax.grad(
-            lambda p, h: (moe_lib.moe_ffn_local(p, h, cap)[0] ** 2).sum(),
-            argnums=(0, 1), allow_int=True,
-        )(ffn, x)
-        for impl in impls:
-            gr = jax.grad(
-                lambda p, h: (
-                    moe_lib.moe_ffn_local(p, h, rag, impl=impl)[0] ** 2
-                ).sum(),
+        yc, _ = moe_lib.moe_ffn_local(ffn, x, cap)
+        yr, _ = moe_lib.moe_ffn_local(ffn, x, rag)
+        np.testing.assert_allclose(np.asarray(yc), np.asarray(yr), atol=1e-5)
+
+        # grads: ragged (the kernels' custom VJP) vs capacity XLA autodiff
+        def grads(mode):
+            return jax.grad(
+                lambda p, h: (moe_lib.moe_ffn_local(p, h, mode)[0] ** 2).sum(),
                 argnums=(0, 1), allow_int=True,
             )(ffn, x)
-            errs = jax.tree.map(
-                lambda a, b: float(
-                    np.abs(np.asarray(a, np.float32)
-                           - np.asarray(b, np.float32)).max()
-                )
-                if np.issubdtype(np.asarray(a).dtype, np.floating)
-                else 0.0,
-                gc, gr,
+
+        errs = jax.tree.map(
+            lambda a, b: float(
+                np.abs(np.asarray(a, np.float32)
+                       - np.asarray(b, np.float32)).max()
             )
-            assert max(jax.tree.leaves(errs)) < 2e-4, impl
+            if np.issubdtype(np.asarray(a).dtype, np.floating)
+            else 0.0,
+            grads(cap), grads(rag),
+        )
+        assert max(jax.tree.leaves(errs)) < 2e-4
 
 
 @pytest.mark.parametrize("seed", [0, 3, 17])
@@ -110,8 +102,7 @@ def test_ragged_keeps_tokens_capacity_drops():
     )
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_ragged_degenerate_skews(impl):
+def test_ragged_degenerate_skews():
     """E=1 (single expert) and all-tokens-to-one-expert: the ragged path
     must match a dense FFN over all tokens."""
     from repro.kernels.moe_gemm import ref as mm_ref
@@ -130,7 +121,7 @@ def test_ragged_degenerate_skews(impl):
         ffn1[kname] = ffn[kname][:1]
     x = jax.random.normal(jax.random.PRNGKey(11), (2, 8, arch.d_model)) * 0.5
     with plan.mesh:
-        y, _ = moe_lib.moe_ffn_local(ffn1, x, arch1, impl=impl)
+        y, _ = moe_lib.moe_ffn_local(ffn1, x, arch1)
     xt = x.reshape(-1, arch.d_model)
     dense = mm_ref.ragged_ffn(
         xt, ffn1["w_up"], ffn1["w_gate"], ffn1["w_down"],
@@ -147,7 +138,7 @@ def test_ragged_degenerate_skews(impl):
     ffn_all = dict(ffn)
     ffn_all["assignment"] = jnp.full_like(ffn["assignment"], 3)
     with plan.mesh:
-        y_all, _ = moe_lib.moe_ffn_local(ffn_all, x, arch_all, impl=impl)
+        y_all, _ = moe_lib.moe_ffn_local(ffn_all, x, arch_all)
     assert np.isfinite(np.asarray(y_all)).all()
     # oracle: dense FFN through expert 3 (router weights sum to 1 per token)
     dense3 = mm_ref.ragged_ffn(

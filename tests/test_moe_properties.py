@@ -115,7 +115,7 @@ def test_ragged_equals_capacity_when_nothing_drops(seed):
     from test_moe_dispatch import check_parity_no_drops, moe_setup
 
     arch, plan, ffn = moe_setup()
-    check_parity_no_drops(arch, plan, ffn, seed, impls=("xla",))
+    check_parity_no_drops(arch, plan, ffn, seed)
 
 
 def test_moe_output_matches_dense_oracle():

@@ -38,9 +38,19 @@ def _ssd_sequential(x, dt, a, B, C):
     return ys, state
 
 
-@pytest.mark.parametrize("chunk", [4, 8, 16])
-def test_ssd_chunked_matches_sequential(chunk):
-    x, dt, a, B, C = _rand_inputs(jax.random.PRNGKey(0), 2, 32, 4, 8, 1, 8)
+# (b, l, h, p, n) and the chunk: three chunkings of one sequence, then two
+# chunks of 32 and 64 rows with wider heads and states.
+SEQUENTIAL_CASES = [
+    *(pytest.param((2, 32, 4, 8, 8), c, id=str(c)) for c in (4, 8, 16)),
+    pytest.param((1, 64, 4, 16, 8), 32, id="l64-cl32"),
+    pytest.param((2, 128, 8, 32, 16), 64, id="l128-cl64"),
+]
+
+
+@pytest.mark.parametrize("shape,chunk", SEQUENTIAL_CASES)
+def test_ssd_chunked_matches_sequential(shape, chunk):
+    b, l, h, p, n = shape
+    x, dt, a, B, C = _rand_inputs(jax.random.PRNGKey(0), b, l, h, p, 1, n)
     y, final = ssd_chunked(x, dt, a, B, C, chunk)
     y_ref, state_ref = _ssd_sequential(x, dt, a, B, C)
     np.testing.assert_allclose(np.asarray(y), y_ref, atol=2e-4)
