@@ -57,6 +57,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.configs.base import ArchConfig, MoECfg
 from repro.core import halo
 from repro.sharding import MeshPlan
@@ -239,6 +240,77 @@ def _sort_dispatch(flat_e: jax.Array, E: int):
     return order, inv, offsets
 
 
+# The ragged path's two permutations.  Autodiff would transpose each gather
+# into a scatter-add of T·k rows; since ``order`` and ``inv`` are inverse
+# permutations, both backward passes are gathers instead.  Each call is
+# counted at trace time as ``moe.permute``.
+
+
+def _rows(x, idx):
+    """x[idx] for indices in range by construction."""
+    return x.at[idx].get(mode="promise_in_bounds")
+
+
+def _to_expert_order(xt, order, inv, k: int):
+    """Token rows (T, d) -> expert-sorted rows (T·k, d): ``xt[order // k]``.
+    Backward: each token sums its k rows' cotangents, gathered by ``inv``,
+    in float32."""
+    obs.counter("moe.permute", op="to_expert", rows=order.shape[0])
+    return _to_expert(xt, order, inv, k)
+
+
+def _to_expert_rows(xt, order, inv, k):
+    with jax.named_scope("moe.dispatch"):
+        return _rows(xt, order // k)
+
+
+def _to_expert_bwd(k, inv, g_xs):
+    with jax.named_scope("moe.dispatch"):
+        g = _rows(g_xs, inv).astype(jnp.float32)
+        g_xt = g.reshape(-1, k, g.shape[-1]).sum(axis=1).astype(g_xs.dtype)
+    return g_xt, None, None
+
+
+_to_expert = jax.custom_vjp(_to_expert_rows, nondiff_argnums=(3,))
+_to_expert.defvjp(
+    lambda xt, order, inv, k: (_to_expert_rows(xt, order, inv, k), inv),
+    _to_expert_bwd)
+
+
+def _from_expert_order(ys, inv, order, w, k: int):
+    """Expert-sorted rows (T·k, d) -> tokens (T, d): ``y[t] = Σ_j w[t·k+j]
+    · ys[inv[t·k+j]]``.  Backward: ``ys`` takes its token's cotangent,
+    gathered by ``order``, times its weight; ``w`` the float32 dot of the
+    two."""
+    obs.counter("moe.permute", op="from_expert", rows=inv.shape[0])
+    return _from_expert(ys, inv, order, w, k)
+
+
+def _from_expert_rows(ys, inv, order, w, k):
+    with jax.named_scope("moe.combine"):
+        vals = _rows(ys, inv)
+        vals = vals * w[:, None].astype(vals.dtype)
+        return vals.reshape(-1, k, vals.shape[-1]).sum(axis=1)
+
+
+def _from_expert_bwd(k, res, g_y):
+    ys, inv, order, w = res
+    with jax.named_scope("moe.combine"):
+        g_rows = _rows(g_y, order // k)  # (T·k, d), in expert order
+        g_ys = g_rows * _rows(w, order)[:, None].astype(g_rows.dtype)
+        f32 = jnp.float32
+        g_w = jnp.sum(g_rows.astype(f32) * ys.astype(f32), axis=-1)
+        g_w = _rows(g_w, inv).astype(w.dtype)
+    return g_ys, None, None, g_w
+
+
+_from_expert = jax.custom_vjp(_from_expert_rows, nondiff_argnums=(4,))
+_from_expert.defvjp(
+    lambda ys, inv, order, w, k: (_from_expert_rows(ys, inv, order, w, k),
+                                  (ys, inv, order, w)),
+    _from_expert_bwd)
+
+
 @jax.named_scope("moe.experts")
 def _ragged_rows_ffn(xs, w_up, w_gate, w_down, offsets, activation: str):
     """Grouped FFN over expert-sorted rows: always the ragged Pallas kernels
@@ -255,17 +327,12 @@ def _moe_ragged_local(xt, top_phys, top_w, w_up, w_gate, w_down,
     """Dropless single-rank MoE compute: sort → ragged FFN → inverse
     permutation → weighted combine.  Processes every (token, k) pair —
     no capacity, no drops, no zero-padding beyond the kernel's row tile."""
-    T, d = xt.shape
     flat_e = top_phys.reshape(-1)
     flat_w = top_w.reshape(-1)
     order, inv, offsets = _sort_dispatch(flat_e, E)
-    with jax.named_scope("moe.dispatch"):
-        xs = jnp.take(xt, order // k, axis=0)  # (T*k, d) expert-sorted
+    xs = _to_expert_order(xt, order, inv, k)  # (T*k, d) expert-sorted
     ys = _ragged_rows_ffn(xs, w_up, w_gate, w_down, offsets, activation)
-    with jax.named_scope("moe.combine"):
-        vals = jnp.take(ys, inv, axis=0)  # back to flat (token, k) order
-    keep = jnp.ones_like(flat_e, dtype=bool)
-    return _combine_expert_outputs(vals, flat_w, keep, T, k, d)
+    return _from_expert_order(ys, inv, order, flat_w, k)
 
 
 @jax.named_scope("moe.dispatch")
@@ -302,7 +369,7 @@ def _moe_ragged_sharded(xt, top_phys, top_w, wu_f, wg_f, wd_f,
     tail), runs the ragged grouped FFN over exactly the occupied rows, and
     returns results through the inverse permutations.
     """
-    T, d = xt.shape
+    d = xt.shape[1]
     k = moe.top_k
     E = moe.num_experts
     E_l = E // ep_size
@@ -311,7 +378,7 @@ def _moe_ragged_sharded(xt, top_phys, top_w, wu_f, wg_f, wd_f,
     Tk = flat_e.shape[0]
     order, inv, _ = _sort_dispatch(flat_e, E)
     sorted_e = flat_e[order]
-    xs = jnp.take(xt, order // k, axis=0)  # (Tk, d) expert-sorted
+    xs = _to_expert_order(xt, order, inv, k)  # (Tk, d) expert-sorted
 
     S = E_l * capacity  # per-destination row budget == capacity wire size
     dest = sorted_e // E_l  # nondecreasing
@@ -390,8 +457,7 @@ def _moe_ragged_sharded(xt, top_phys, top_w, wu_f, wg_f, wd_f,
     y_buf = jnp.concatenate(outs, axis=1)  # (ep, S, d)
     vals = y_buf[dest, jnp.minimum(posd, S - 1)]
     vals = jnp.where(keep_s[:, None], vals, 0.0)
-    vals = jnp.take(vals, inv, axis=0)  # back to flat (token, k) order
-    return _combine_expert_outputs(vals, flat_w, keep_s[inv], T, k, d)
+    return _from_expert_order(vals, inv, order, flat_w * keep_s[inv], k)
 
 
 # A share's chunk of held rows: twice what its experts take under even
@@ -761,7 +827,11 @@ def moe_ffn(
     def body(wr, wu, wg, wd, assignment, replicas, bias, xl):
         b_l, s_l, d = xl.shape
         T = b_l * s_l
-        xt = xl.reshape(T, d)
+        # The tokens as rows for the router and the dispatch.  Its backward
+        # closes the fusion that the dispatch's k-sum joins, which a trace
+        # then reads under moe.dispatch.
+        with jax.named_scope("moe.dispatch"):
+            xt = xl.reshape(T, d)
         top_w, top_i, probs, logits = _route(
             xt, wr, moe, bias if bias.shape[0] else None)
         # Metrics/aux use LOGICAL expert ids; dispatch uses PHYSICAL slots

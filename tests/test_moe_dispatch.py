@@ -11,6 +11,7 @@ in tests/test_moe_properties.py):
 """
 
 import dataclasses
+import re
 from functools import lru_cache
 
 import jax
@@ -149,3 +150,170 @@ def test_ragged_degenerate_skews():
         np.asarray(y_all).reshape(-1, arch.d_model), np.asarray(dense3),
         atol=1e-5,
     )
+
+
+# -- the ragged path's permutations ------------------------------------------
+
+# (T, E, k, routing): uniform routing, every row to one expert, k = 1,
+# k = E, and T no multiple of 128.
+PERMUTE_CASES = {
+    "uniform": (256, 8, 2, "uniform"),
+    "one-expert": (256, 8, 2, "one"),
+    "k1": (256, 8, 1, "uniform"),
+    "kE": (64, 8, 8, "all"),
+    "t100": (100, 8, 2, "uniform"),
+}
+PERMUTE_TOL = {jnp.float32: 1e-6, jnp.bfloat16: 2.0 ** -6}
+
+
+def _routing(T, E, k, routing, key):
+    if routing == "one":
+        return jnp.full((T, k), 3, jnp.int32)
+    if routing == "all":
+        keys = jax.random.split(key, T)
+        return jax.vmap(lambda kk: jax.random.permutation(kk, E))(keys)
+    # k distinct experts per token, as top-k gives them
+    scores = jax.random.uniform(key, (T, E))
+    return jax.lax.top_k(scores, k)[1].astype(jnp.int32)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", list(PERMUTE_CASES))
+def test_permutation_helpers_match_the_take_formula(case, dtype):
+    """``_to_expert_order`` and ``_from_expert_order``: values and
+    gradients (to the rows and to the weights) equal ``jax.vjp`` of the
+    plain ``jnp.take`` formula, which autodiff transposes into
+    scatter-adds.  The formula runs in float32 on the same inputs; a
+    bfloat16 helper lands within bfloat16's rounding of it, and no further
+    from it than the formula run in bfloat16."""
+    T, E, k, routing = PERMUTE_CASES[case]
+    d = 32
+    kx, ke, kw, kg, ky = jax.random.split(jax.random.PRNGKey(T + k), 5)
+    ids = _routing(T, E, k, routing, ke)
+    order, inv, _ = moe_lib._sort_dispatch(ids.reshape(-1), E)
+    xt = jax.random.normal(kx, (T, d)).astype(dtype)
+    ys = jax.random.normal(ky, (T * k, d)).astype(dtype)
+    w = jax.nn.softmax(jax.random.normal(kw, (T, k)), axis=-1).reshape(-1)
+    g_xs = jax.random.normal(kg, (T * k, d)).astype(dtype)
+    g_y = jax.random.normal(kg, (T, d)).astype(dtype)
+    tol = PERMUTE_TOL[dtype]
+
+    def to_plain(xt):
+        return jnp.take(xt, order // k, axis=0)
+
+    def from_plain(ys, w):
+        vals = jnp.take(ys, inv, axis=0)
+        vals = vals * w[:, None].astype(vals.dtype)
+        return vals.reshape(T, k, d).sum(axis=1)
+
+    def to_help(xt):
+        return moe_lib._to_expert_order(xt, order, inv, k)
+
+    def from_help(ys, w):
+        return moe_lib._from_expert_order(ys, inv, order, w, k)
+
+    def run(to, frm, cast):
+        xs, to_vjp = jax.vjp(to, cast(xt))
+        y, from_vjp = jax.vjp(frm, cast(ys), w)
+        out = (xs, to_vjp(cast(g_xs))[0], y, *from_vjp(cast(g_y)))
+        return [np.asarray(a, np.float32) for a in out]
+
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    got = run(to_help, from_help, lambda a: a)
+    want = run(to_plain, from_plain, f32)
+    plain = run(to_plain, from_plain, lambda a: a)
+    for name, g, r, p in zip(("xs", "g_xt", "y", "g_ys", "g_w"),
+                             got, want, plain):
+        scale = max(float(np.abs(r).max()), 1.0)
+        np.testing.assert_allclose(g, r, rtol=0, atol=tol * scale,
+                                   err_msg=name)
+        assert np.abs(g - r).max() <= np.abs(p - r).max() + tol * scale / 4, \
+            name
+
+
+def test_permutation_vjps_gather_and_never_scatter_add():
+    """Both helpers' backward passes are gathers: their VJP jaxprs hold no
+    ``scatter-add``, where the plain ``jnp.take`` formula's do."""
+    T, E, k, d = 64, 8, 2, 16
+    ids = _routing(T, E, k, "uniform", jax.random.PRNGKey(0))
+    order, inv, _ = moe_lib._sort_dispatch(ids.reshape(-1), E)
+    xt = jnp.ones((T, d))
+    ys = jnp.ones((T * k, d))
+    w = jnp.ones((T * k,))
+
+    def vjp_jaxpr(f, *primals):
+        def pull(*p):
+            out, back = jax.vjp(f, *p)
+            return back(jnp.ones_like(out))
+        return str(jax.make_jaxpr(pull)(*primals))
+
+    to_help = vjp_jaxpr(lambda x: moe_lib._to_expert_order(x, order, inv, k),
+                        xt)
+    from_help = vjp_jaxpr(
+        lambda y, w: moe_lib._from_expert_order(y, inv, order, w, k), ys, w)
+    to_take = vjp_jaxpr(lambda x: jnp.take(x, order // k, axis=0), xt)
+    from_take = vjp_jaxpr(lambda y: jnp.take(y, inv, axis=0), ys)
+    assert "scatter-add" in to_take and "scatter-add" in from_take
+    assert "scatter-add" not in to_help and "gather" in to_help
+    assert "scatter-add" not in from_help and "gather" in from_help
+
+
+_SCATTER = re.compile(
+    r'"?stablehlo\.scatter"?\(.*?\}\) : \(tensor<([^>]*)>, tensor<([^>]*)>, '
+    r'tensor<([^>]*)>\)', re.S)
+
+
+@lru_cache(maxsize=None)
+def lowered_step(arch: str):
+    """The jitted train step of ``arch``, reduced and ragged at batch
+    2 x 256, built through ``launch.train.setup`` and lowered with
+    telemetry on: (StableHLO text, the ``moe.permute`` counts, T·k, d)."""
+    from repro import obs
+    from repro.launch import train
+
+    args = train.parse_args(
+        ["--arch", arch, "--reduced", "--steps", "2", "--batch", "2",
+         "--seq", "256", "--dispatch", "ragged"])
+    run = train.setup(args)
+    batch = next(run["data"])
+    run["data"].close()
+    ring = obs.RingBufferSink()
+    prev = obs.set_telemetry(obs.Telemetry(sinks=[ring]))
+    try:
+        with run["plan"].mesh:
+            text = run["trainer"].train_step.lower(run["state"],
+                                                   batch).as_text()
+    finally:
+        obs.set_telemetry(prev)
+    permute = [e["attrs"] for e in ring.events()
+               if e["kind"] == "counter" and e["name"] == "moe.permute"]
+    arch_ = run["arch"]
+    return text, permute, 2 * 256 * arch_.moe.top_k, arch_.d_model
+
+
+def test_train_step_scatters_no_expert_rows():
+    """The ragged train step of granite holds no scatter whose updates are
+    (T·k, d) rows: the dispatch and the combine move rows by gathers in both
+    passes.  The expert-count bincounts may stay."""
+    text, _, Tk, d = lowered_step("granite-moe-3b-a800m")
+    updates = [m.group(3) for m in _SCATTER.finditer(text)]
+    assert updates, "no scatter found: the pattern no longer parses"
+    rows = f"{Tk}x{d}x"
+    assert not [u for u in updates if u.startswith(rows)], updates
+
+
+@pytest.mark.parametrize("arch,counted", [
+    ("granite-moe-3b-a800m", True),
+    ("moonlight-16b-a3b-ep8", False),
+])
+def test_permute_counter_counts_the_ragged_path_only(arch, counted):
+    """``moe.permute`` counts each traced helper call: a granite step both
+    ``op`` values with ``rows`` = T·k; a Moonlight share step, whose held
+    rows bypass the helpers, none."""
+    _, permute, Tk, _ = lowered_step(arch)
+    if not counted:
+        assert permute == []
+        return
+    assert {p["op"] for p in permute} == {"to_expert", "from_expert"}
+    assert {p["rows"] for p in permute} == {Tk}
