@@ -5,7 +5,8 @@ a layer (the family's ``held`` experts from ``first``).
 Per step, the rows routed to the held experts come from the step's
 ``expert_load`` (MoE layers x every routed expert), over the MoE layers
 only; ``bench/flops.py`` turns them into the needed calls' operations and
-bytes, each call's least time max(operations / bf16 peak, bytes / HBM
+bytes (gated experts unless the family's ``dims()`` says ``gated``
+False), each call's least time max(operations / bf16 peak, bytes / HBM
 bandwidth).  The share is the summed least time over the measured kernel
 time.  One chip only."""
 
@@ -26,7 +27,8 @@ def read(ctx):
     for load in ctx.loads:
         for layer in load:
             calls = flops.ragged_ffn_calls(float(layer[held].sum()), n["d"],
-                                           n["f"], n["held"])
+                                           n["f"], n["held"],
+                                           n.get("gated", True))
             least += sum(flops.roofline_s(
                 calls, ctx.peak["bf16_flops"],
                 ctx.peak["hbm_bytes_per_s"]).values()) / len(ctx.loads)

@@ -4,7 +4,8 @@ roofline accounts for, in %.
 Per device and step, the rows routed to that device's experts come from
 the step's ``expert_load`` (tokens x top-k per expert and layer), over the
 layers of the device's pipeline stage.  ``bench/flops.py`` turns them into
-the needed calls' operations and bytes; each call's least time is
+the needed calls' operations and bytes (gated experts unless the
+family's ``dims()`` says ``gated`` False); each call's least time is
 max(operations / bf16 peak, bytes / HBM bandwidth).  The share is the sum
 of least times over the sum of measured kernel times, over all devices.
 """
@@ -28,7 +29,8 @@ def read(ctx):
         for load in ctx.loads:
             for layer in range(stage * lps, (stage + 1) * lps):
                 rows = float(load[layer, rank * e_l:(rank + 1) * e_l].sum())
-                calls = flops.ragged_ffn_calls(rows, n["d"], n["f"], e_l)
+                calls = flops.ragged_ffn_calls(rows, n["d"], n["f"], e_l,
+                                               n.get("gated", True))
                 t = flops.roofline_s(calls, ctx.peak["bf16_flops"],
                                      ctx.peak["hbm_bytes_per_s"])
                 least += sum(t.values()) / len(ctx.loads)

@@ -34,8 +34,9 @@ at one chip's share of an expert-parallel deployment):
 
 The selection bias is no weight: ``loss_fn`` takes it (zero by default)
 and returns each MoE layer's expert loads, and ``bias_update`` is the
-step's move of it (``assumed.bias_update_speed``).  ``bench/reference.py``
-calls ``loss_fn`` without a bias, so its steps keep the bias at zero.
+step's move of it (``assumed.bias_update_speed``).  ``initial_state`` and
+``after_step`` hand both to ``bench/reference.py``, whose steps then move
+the bias after each step from that step's loads, as the program does.
 
 Memory is bounded by taking each row of the batch in turn (the loss is a
 sum over rows; the row is rematerialised), each layer, each head's
@@ -311,6 +312,20 @@ def bias_update(bias, loads, cfg: Dict):
     gamma = cfg["assumed"]["bias_update_speed"]
     return bias + gamma * jnp.sign(
         jnp.mean(loads, axis=-1, keepdims=True) - loads)
+
+
+def initial_state(cfg: Dict):
+    """The selection bias at the seed: zero, (MoE layers, E)."""
+    import jax.numpy as jnp
+
+    n = dims(cfg)
+    return jnp.zeros((n["Lm"], n["E"]), jnp.float32)
+
+
+def after_step(bias, aux, cfg: Dict):
+    """The reference's hook after a step: ``aux`` is ``loss_fn``'s
+    ``(ce, aux, z, loads)``; the bias moves by ``bias_update``."""
+    return bias_update(bias, aux[3], cfg)
 
 
 def to_flat(params) -> Dict:

@@ -9,6 +9,13 @@ family's loss) so that one v5e holds it at the benchmark's sizes once the
 program's state is freed.  The optimizer is AdamW with global-norm
 clipping, linear warm-up and cosine decay.
 
+A family whose loss takes state besides the weights (``deepseek_v3``: the
+routers' selection bias, moved after every step from the step's expert
+loads) gives ``initial_state(cfg)`` and ``after_step(state, aux, cfg)``,
+where ``aux`` is the second value its ``loss_fn`` returns; the state is
+passed to ``loss_fn`` after ``dot_w`` and moved between steps, as the
+program moves it.
+
 ``mode="fp8"`` is the control: the same computation with the operands of
 every weight matmul rounded to float8 (e4m3, per-tensor scale), the
 precision below the configuration's bfloat16 compute.  ``fault`` plants
@@ -67,9 +74,11 @@ def lr_at(opt: Dict, t):
 
 
 def train_step(p, m, v, step, tokens, labels, cfg, mode="highest",
-               fault=None):
+               fault=None, state=None):
     """One AdamW step.  Returns ``(p, m, v, loss, clipped-gradient slice
-    norms)``; ``step`` counts the steps already taken."""
+    norms, state after the step)``; ``step`` counts the steps already
+    taken, ``state`` is the family's loss state (None where it has
+    none)."""
     import jax
     import jax.numpy as jnp
 
@@ -78,9 +87,12 @@ def train_step(p, m, v, step, tokens, labels, cfg, mode="highest",
         half = tokens.shape[0] // 2
         tokens, labels = tokens[:half], labels[:half]
     dot, dot_w = _dots(mode)
-    (loss, _), g = jax.value_and_grad(weights.family(cfg).loss_fn,
-                                      has_aux=True)(
-        p, tokens, labels, cfg, dot, dot_w)
+    fam = weights.family(cfg)
+    extra = () if state is None else (state,)
+    (loss, aux), g = jax.value_and_grad(fam.loss_fn, has_aux=True)(
+        p, tokens, labels, cfg, dot, dot_w, *extra)
+    if state is not None:
+        state = fam.after_step(state, aux, cfg)
     gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(x)) for x in g.values()))
     g = {name: x * jnp.minimum(1.0, opt["clip_norm"] / (gnorm + 1e-9))
          for name, x in g.items()}
@@ -93,7 +105,7 @@ def train_step(p, m, v, step, tokens, labels, cfg, mode="highest",
          for kk in p}
     p = {kk: p[kk] - lr * (m[kk] / bc1 / (jnp.sqrt(v[kk] / bc2) + opt["eps"])
                            + opt["weight_decay"] * p[kk]) for kk in p}
-    return p, m, v, loss, weights.slice_norms(cfg, g)
+    return p, m, v, loss, weights.slice_norms(cfg, g), state
 
 
 def _shardings(cfg, devices):
@@ -136,11 +148,15 @@ class Reference:
                              out_shardings=self.st_sh)
         self._zeros = jax.jit(lambda t: jax.tree.map(jnp.zeros_like, t),
                               out_shardings=self.st_sh)
+        fam = weights.family(cfg)
+        self._state0 = (fam.initial_state(cfg) if hasattr(fam, "after_step")
+                        else None)
         self._step = jax.jit(
-            lambda p_, m_, v_, t, x, y: train_step(p_, m_, v_, t, x, y, cfg,
-                                                   mode, fault),
+            lambda p_, m_, v_, t, x, y, s_: train_step(
+                p_, m_, v_, t, x, y, cfg, mode, fault, s_),
             donate_argnums=(0, 1, 2),
-            out_shardings=(self.st_sh, self.st_sh, self.st_sh, None, None),
+            out_shardings=(self.st_sh, self.st_sh, self.st_sh, None, None,
+                           None),
         )
 
     def run(self, seed: int, batches: List[Dict[str, np.ndarray]]) -> Dict:
@@ -156,12 +172,14 @@ class Reference:
         key = jax.random.key(seed)
         p = self._make(key)
         m, v = self._zeros(p), self._zeros(p)
+        state = self._state0
         losses, grad = [], None
         for i, bt in enumerate(batches):
             tok, lab = (jax.device_put(bt[kk], self.batch_sh)
                         if self.batch_sh else bt[kk]
                         for kk in ("tokens", "labels"))
-            p, m, v, loss, gn = self._step(p, m, v, jnp.int32(i), tok, lab)
+            p, m, v, loss, gn, state = self._step(p, m, v, jnp.int32(i), tok,
+                                                  lab, state)
             losses.append(float(loss))
             if grad is None:
                 grad = jax.tree.map(np.asarray, gn)

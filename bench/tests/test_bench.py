@@ -26,7 +26,7 @@ def test_flop_counter_matches_active_params(name):
     n = weights.family(cfg).dims(cfg)
     arch = get_arch(cfg["program"]["arch"]).replace(num_layers=n["L"])
     # active_params counts every norm scale; matmul parameters do not.
-    norms = (2 * n["L"] + 1) * n["d"]
+    norms = flops.norm_params(cfg)
     assert flops.active_matmul_params(cfg) == arch.active_params() - norms
 
 
@@ -37,6 +37,18 @@ def test_model_flops_per_token_at_granite_4l():
     attn = 12 * 4 * 24 * 64 * 4096
     assert flops.model_flops_per_token(cfg, 4096) == 6 * n_active + attn
     assert 1.35e9 < flops.model_flops_per_token(cfg, 4096) < 1.37e9
+
+
+def test_model_flops_per_token_at_moonlight_5l():
+    """The parent's count: 6 N plus 12 L H hd s at the mean of the q/k and
+    v head sizes, (192 + 128) / 2 = 160."""
+    cfg = manifest.config("moonlight-16b-a3b-5l-ep8")
+    n_active = flops.active_matmul_params(cfg)
+    assert n_active == 275_646_976
+    attn = 12 * 5 * 16 * 160 * 8192
+    assert flops.attention_flops_per_token(cfg, 8192) == attn
+    assert flops.model_flops_per_token(cfg, 8192) == 2_912_173_056.0
+    assert flops.model_flops_per_token(cfg, 8192) == 6 * n_active + attn
 
 
 def test_ragged_ffn_calls_at_granite_widths():

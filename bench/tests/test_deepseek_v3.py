@@ -171,37 +171,36 @@ def test_parameter_accounting_is_the_tree(name, layers):
 
 
 def _two_steps(cfg, seed, fault=None):
-    """The reference's two steps with the selection bias moved after the
-    first (``bias_update``), as the program moves it."""
-    fam = weights.family(cfg)
+    """The reference's two steps; its family hook moves the selection bias
+    after the first (``after_step``), as the program moves it."""
     tr = cfg["training"]
     s = gen.TokenStream(manifest.traffic("zipf-topics"), cfg["vocab_size"],
                         tr["batch"], tr["seq"], seed)
     batches = [s.batch_at(i) for i in range(2)]
-    key = jax.random.key(seed)
-    p = weights.make(cfg, key)
-    m = jax.tree.map(jnp.zeros_like, p)
-    v = jax.tree.map(jnp.zeros_like, p)
-    n = fam.dims(cfg)
-    bias = jnp.zeros((n["Lm"], n["E"]), jnp.float32)
-    orig = fam.loss_fn
-    losses, grad = [], None
-    try:
-        for i, bt in enumerate(batches):
-            fam.loss_fn = functools.partial(orig, bias=bias)
-            dot, _ = reference._dots("highest")
-            loads = orig(p, bt["tokens"], bt["labels"], cfg, dot, dot,
-                         bias=bias)[1][3]
-            p, m, v, loss, gn = reference.train_step(
-                p, m, v, jnp.int32(i), bt["tokens"], bt["labels"], cfg,
-                fault=fault)
-            losses.append(float(loss))
-            grad = gn if grad is None else grad
-            bias = fam.bias_update(bias, loads, cfg)
-    finally:
-        fam.loss_fn = orig
-    return {"losses": losses, "grad": jax.tree.map(np.asarray, grad),
-            "delta": reference.delta_norms(cfg, p, key)}
+    return reference.Reference(cfg, fault=fault).run(seed, batches)
+
+
+def test_reference_moves_the_selection_bias_after_each_step():
+    """``reference.train_step`` hands the family's ``after_step`` the
+    step's expert loads: the bias moves by ``bias_update``, in whole steps
+    of its speed."""
+    cfg = tiny()
+    fam = weights.family(cfg)
+    b = _batch(cfg)
+    p = weights.make(cfg, jax.random.key(SEED))
+    zeros = jax.tree.map(jnp.zeros_like, p)
+    bias = fam.initial_state(cfg)
+    assert bias.shape == (2, 8) and not bias.any()
+    dot, _ = reference._dots("highest")
+    loads = fam.loss_fn(p, b["tokens"], b["labels"], cfg, dot, dot,
+                        bias)[1][3]
+    *_, moved = reference.train_step(p, zeros, zeros, jnp.int32(0),
+                                     b["tokens"], b["labels"], cfg,
+                                     state=bias)
+    np.testing.assert_array_equal(moved, fam.bias_update(bias, loads, cfg))
+    speed = cfg["assumed"]["bias_update_speed"]
+    assert set(np.unique(np.abs(np.asarray(moved)) / speed).round(6)) <= {
+        0.0, 1.0} and np.asarray(moved).any()
 
 
 # Limits for the tiny size, set from seeds 2**31 + 301..306 read on the CPU

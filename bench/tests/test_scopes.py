@@ -191,20 +191,36 @@ def test_readers_return_nothing_without_scopes(name):
     assert scopes.READERS[name](_ctx(empty), {"fusion.1": "x"}) is None
 
 
+PR12_READINGS = {
+    "device_idle_pct": 0.4812368254354493,
+    "step_mfu": 8.448938239863582,
+    "moe_gemm_roofline": 13.371703362630065,
+    "moe_gemm.ms_per_step": 335.0977983333333,
+    "moe.expert_load_max_over_mean": 1.0,  # the file keeps even loads
+    "trainer.host_syncs_per_step": 2.3333333333333335,
+}
+
+
 def test_existing_readers_read_the_pr12_trace_as_before():
-    """The six readers the benchmark runs, bit for bit, on the trace of
-    three granite4l-zipf steps recorded before the program had scopes."""
+    """Six readers the benchmark runs, each by name, bit for bit, on the
+    trace of three granite4l-zipf steps recorded before the program had
+    scopes."""
     ctx = _ctx(trace.load(str(TESTDATA / "granite4l-zipf.trace.json.gz")))
-    got = {m["name"]: reduce.load_reader(m["name"])(ctx)
-           for m in manifest.load()["per_layer"]}
-    assert got == {
-        "device_idle_pct": 0.4812368254354493,
-        "step_mfu": 8.448938239863582,
-        "moe_gemm_roofline": 13.371703362630065,
-        "moe_gemm.ms_per_step": 335.0977983333333,
-        "moe.expert_load_max_over_mean": 1.0,  # the file keeps even loads
-        "trainer.host_syncs_per_step": 2.3333333333333335,
-    }
+    got = {name: reduce.load_reader(name)(ctx) for name in PR12_READINGS}
+    assert got == PR12_READINGS
+
+
+@pytest.mark.parametrize("entry", manifest.load()["per_layer"],
+                         ids=lambda m: m["name"])
+def test_every_listed_reader_loads_and_reads_the_pr12_trace(entry):
+    """Each ``per_layer`` entry of ``BENCHMARK.json``: its reader loads,
+    returns a float or None on the recorded granite trace, and its
+    ``workloads``, where given, name only cells the benchmark has."""
+    ctx = _ctx(trace.load(str(TESTDATA / "granite4l-zipf.trace.json.gz")))
+    value = reduce.load_reader(entry["name"])(ctx)
+    assert value is None or isinstance(value, float), value
+    cells = {w["name"] for w in manifest.load()["workloads"]}
+    assert set(entry.get("workloads", cells)) <= cells
 
 
 def test_scoped_trace_reads_what_perf_md_records():

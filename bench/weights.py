@@ -14,6 +14,14 @@ A configuration's ``model_type`` names its family, a file
 - ``to_flat(params)`` and ``from_flat(flat, like)``: the map between the
   program's parameter tree and the flat layout.
 
+A family whose layers differ from one another may also give, for
+``bench/flops.py``, ``attention_flops_per_token(cfg, seq)`` (its
+attention term, where not every layer attends alike), ``norm_params(cfg)``
+(its norm scales, where they are not a pre-norm pair per layer and a final
+norm) and ``dims()["gated"]`` False (two-matrix experts); and, for
+``bench/reference.py``, ``initial_state(cfg)`` and ``after_step(state,
+aux, cfg)`` where its loss takes state that moves between steps.
+
 A new family is a new file there, with no code edited.  The same weights
 feed the program (through ``from_flat``) and the reference, so the
 reference takes nothing the program made.  Every leaf is float32.
